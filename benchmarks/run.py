@@ -40,6 +40,8 @@ import json
 import sys
 import traceback
 
+from repro.core.compile_cache import enable_compile_cache
+
 MODULES = ["sweeps", "precision", "chunking", "greedy_modes",
            "kernel_roofline", "optimizers", "streaming", "functions",
            "serving", "contracts"]
@@ -53,6 +55,7 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write rows to PATH as JSON (CI artifact)")
     args = ap.parse_args()
+    enable_compile_cache()
     mods = args.only.split(",") if args.only else MODULES
     print("name,us_per_call,derived,backend,peak_device_bytes,function,"
           "n_batch")

@@ -40,11 +40,7 @@ from collections import Counter
 from typing import Any, Iterator, Optional
 
 import numpy as np
-
-try:  # jax.core keeps these public names; fall back for renamed internals
-    from jax.core import ClosedJaxpr, Jaxpr, JaxprEqn
-except ImportError:  # pragma: no cover
-    from jax._src.core import ClosedJaxpr, Jaxpr, JaxprEqn
+from jax.extend.core import ClosedJaxpr, Jaxpr, JaxprEqn
 
 #: Cross-device communication primitives. ``axis_index`` is free (no data
 #: movement) and deliberately excluded.

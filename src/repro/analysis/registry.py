@@ -27,6 +27,7 @@ from typing import Callable, Optional
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.core import functions as fx
 from repro.core.functions import FnSpec
@@ -176,7 +177,8 @@ def _device_case(kind, fname, backend, policy, batch=None):
 
 
 def audit_mesh():
-    return jax.make_mesh((jax.device_count(),), ("data",))
+    return jax.make_mesh((jax.device_count(),), ("data",),
+                         axis_types=(AxisType.Auto,))
 
 
 def _sharded_case(kind, fname, backend, policy, pool_plan):

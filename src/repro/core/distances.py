@@ -19,7 +19,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.core.precision import PrecisionPolicy, FP32
+from repro.core.precision import FP32, PrecisionPolicy, matmul_precision
 
 
 def _dot(X: jax.Array, Y: jax.Array, accum_dtype) -> jax.Array:
@@ -28,6 +28,7 @@ def _dot(X: jax.Array, Y: jax.Array, accum_dtype) -> jax.Array:
         X,
         Y,
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=matmul_precision(X.dtype),
         preferred_element_type=accum_dtype,
     )
 
@@ -49,6 +50,7 @@ def sq_norms(X: jax.Array, accum_dtype=jnp.float32) -> jax.Array:
     return jax.lax.dot_general(
         X, X,
         dimension_numbers=((contract, contract), (batch, batch)),
+        precision=matmul_precision(X.dtype),
         preferred_element_type=accum_dtype,
     )
 

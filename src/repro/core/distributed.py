@@ -48,8 +48,8 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.analysis.contracts import contract
 from repro.core import distances as dist_mod
@@ -98,7 +98,7 @@ def make_distributed_eval(mesh: Mesh, cfg: EvalConfig,
         mesh=mesh,
         in_specs=(P(axes, None), P(None, None, None), P(None), P(axes), P()),
         out_specs=P(None),
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit
@@ -128,7 +128,7 @@ def make_distributed_gains(mesh: Mesh, cfg: EvalConfig,
         mesh=mesh,
         in_specs=(P(axes, None), P(None, None), P(axes), P()),
         out_specs=P(None),
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit
@@ -155,7 +155,7 @@ def make_distributed_cache_update(mesh: Mesh, cfg: EvalConfig,
         mesh=mesh,
         in_specs=(P(axes, None), P(None), P(axes)),
         out_specs=P(axes),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(smapped)
 
@@ -418,8 +418,8 @@ def make_selection_scan(
         in_specs=(P(axes, None),
                   P(axes, None) if sharded_pool else P(None, None),
                   P(axes), P(axes), P(None, None), P(None)),
-        out_specs=(P(None), P(None), P(None)),
-        check_rep=False,
+        out_specs=(P(None), P(None), P()),  # sel, traj, scalar n_scored
+        check_vma=False,
     )
 
     @jax.jit
@@ -437,7 +437,8 @@ def _resolve_mesh(mesh: Optional[Mesh], data_axes: Sequence[str]) -> Mesh:
             raise ValueError(
                 "the default mesh is 1-D; pass an explicit mesh to shard "
                 f"over multiple axes {tuple(data_axes)}")
-        mesh = jax.make_mesh((jax.device_count(),), tuple(data_axes))
+        mesh = jax.make_mesh((jax.device_count(),), tuple(data_axes),
+                             axis_types=(AxisType.Auto,))
     return mesh
 
 
@@ -818,7 +819,7 @@ def make_selection_scan_batched(
                   P(None, axes), P(None, axes), P(None, None, None),
                   P(None, None), P(None)),
         out_specs=(P(None), P(None), P(None), P(None, axes)),
-        check_rep=False,
+        check_vma=False,
     )
 
     @partial(jax.jit, donate_argnums=(2,))
@@ -1197,8 +1198,8 @@ def make_greedi_scan(
         local_scan,
         mesh=mesh,
         in_specs=(P(axes, None), P(axes), P(axes), P(None)),
-        out_specs=(P(None), P(None), P(None)),
-        check_rep=False,
+        out_specs=(P(None), P(None), P()),  # sel, traj, scalar n_scored
+        check_vma=False,
     )
 
     @jax.jit

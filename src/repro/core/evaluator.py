@@ -39,7 +39,8 @@ import jax.numpy as jnp
 
 from repro.core import distances as dist_mod
 from repro.core.multiset import PackedMultiset
-from repro.core.precision import PrecisionPolicy, resolve as resolve_policy
+from repro.core.precision import (PrecisionPolicy, check_kernel_policy,
+                                  resolve as resolve_policy)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,7 +76,9 @@ class EvalConfig:
             raise ValueError(
                 f"kernel_variant must be 'flat' or 'loop', "
                 f"got {self.kernel_variant!r}")
-        resolve_policy(self.policy)  # raises on unknown policy names
+        policy = resolve_policy(self.policy)  # raises on unknown names
+        if self.backend == "pallas":
+            check_kernel_policy(policy)
         if isinstance(self.memory_budget_bytes, str) \
                 and self.memory_budget_bytes != "auto":
             raise ValueError(

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 
 
@@ -39,6 +40,34 @@ FP16 = PrecisionPolicy("fp16", jnp.float16, jnp.float32)
 FP16_STRICT = PrecisionPolicy("fp16_strict", jnp.float16, jnp.float16)
 
 POLICIES = {p.name: p for p in (FP32, BF16, FP16, FP16_STRICT)}
+
+
+def matmul_precision(dtype):
+    """Contraction precision for ``dtype`` operands.
+
+    On TPU a float32 (or float16) matmul at DEFAULT precision rounds its
+    operands to bfloat16 for one MXU pass; HIGHEST keeps them whole, so the
+    fp32 and fp16 policies compute what they name, in XLA and in the Pallas
+    kernels alike. bfloat16 operands are exact in one pass. CPU backends
+    ignore the setting.
+    """
+    if jnp.dtype(dtype) == jnp.bfloat16:
+        return None
+    return jax.lax.Precision.HIGHEST
+
+
+def check_kernel_policy(policy: PrecisionPolicy) -> None:
+    """Refuse a policy the Pallas TPU kernels cannot run.
+
+    Mosaic on TPU v5e has no float16 vector load, so ``fp16`` and
+    ``fp16_strict`` exist only on the ``jnp`` backend. Raised when a config
+    or kernel call is built, never as a silent fall back to ``jnp``.
+    """
+    if jnp.dtype(policy.compute_dtype) == jnp.float16:
+        raise ValueError(
+            f"precision policy {policy.name!r} stores float16, which the "
+            f"Pallas TPU kernels cannot load; use policy 'bf16' for half "
+            f"precision on the pallas backend, or backend 'jnp' for fp16")
 
 
 def resolve(policy: "str | PrecisionPolicy") -> PrecisionPolicy:

@@ -582,10 +582,13 @@ class SelectionService:
         #: requests they carried; padded_slots: inert k_eff=0 fill (the
         #: amortization ratio is batched_requests / dispatches);
         #: staged_buckets: dispatches whose padded stacks were device-put
-        #: WHILE the previous bucket's dispatch ran (issue-and-go overlap).
+        #: WHILE the previous bucket's dispatch ran (issue-and-go overlap);
+        #: staging_failures: ahead-of-dispatch stagings that raised (the
+        #: bucket was then rebuilt inline — a non-zero count is a fault to
+        #: look into, not a normal path).
         self.stats = {"requests": 0, "dispatches": 0,
                       "batched_requests": 0, "padded_slots": 0,
-                      "staged_buckets": 0}
+                      "staged_buckets": 0, "staging_failures": 0}
         self._queue: Optional[asyncio.Queue] = None
         self._task: Optional[asyncio.Task] = None
         self._error: Optional[BaseException] = None
@@ -698,8 +701,10 @@ class SelectionService:
                         except asyncio.CancelledError:
                             raise
                         except BaseException:
-                            staged = None  # staging is an optimization:
-                            # the serve path rebuilds inline on fallback
+                            # staging is an optimization: the serve path
+                            # rebuilds inline, and the fault is counted
+                            staged = None
+                            self.stats["staging_failures"] += 1
                     await serving
             except asyncio.CancelledError:
                 raise

@@ -423,7 +423,7 @@ def make_sharded_offer_scan(mesh, data_axes, *, spec: SieveSpec,
     from repro.core import distances as dist_mod
     from repro.core.precision import resolve as resolve_policy
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     axes = tuple(data_axes)
     key = (mesh, axes, spec, n_total, distance, policy_name, counter_key)
@@ -472,7 +472,7 @@ def make_sharded_offer_scan(mesh, data_axes, *, spec: SieveSpec,
         in_specs=(specs, P(axes, None), P(axes), P(axes), P(None, None),
                   P(None), P(None)),
         out_specs=(specs, P(None)),
-        check_rep=False,
+        check_vma=False,
     )
 
     # donate ONLY the state carry: V/seed/aux are the function's resident

@@ -10,15 +10,16 @@ This is the TPU-native version of the paper's GPU kernel (Algorithm 3):
   contraction ``(Bn, d) · (d, Bl)`` through the Gram identity
   ``‖v−s‖² = ‖v‖² + ‖s‖² − 2⟨v,s⟩`` — see DESIGN.md §2.
 
-Two data layouts (kernel *variants*):
+Every kernel reads S pre-transposed to ``(k, l, d)`` ("k-major"), the TPU
+analogue of the paper's round-robin interleave (§IV-B-2): vector *lanes* hold
+consecutive sets for a fixed k, and the k-th slice of an S tile is a
+leading-axis index. Two compute orders (kernel *variants*):
 
-* ``loop`` — S stays ``(l, k, d)``; the kernel loops over k, issuing one
-  ``(Bn,d)·(d,Bl)`` matmul per step with a running elementwise min.
-* ``flat`` — S is pre-transposed to ``(k, l, d)`` ("k-major"). This is the
-  TPU analogue of the paper's round-robin interleave (§IV-B-2): vector
-  *lanes* hold consecutive sets for a fixed k, so a single
-  ``(Bn, d)·(d, k·Bl)`` matmul computes every (set, k) pair at once and the
-  min over k is a clean sublane reduction of a ``(Bn, k, Bl)`` tile.
+* ``loop`` — the kernel loops over k, issuing one ``(Bn,d)·(d,Bl)`` matmul
+  per step with a running elementwise min (the paper's per-set loop).
+* ``flat`` — a single ``(Bn, d)·(d, k·Bl)`` matmul computes every (set, k)
+  pair at once and the min over k is a clean sublane reduction of a
+  ``(Bn, k, Bl)`` tile.
 
 Two reduction modes:
 
@@ -39,7 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.precision import PrecisionPolicy
+from repro.core.precision import PrecisionPolicy, matmul_precision
 
 _BIG = 3.0e38  # +inf stand-in that survives fp32 math
 
@@ -59,6 +60,7 @@ def _dist_tile(v, s, policy: PrecisionPolicy, rbf_gamma):
     """(Bn, d)×(B, d) → (Bn, B) squared distances via the MXU."""
     g = jax.lax.dot_general(
         v, s, (((1,), (1,)), ((), ())),
+        precision=matmul_precision(v.dtype),
         preferred_element_type=policy.accum_dtype,
     )
     vn = _sq_norms(v, policy.accum_dtype)
@@ -72,6 +74,31 @@ def _dist_tile(v, s, policy: PrecisionPolicy, rbf_gamma):
 # ---------------------------------------------------------------------------
 
 
+def _loop_min(v, s_ref, lens, e0, *, k: int, policy: PrecisionPolicy,
+              rbf_gamma, unroll: int):
+    """Running min over the k slices of a k-major ``(k, Bl, d)`` S tile.
+
+    Returns the ``(Bl, Bn)`` tile — rows are sets, as in the paper's W — with
+    ``lens`` a (Bl, 1) column and ``e0`` a (1, Bn) row, so no operand or
+    result needs a sublane↔lane relayout (Mosaic spends minutes and more
+    VMEM than the chip has on the transposed orientation).
+    """
+    minval = jnp.broadcast_to(e0.astype(policy.accum_dtype),
+                              (lens.shape[0], e0.shape[1]))
+
+    def body(kk, minval):
+        s = s_ref[kk].astype(policy.compute_dtype)        # (Bl, d)
+        d2 = _dist_tile(s, v, policy, rbf_gamma)          # (Bl, Bn)
+        d2 = jnp.where(kk < lens, d2, _BIG)
+        return jnp.minimum(minval, d2.astype(policy.accum_dtype))
+
+    if k <= unroll:
+        for kk in range(k):
+            minval = body(kk, minval)
+        return minval
+    return jax.lax.fori_loop(0, k, body, minval)
+
+
 def _fused_loop_kernel(v_ref, s_ref, len_ref, e0_ref, out_ref, *,
                        k: int, n_total: int, policy: PrecisionPolicy,
                        rbf_gamma, unroll: int):
@@ -82,27 +109,10 @@ def _fused_loop_kernel(v_ref, s_ref, len_ref, e0_ref, out_ref, *,
         out_ref[...] = jnp.zeros_like(out_ref)
 
     v = v_ref[...].astype(policy.compute_dtype)          # (Bn, d)
-    e0 = e0_ref[...].astype(policy.accum_dtype)          # (Bn, 1)
-    lens = len_ref[...][:, 0]                            # (Bl,)
-    bl = lens.shape[0]
-    bn = v.shape[0]
-    minval = jnp.broadcast_to(e0, (bn, bl))              # seed with d(v, e0)
-
-    def body(kk, minval):
-        s = s_ref[:, kk, :].astype(policy.compute_dtype)  # (Bl, d)
-        d2 = _dist_tile(v, s, policy, rbf_gamma)          # (Bn, Bl)
-        valid = (kk < lens)[None, :]
-        d2 = jnp.where(valid, d2, _BIG)
-        return jnp.minimum(minval, d2.astype(policy.accum_dtype))
-
-    if k <= unroll:
-        for kk in range(k):
-            minval = body(kk, minval)
-    else:
-        minval = jax.lax.fori_loop(0, k, body, minval)
-
-    partial = jnp.sum(minval.astype(jnp.float32), axis=0) / n_total  # (Bl,)
-    out_ref[...] += partial[:, None]
+    minval = _loop_min(v, s_ref, len_ref[...], e0_ref[...], k=k,
+                       policy=policy, rbf_gamma=rbf_gamma, unroll=unroll)
+    out_ref[...] += jnp.sum(minval.astype(jnp.float32), axis=1,
+                            keepdims=True) / n_total     # (Bl, 1)
 
 
 def _fused_flat_kernel(v_ref, s_ref, len_ref, e0_ref, out_ref, *,
@@ -141,27 +151,11 @@ def _fused_flat_kernel(v_ref, s_ref, len_ref, e0_ref, out_ref, *,
 def _two_pass_kernel(v_ref, s_ref, len_ref, e0_ref, w_ref, *,
                      k: int, n_total: int, policy: PrecisionPolicy,
                      rbf_gamma, unroll: int):
-    v = v_ref[...].astype(policy.compute_dtype)
-    e0 = e0_ref[...].astype(policy.accum_dtype)
-    lens = len_ref[...][:, 0]
-    bl = lens.shape[0]
-    bn = v.shape[0]
-    minval = jnp.broadcast_to(e0, (bn, bl))
-
-    def body(kk, minval):
-        s = s_ref[:, kk, :].astype(policy.compute_dtype)
-        d2 = _dist_tile(v, s, policy, rbf_gamma)
-        valid = (kk < lens)[None, :]
-        d2 = jnp.where(valid, d2, _BIG)
-        return jnp.minimum(minval, d2.astype(policy.accum_dtype))
-
-    if k <= unroll:
-        for kk in range(k):
-            minval = body(kk, minval)
-    else:
-        minval = jax.lax.fori_loop(0, k, body, minval)
-    # W[j, i] = min-dist / n (paper eq. 5) — note transpose: out rows are sets
-    w_ref[...] = (minval.T / n_total).astype(w_ref.dtype)
+    v = v_ref[...].astype(policy.compute_dtype)          # (Bn, d)
+    minval = _loop_min(v, s_ref, len_ref[...], e0_ref[...], k=k,
+                       policy=policy, rbf_gamma=rbf_gamma, unroll=unroll)
+    # W[j, i] = min-dist / n (paper eq. 5)
+    w_ref[...] = (minval / n_total).astype(w_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -171,9 +165,9 @@ def _two_pass_kernel(v_ref, s_ref, len_ref, e0_ref, w_ref, *,
 
 def fused_eval(
     V: jax.Array,            # (n_pad, d_pad)
-    S: jax.Array,            # loop: (l_pad, k, d_pad); flat: (k, l_pad, d_pad)
+    S: jax.Array,            # (k, l_pad, d_pad) k-major
     lengths: jax.Array,      # (l_pad, 1) int32
-    d_e0: jax.Array,         # (n_pad, 1) float32 (already transformed)
+    d_e0: jax.Array,         # flat: (n_pad, 1); loop: (1, n_pad) float32
     *,
     n_total: int,
     policy: PrecisionPolicy,
@@ -184,19 +178,22 @@ def fused_eval(
     unroll: int = 16,
     interpret: bool = False,
 ) -> jax.Array:
-    """Returns (l_pad, 1) float32 sums L(S_j ∪ {e0})."""
+    """Returns (l_pad, 1) float32 sums L(S_j ∪ {e0}).
+
+    ``d_e0`` (already transformed) is a column for ``flat``, whose tile is
+    (Bn, ·), and a row for ``loop``, whose tile is (Bl, Bn).
+    """
+    k, l_pad, d_pad = S.shape
     if variant == "flat":
-        k, l_pad, d_pad = S.shape
-        s_spec = pl.BlockSpec((k, block_l, d_pad), lambda i, j: (0, i, 0))
         kern = functools.partial(
             _fused_flat_kernel, k=k, n_total=n_total, policy=policy,
             rbf_gamma=rbf_gamma)
+        e0_spec = pl.BlockSpec((block_n, 1), lambda i, j: (j, 0))
     elif variant == "loop":
-        l_pad, k, d_pad = S.shape
-        s_spec = pl.BlockSpec((block_l, k, d_pad), lambda i, j: (i, 0, 0))
         kern = functools.partial(
             _fused_loop_kernel, k=k, n_total=n_total, policy=policy,
             rbf_gamma=rbf_gamma, unroll=unroll)
+        e0_spec = pl.BlockSpec((1, block_n), lambda i, j: (0, j))
     else:
         raise ValueError(f"unknown variant {variant!r}")
     n_pad = V.shape[0]
@@ -205,10 +202,10 @@ def fused_eval(
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_n, V.shape[1]), lambda i, j: (j, 0)),
-            s_spec,
+            pl.BlockSpec((block_n, d_pad), lambda i, j: (j, 0)),
+            pl.BlockSpec((k, block_l, d_pad), lambda i, j: (0, i, 0)),
             pl.BlockSpec((block_l, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_n, 1), lambda i, j: (j, 0)),
+            e0_spec,
         ],
         out_specs=pl.BlockSpec((block_l, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((l_pad, 1), jnp.float32),
@@ -218,9 +215,9 @@ def fused_eval(
 
 def two_pass_eval(
     V: jax.Array,            # (n_pad, d_pad)
-    S: jax.Array,            # (l_pad, k, d_pad)
+    S: jax.Array,            # (k, l_pad, d_pad) k-major
     lengths: jax.Array,      # (l_pad, 1)
-    d_e0: jax.Array,         # (n_pad, 1)
+    d_e0: jax.Array,         # (1, n_pad) row
     *,
     n_total: int,
     policy: PrecisionPolicy,
@@ -230,8 +227,12 @@ def two_pass_eval(
     unroll: int = 16,
     interpret: bool = False,
 ) -> jax.Array:
-    """Paper-faithful: materialize W (l_pad, n_pad) in HBM; caller reduces."""
-    l_pad, k, d_pad = S.shape
+    """Paper-faithful: materialize W (l_pad, n_pad) in HBM; caller reduces.
+
+    The W tile ``(block_l, block_n)`` is lane-dense: ``block_n`` must be a
+    multiple of 128 or the whole padded n (see ``ops.kernel_config``).
+    """
+    k, l_pad, d_pad = S.shape
     n_pad = V.shape[0]
     grid = (l_pad // block_l, n_pad // block_n)
     kern = functools.partial(
@@ -242,9 +243,9 @@ def two_pass_eval(
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, d_pad), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_l, k, d_pad), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((k, block_l, d_pad), lambda i, j: (0, i, 0)),
             pl.BlockSpec((block_l, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_n, 1), lambda i, j: (j, 0)),
+            pl.BlockSpec((1, block_n), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_l, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((l_pad, n_pad), jnp.float32),

@@ -105,6 +105,18 @@ def _fold_tile(cache, dw, fold: str, affine):
     return jnp.maximum(cache, jnp.maximum(a + b * dw.astype(jnp.float32), 0.0))
 
 
+def _winner_dist(v, w, policy: PrecisionPolicy, rbf_gamma):
+    """(Bn, d)×(1, d) → (Bn, 1) distances to the previous winner.
+
+    The winner row is tiled to one sublane group (8 rows) so the contraction
+    lowers to the same MXU matmul as the candidate tile. A lone row lowers
+    to a broadcast-multiply instead, whose half-precision → float32 widening
+    Mosaic rejects (``vector.broadcast`` element-type mismatch at bf16).
+    """
+    w8 = jnp.broadcast_to(w, (8, w.shape[1]))
+    return _dist_tile(v, w8, policy, rbf_gamma)[:, :1]
+
+
 def _gain_kernel(v_ref, c_ref, cache_ref, out_ref, *,
                  n_total: int, policy: PrecisionPolicy, rbf_gamma,
                  fold: str, affine):
@@ -169,7 +181,7 @@ def _gain_update_kernel(v_ref, c_ref, cache_ref, w_ref, wv_ref,
     v = v_ref[...].astype(policy.compute_dtype)      # (Bn, d)
     w = w_ref[...].astype(policy.compute_dtype)      # (1, d) previous winner
     cache = cache_ref[...].astype(jnp.float32)       # (Bn, 1)
-    dw = _dist_tile(v, w, policy, rbf_gamma)         # (Bn, 1)
+    dw = _winner_dist(v, w, policy, rbf_gamma)       # (Bn, 1)
     # w_valid gates the fold (round 0 has no winner; the max fold is not
     # idempotent, so an ungated seed row would corrupt the cache)
     new_cache = jnp.where(wv_ref[0, 0] > 0,
@@ -310,7 +322,7 @@ def _gain_update_kernel_batched(v_ref, c_ref, cache_ref, w_ref, wv_ref,
     v = v_ref[0].astype(policy.compute_dtype)        # (Bn, d)
     w = w_ref[0].astype(policy.compute_dtype)        # (1, d) request's winner
     cache = cache_ref[0].astype(jnp.float32)         # (Bn, 1)
-    dw = _dist_tile(v, w, policy, rbf_gamma)         # (Bn, 1)
+    dw = _winner_dist(v, w, policy, rbf_gamma)       # (Bn, 1)
     # per-request w_valid gate: requests whose previous round was masked
     # (ragged k) or round 0 must not fold
     new_cache = jnp.where(wv_ref[0, 0, 0] > 0,

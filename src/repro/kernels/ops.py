@@ -7,12 +7,15 @@ Handles everything the CUDA host code in the paper handles:
   ⌊1024/b_y⌋, ⌊β/γ⌋)`` from the shared-memory budget β — see
   :func:`kernel_config`.
 * **padding / layout** (paper's vectorization routine §IV-B-2): d is padded to
-  the 128-lane boundary, n/l to block multiples, and the ``flat`` variant
-  pre-transposes the multiset to k-major layout (the TPU analogue of
-  round-robin interleaving).
+  the 128-lane boundary, n/l to block multiples, and the multiset is
+  pre-transposed to k-major layout (the TPU analogue of round-robin
+  interleaving).
 * **chunking** (paper §IV-B-3): an optional memory budget splits the multiset.
-* **interpret fallback**: on CPU backends the kernels run in interpret mode
-  (bit-accurate Python execution of the kernel body).
+
+``interpret=True`` runs a kernel body in Python (bit-accurate, any backend);
+the default compiles it for the TPU. Nothing here picks interpret mode from
+the backend: callers choose it (``backend="pallas_interpret"``), so a run
+that was meant for the chip can never fall back to the interpreter unseen.
 """
 from __future__ import annotations
 
@@ -25,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.evaluator import plan_chunks
-from repro.core.precision import PrecisionPolicy, FP32
+from repro.core.precision import FP32, PrecisionPolicy, check_kernel_policy
 from repro.kernels import exemplar_eval as _ee
 from repro.kernels import marginal_gain as _mg
 
@@ -60,29 +63,36 @@ def kernel_config(k: int, d_pad: int, policy: PrecisionPolicy,
     ``mode="traffic_opt"`` (default, §Perf K4): minimize total HBM traffic
       T(Bn, Bl) = n·d·cs·⌈l/Bl⌉  (V re-read per l-tile row)
                 + l·k·d·cs·⌈n/Bn⌉ (S re-read per n-tile column)
-    subject to the VMEM working set (V tile + S tile + distance tile). The
+    subject to the VMEM working set: the V and S tiles twice (the Pallas
+    pipeline double-buffers inputs) and the f32 distance tile three times
+    (the matmul result, its masked copy and the min's operand live at once;
+    a v5e compile of the paper's size refused the single-count model). The
     paper's rule fixes Bn=256 and spends all VMEM on Bl, which over-weights
     the V term; for l·k ≫ n the S term dominates and a balanced split is up
     to ~1.9× less traffic (see benchmarks/kernel_roofline.py).
     """
     cs = policy.itemsize
-    cap_l = min(512, _round_up(l, SUBLANE))
+    # Bl is lane-dense: the flat variant splits its (Bn, k·Bl) distance
+    # tile into (Bn, k, Bl), which Mosaic reshapes only at whole lane rows
+    cap_l = min(512, _round_up(l, LANE))
     if mode == "paper":
         per_set = k * d_pad * cs
-        bl = max(s_budget_bytes // per_set, SUBLANE)
+        bl = max(s_budget_bytes // per_set, LANE)
         bl = min(bl, cap_l)
-        bl = (bl // SUBLANE) * SUBLANE
+        bl = (bl // LANE) * LANE
         return KernelConfig(block_n=min(256, _round_up(n, SUBLANE)), block_l=bl)
 
     vmem_cap = 3 * s_budget_bytes  # total working-set budget (~12 MiB)
     best, best_t = None, None
-    bn_opts = [b for b in (64, 128, 256, 512, 1024)
-               if b <= _round_up(n, SUBLANE)] or [SUBLANE]
-    bl_opts = [b for b in (8, 16, 32, 64, 128, 256, 512) if b <= cap_l] or [SUBLANE]
+    # the two-pass W tile (Bl, Bn) is lane-dense: Bn is a multiple of 128,
+    # or the whole padded n when n is smaller than one lane row
+    bn_opts = [b for b in (128, 256, 512, 1024)
+               if b <= _round_up(n, SUBLANE)] or [_round_up(n, SUBLANE)]
+    bl_opts = [b for b in (128, 256, 512) if b <= cap_l]
     for bn in bn_opts:
         for bl in bl_opts:
-            work = (bn * d_pad * cs + bl * k * d_pad * cs  # V + S tiles
-                    + bn * bl * k * 4)                     # distance tile
+            work = (2 * (bn * d_pad * cs + bl * k * d_pad * cs)  # V + S
+                    + 3 * bn * bl * k * 4)                # distance tile
             if work > vmem_cap:
                 continue
             traffic = (n * d_pad * cs * math.ceil(l / bl)
@@ -90,7 +100,7 @@ def kernel_config(k: int, d_pad: int, policy: PrecisionPolicy,
             if best_t is None or traffic < best_t:
                 best, best_t = (bn, bl), traffic
     if best is None:
-        best = (SUBLANE, SUBLANE)
+        best = (bn_opts[0], SUBLANE)
     return KernelConfig(block_n=best[0], block_l=best[1])
 
 
@@ -108,8 +118,9 @@ def _pad_axis(x: jax.Array, target: int, axis: int,
     return jnp.pad(x, widths, constant_values=value)
 
 
-def _is_cpu() -> bool:
-    return jax.default_backend() == "cpu"
+def _check_compiled_policy(policy: PrecisionPolicy, interpret: bool) -> None:
+    if not interpret:
+        check_kernel_policy(policy)
 
 
 # ---------------------------------------------------------------------------
@@ -133,20 +144,20 @@ def _exemplar_eval_padded(V, S, lengths, d_e0, *, policy, mode, variant,
 
     Vp = _pad_axis(_pad_axis(V, n_pad, 0), d_pad, 1)
     Sp = _pad_axis(_pad_axis(S, l_pad, 0), d_pad, 2)
+    Sp = jnp.transpose(Sp, (1, 0, 2))  # k-major (paper's interleave)
     lens_p = _pad_axis(lengths.astype(jnp.int32), l_pad, 0)[:, None]
-    e0_p = _pad_axis(d_e0.astype(jnp.float32), n_pad, 0)[:, None]
+    e0_p = _pad_axis(d_e0.astype(jnp.float32), n_pad, 0)
 
     if mode == "fused":
-        if variant == "flat":
-            Sp = jnp.transpose(Sp, (1, 0, 2))  # k-major (paper's interleave)
+        e0_t = e0_p[:, None] if variant == "flat" else e0_p[None, :]
         out = _ee.fused_eval(
-            Vp, Sp, lens_p, e0_p, n_total=n_total, policy=policy,
+            Vp, Sp, lens_p, e0_t, n_total=n_total, policy=policy,
             block_n=bn, block_l=bl, variant=variant, rbf_gamma=rbf_gamma,
             interpret=interpret)
         return out[:l, 0]
     elif mode == "two_pass":
         W = _ee.two_pass_eval(
-            Vp, Sp, lens_p, e0_p, n_total=n_total, policy=policy,
+            Vp, Sp, lens_p, e0_p[None, :], n_total=n_total, policy=policy,
             block_n=bn, block_l=bl, rbf_gamma=rbf_gamma, interpret=interpret)
         # second pass: the paper's W·1 row reduction
         return jnp.sum(W, axis=1)[:l]
@@ -162,13 +173,12 @@ def exemplar_eval(
     policy: PrecisionPolicy = FP32,
     mode: str = "fused",
     variant: str = "flat",
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
     memory_budget_bytes: Optional[int | str] = None,  # int | None | "auto"
     rbf_gamma: Optional[float] = None,
 ) -> jax.Array:
     """L(S_j ∪ {e0}) for the packed multiset — (l,) float32."""
-    if interpret is None:
-        interpret = _is_cpu()
+    _check_compiled_policy(policy, interpret)
     n, d = V.shape
     l, k, _ = S.shape
     d_pad = _round_up(d, LANE)
@@ -250,7 +260,7 @@ def marginal_gain(
     mincache: jax.Array,
     *,
     policy: PrecisionPolicy = FP32,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
     rbf_gamma: Optional[float] = None,
     block_n: int = 256,
     block_m: int = 256,
@@ -278,8 +288,7 @@ def marginal_gain(
     one launch scores all B requests with per-request block shapes identical
     to the unbatched path (bit-compatible per-request gains).
     """
-    if interpret is None:
-        interpret = _is_cpu()
+    _check_compiled_policy(policy, interpret)
     if V.ndim == 3:
         n = V.shape[1]
         bn = min(block_n, _round_up(n, SUBLANE))
@@ -346,7 +355,7 @@ def fused_gain_update(
     winner: jax.Array,       # (d,) previous round's winning candidate
     *,
     policy: PrecisionPolicy = FP32,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
     rbf_gamma: Optional[float] = None,
     block_n: int = 256,
     block_m: int = 256,
@@ -374,8 +383,7 @@ def fused_gain_update(
     doubles as the ragged-k gate (a request past its effective k passes 0
     and its cache stays frozen in-kernel).
     """
-    if interpret is None:
-        interpret = _is_cpu()
+    _check_compiled_policy(policy, interpret)
     if V.ndim == 3:
         n = V.shape[1]
         bn = min(block_n, _round_up(n, SUBLANE))
@@ -431,7 +439,7 @@ def sieve_gains(
     dvec: jax.Array,       # (n,) float32 one element's distances to V
     *,
     n_total: Optional[int] = None,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
     block_s: int = 64,
     block_n: int = 512,
     fold: str = "min",
@@ -454,8 +462,6 @@ def sieve_gains(
     relu(α − t) > 0 against finite rows, while a +inf column drives the
     affine to −inf before the relu.
     """
-    if interpret is None:
-        interpret = _is_cpu()
     r, n = table.shape
     bs = min(block_s, _round_up(r, SUBLANE))
     bn = min(block_n, _round_up(n, LANE))
@@ -478,7 +484,7 @@ def sieve_gains_batched(
     dvecs: jax.Array,      # (P, n) float32 per-partition element distances
     *,
     n_total: Optional[int] = None,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
     block_s: int = 64,
     block_n: int = 512,
     fold: str = "min",
@@ -494,8 +500,6 @@ def sieve_gains_batched(
     wrapper it is NOT jit-wrapped (it traces inside the batched per-block
     scan).
     """
-    if interpret is None:
-        interpret = _is_cpu()
     P, r, n = tables.shape
     bs = min(block_s, _round_up(r, SUBLANE))
     bn = min(block_n, _round_up(n, LANE))

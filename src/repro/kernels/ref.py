@@ -11,7 +11,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.precision import PrecisionPolicy, FP32
+from repro.core.precision import FP32, PrecisionPolicy, matmul_precision
 
 
 def _pair_sqdist(V, S, policy: PrecisionPolicy):
@@ -19,6 +19,7 @@ def _pair_sqdist(V, S, policy: PrecisionPolicy):
     Sc = S.astype(policy.compute_dtype)
     g = jax.lax.dot_general(
         Vc, Sc, (((1,), (1,)), ((), ())),
+        precision=matmul_precision(Vc.dtype),
         preferred_element_type=policy.accum_dtype,
     )
     vn = jnp.sum(Vc.astype(policy.accum_dtype) ** 2, -1)

@@ -22,7 +22,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.params import PLeaf, dense_init
@@ -251,7 +251,7 @@ def _decode_attn_seqshard(q, k_new, v_new, cache, pos, mask_kind, window,
                   P(dp, "model", None, None), P(), P()),
         out_specs=(P(dp, None, None, None), P(dp, "model", None, None),
                    P(dp, "model", None, None)),
-        check_rep=False,
+        check_vma=False,
     )
     out, kc, vc = smapped(q, k_new, v_new, cache["k"], cache["v"], slot,
                           jnp.asarray(pos, jnp.int32))

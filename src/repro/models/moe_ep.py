@@ -29,7 +29,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import NEG_INF, _act
@@ -127,6 +127,6 @@ def moe_ep(p, cfg, x, act: str, rules, capacity_factor: float | None = None):
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
         out_specs=P(dp_axes or None, "model", None),
-        check_rep=False,
+        check_vma=False,
     )
     return smapped(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])

@@ -15,8 +15,13 @@ def rng():
 
 
 def run_with_devices(code: str, n_devices: int = 8, timeout: int = 600) -> str:
-    """Run a python snippet in a subprocess with N host devices."""
+    """Run a python snippet in a subprocess with N host devices.
+
+    The child is held to the CPU: on a machine with a TPU the parent may
+    already hold the chip, and a child reaching for it would fail or hang.
+    """
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))

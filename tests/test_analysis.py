@@ -85,12 +85,12 @@ def test_scan_inside_loop_is_not_top_level():
 
 
 def _shmap(fn):
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = jax.make_mesh((jax.device_count(),), ("data",))
     return jax.jit(shard_map(fn, mesh=mesh, in_specs=(P("data"),),
-                             out_specs=P(None), check_rep=False))
+                             out_specs=P(), check_vma=False))
 
 
 def test_second_psum_detected():
@@ -142,14 +142,14 @@ def test_per_tenant_psum_migration_detected():
                 jnp.concatenate([g, stat[None]]), "data"))
         return jnp.stack(out)
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = jax.make_mesh((jax.device_count(),), ("data",))
 
     def _sh(fn):
         return jax.jit(shard_map(fn, mesh=mesh, in_specs=(P(None, "data"),),
-                                 out_specs=P(None, None), check_rep=False))
+                                 out_specs=P(None, None), check_vma=False))
 
     x = jax.ShapeDtypeStruct((B, 8 * jax.device_count()), np.float32)
     good = ja.collective_census(_artifact(_sh(stacked), x).jaxpr)

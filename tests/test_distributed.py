@@ -63,7 +63,7 @@ def test_ef_int8_psum_error_feedback():
         import jax, numpy as np
         import jax.numpy as jnp
         from functools import partial
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed.compression import ef_int8_psum, bf16_psum
 
@@ -73,7 +73,7 @@ def test_ef_int8_psum_error_feedback():
         exact = np.asarray(x).sum(0)
 
         @partial(shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
-                 out_specs=(P("data"), P("data")), check_rep=False)
+                 out_specs=(P("data"), P("data")), check_vma=False)
         def reduce_once(xs, err):
             y, e = ef_int8_psum(xs[0], err[0], "data")
             return y[None], e[None]
@@ -95,7 +95,7 @@ def test_ef_int8_psum_error_feedback():
         assert relT < rel1 / 2, (relT, rel1)
 
         @partial(shard_map, mesh=mesh, in_specs=P("data"),
-                 out_specs=P("data"), check_rep=False)
+                 out_specs=P("data"), check_vma=False)
         def reduce_bf16(xs):
             return bf16_psum(xs[0], "data")[None]
 
@@ -183,10 +183,11 @@ def test_flash_decoding_matches_full_forward():
         import jax, numpy as np, jax.numpy as jnp
         from repro.configs import get_reduced_config
         from repro.distributed.sharding import MeshRules
+        from repro.launch.mesh import make_mesh
         from repro.models.model import init_model, forward
 
         cfg = get_reduced_config("gemma3-1b")  # kv=1 → seqshard on 4-way TP
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = MeshRules.for_mesh(mesh)
         params, _ = init_model(cfg, jax.random.PRNGKey(0))
         B, S, PRE = 2, 24, 4   # window 16 → ring wraps during decode
