@@ -25,7 +25,9 @@ HBM = 819e9
 
 def kernel_terms(n, l, k, d, policy, fused: bool, mode: str = "traffic_opt"):
     d_pad = ((d + 127) // 128) * 128
-    cfgk = kernel_config(k, d_pad, policy, l, n, mode=mode)
+    # fused is modelled at the loop variant's tile: flat has none at k=500
+    cfgk = kernel_config(k, d_pad, policy, l, n, mode=mode,
+                         variant="loop" if fused else "two_pass")
     cs = policy.itemsize
     flops = 2.0 * n * l * k * d_pad              # Gram matmul (dominant)
     # HBM traffic: V read once per l-tile row; S read once per n-tile column

@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
 """Smoke run of the selection engine on the TPU that JAX finds.
 
-    python chip_smoke.py             # one chip: multiset evaluation, one-shot
-                                     # selection, serving, streaming
+    python chip_smoke.py             # one chip: multiset evaluation (k=10
+                                     # and across the paper's k sweep),
+                                     # one-shot selection, serving, streaming
     python chip_smoke.py --chips 4   # four chips: the mesh plans only
 
 Everything runs in this one process. Each phase drives the system through
@@ -90,16 +91,17 @@ def _same_selection(a, b) -> bool:
 
 
 def phase_multiset(n: int, l: int, k: int, d: int, backend: str,
-                   seed: int = 0) -> Phase:
+                   seed: int = 0, name: str = "multiset_eval") -> Phase:
     """Paper §V-A: L(S_j ∪ {e0}) for l sets of k over n points, on the
-    kernel backend (fused fp32 and bf16, two-pass fp32 as the paper's
-    reference) against the jnp backend at the same policy."""
+    kernel backend (fused fp32 and bf16 at the default variant, two-pass
+    fp32 as the paper's reference) against the jnp backend at the same
+    policy."""
     import jax.numpy as jnp
 
     from repro.core import EvalConfig, PackedMultiset, evaluate_multiset
     from repro.data.synthetic import uniform_problem
 
-    ph = Phase("multiset_eval", f"n={n} l={l} k={k} d={d}")
+    ph = Phase(name, f"n={n} l={l} k={k} d={d}")
     V = jnp.asarray(uniform_problem(n, d, seed=seed))
     idx = np.random.default_rng(seed + 1).integers(0, n, size=(l, k))
     pk = PackedMultiset(V[jnp.asarray(idx)], jnp.full((l,), k, jnp.int32))
@@ -366,11 +368,20 @@ def main(argv=None) -> int:
         phases = [("mesh_plans", lambda: phase_mesh(262_144, 128, 10,
                                                     "pallas"))]
     else:
-        from repro.configs.paper import CONFIG as PAPER
+        from repro.configs.paper import CONFIG as PAPER, SWEEPS
 
+        k_mid, k_max = SWEEPS["k"][1], SWEEPS["k"][-1]
         phases = [
             ("multiset_eval", lambda: phase_multiset(
                 PAPER.n, PAPER.l, PAPER.k, PAPER.dim, "pallas")),
+            # the paper's k sweep past the flat tile's VMEM limit (k≈39 at
+            # fp32), at the sweep's smallest l for the largest k
+            (f"multiset_eval_k{k_mid}", lambda: phase_multiset(
+                PAPER.n, PAPER.l, k_mid, PAPER.dim, "pallas",
+                name=f"multiset_eval_k{k_mid}")),
+            (f"multiset_eval_k{k_max}", lambda: phase_multiset(
+                PAPER.n, SWEEPS["l"][0], k_max, PAPER.dim, "pallas",
+                name=f"multiset_eval_k{k_max}")),
             ("one_shot_selection", lambda: phase_selection(
                 PAPER.n, PAPER.dim, PAPER.k, "pallas")),
             ("serving", lambda: phase_serving(8_192, 100, 64, "pallas")),

@@ -53,7 +53,8 @@ class KernelConfig:
 def kernel_config(k: int, d_pad: int, policy: PrecisionPolicy,
                   l: int, n: int,
                   s_budget_bytes: int = VMEM_S_BUDGET,
-                  mode: str = "traffic_opt") -> KernelConfig:
+                  mode: str = "traffic_opt",
+                  variant: str = "loop") -> KernelConfig:
     """Pick block dims (paper's b_x/b_y computation, then one step further).
 
     ``mode="paper"`` reproduces the paper's greedy rule: maximize the number
@@ -63,13 +64,20 @@ def kernel_config(k: int, d_pad: int, policy: PrecisionPolicy,
     ``mode="traffic_opt"`` (default, §Perf K4): minimize total HBM traffic
       T(Bn, Bl) = n·d·cs·⌈l/Bl⌉  (V re-read per l-tile row)
                 + l·k·d·cs·⌈n/Bn⌉ (S re-read per n-tile column)
-    subject to the VMEM working set: the V and S tiles twice (the Pallas
-    pipeline double-buffers inputs) and the f32 distance tile three times
-    (the matmul result, its masked copy and the min's operand live at once;
-    a v5e compile of the paper's size refused the single-count model). The
-    paper's rule fixes Bn=256 and spends all VMEM on Bl, which over-weights
-    the V term; for l·k ≫ n the S term dominates and a balanced split is up
-    to ~1.9× less traffic (see benchmarks/kernel_roofline.py).
+    subject to the VMEM working set of the kernel ``variant`` the tile is
+    for: the V and S tiles twice (the Pallas pipeline double-buffers
+    inputs) and the f32 distance tile three times (the matmul result, its
+    masked copy and the min's operand live at once; a v5e compile of the
+    paper's size refused the single-count model). ``"flat"`` holds all k
+    distance slices at once and needs a lane-dense Bl for its (Bn, k, Bl)
+    split; ``"loop"`` holds one slice; ``"two_pass"`` holds one slice and
+    writes a double-buffered (Bl, Bn) W tile. The paper's rule fixes Bn=256
+    and spends all VMEM on Bl, which over-weights the V term; for l·k ≫ n
+    the S term dominates and a balanced split is up to ~1.9× less traffic
+    (see benchmarks/kernel_roofline.py).
+
+    Raises ``ValueError`` when no tile of the variant fits the working-set
+    budget (``"flat"`` from k≈39 at fp32, d_pad=128).
     """
     cs = policy.itemsize
     # Bl is lane-dense: the flat variant splits its (Bn, k·Bl) distance
@@ -82,17 +90,26 @@ def kernel_config(k: int, d_pad: int, policy: PrecisionPolicy,
         bl = (bl // LANE) * LANE
         return KernelConfig(block_n=min(256, _round_up(n, SUBLANE)), block_l=bl)
 
+    if variant not in ("flat", "loop", "two_pass"):
+        raise ValueError(f"unknown variant {variant!r}")
     vmem_cap = 3 * s_budget_bytes  # total working-set budget (~12 MiB)
+    live_k = k if variant == "flat" else 1   # distance slices live at once
+    w_tiles = 2 if variant == "two_pass" else 0
     best, best_t = None, None
     # the two-pass W tile (Bl, Bn) is lane-dense: Bn is a multiple of 128,
     # or the whole padded n when n is smaller than one lane row
     bn_opts = [b for b in (128, 256, 512, 1024)
                if b <= _round_up(n, SUBLANE)] or [_round_up(n, SUBLANE)]
+    # one-slice variants keep Bl on sublanes, so large k may shrink it to
+    # one sublane tile of S: 8 rows at fp32, 16 at bf16
     bl_opts = [b for b in (128, 256, 512) if b <= cap_l]
+    if variant != "flat":
+        bl_opts = [b for b in (8, 16, 32, 64)
+                   if SUBLANE * 4 // cs <= b < cap_l] + bl_opts
     for bn in bn_opts:
         for bl in bl_opts:
             work = (2 * (bn * d_pad * cs + bl * k * d_pad * cs)  # V + S
-                    + 3 * bn * bl * k * 4)                # distance tile
+                    + (3 * live_k + w_tiles) * bn * bl * 4)  # f32 tiles
             if work > vmem_cap:
                 continue
             traffic = (n * d_pad * cs * math.ceil(l / bl)
@@ -100,8 +117,27 @@ def kernel_config(k: int, d_pad: int, policy: PrecisionPolicy,
             if best_t is None or traffic < best_t:
                 best, best_t = (bn, bl), traffic
     if best is None:
-        best = (bn_opts[0], SUBLANE)
+        raise ValueError(
+            f"no {variant!r} tile fits the {vmem_cap >> 20} MiB VMEM working "
+            f"set at k={k}, d_pad={d_pad}, {policy.name}"
+            + ("; variant 'loop' keeps one k slice live" if variant == "flat"
+               else ""))
     return KernelConfig(block_n=best[0], block_l=best[1])
+
+
+def multiset_tile(k: int, d_pad: int, policy: PrecisionPolicy, l: int,
+                  n: int, mode: str, variant: str) -> tuple[str, KernelConfig]:
+    """The multiset kernel variant and tile that run ``mode``/``variant``:
+    ``"flat"`` becomes ``"loop"`` where no flat tile fits VMEM (from k≈39
+    at fp32, d_pad=128); both compute the same minima."""
+    tile_variant = "two_pass" if mode == "two_pass" else variant
+    try:
+        return variant, kernel_config(k, d_pad, policy, l, n,
+                                      variant=tile_variant)
+    except ValueError:
+        if tile_variant != "flat":
+            raise
+    return "loop", kernel_config(k, d_pad, policy, l, n, variant="loop")
 
 
 def _round_up(x: int, m: int) -> int:
@@ -142,8 +178,10 @@ def _exemplar_eval_padded(V, S, lengths, d_e0, *, policy, mode, variant,
     l_pad = _round_up(l, bl)
     n_pad = _round_up(V.shape[0], bn)
 
-    Vp = _pad_axis(_pad_axis(V, n_pad, 0), d_pad, 1)
-    Sp = _pad_axis(_pad_axis(S, l_pad, 0), d_pad, 2)
+    # tiles reach VMEM at the compute dtype kernel_config sized them for
+    cdt = policy.compute_dtype
+    Vp = _pad_axis(_pad_axis(V.astype(cdt), n_pad, 0), d_pad, 1)
+    Sp = _pad_axis(_pad_axis(S.astype(cdt), l_pad, 0), d_pad, 2)
     Sp = jnp.transpose(Sp, (1, 0, 2))  # k-major (paper's interleave)
     lens_p = _pad_axis(lengths.astype(jnp.int32), l_pad, 0)[:, None]
     e0_p = _pad_axis(d_e0.astype(jnp.float32), n_pad, 0)
@@ -177,12 +215,13 @@ def exemplar_eval(
     memory_budget_bytes: Optional[int | str] = None,  # int | None | "auto"
     rbf_gamma: Optional[float] = None,
 ) -> jax.Array:
-    """L(S_j ∪ {e0}) for the packed multiset — (l,) float32."""
+    """L(S_j ∪ {e0}) for the packed multiset — (l,) float32. The variant
+    and tile come from :func:`multiset_tile`."""
     _check_compiled_policy(policy, interpret)
     n, d = V.shape
     l, k, _ = S.shape
     d_pad = _round_up(d, LANE)
-    cfgk = kernel_config(k, d_pad, policy, l, n)
+    variant, cfgk = multiset_tile(k, d_pad, policy, l, n, mode, variant)
     chunks = plan_chunks(l, n, k, d, policy, mode, memory_budget_bytes)
     outs = []
     for start, stop in chunks:

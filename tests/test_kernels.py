@@ -106,6 +106,36 @@ def test_kernel_config_respects_budget():
         assert cfgk.block_l % 8 == 0 and cfgk.block_n % 8 == 0
 
 
+def test_kernel_config_refuses_flat_tile_beyond_vmem():
+    """The flat variant keeps all k distance slices live: past k≈38 at fp32
+    no lane-dense tile fits, and it says so instead of handing Mosaic a
+    tile it refuses; the one-slice variants still find one."""
+    from repro.kernels.ops import kernel_config
+    with pytest.raises(ValueError, match="VMEM.*'loop'"):
+        kernel_config(45, 128, FP32, l=5_000, n=50_000, variant="flat")
+    for variant in ("loop", "two_pass"):
+        cfgk = kernel_config(500, 128, FP32, l=5_000, n=50_000,
+                             variant=variant)
+        assert cfgk.block_l % 8 == 0 and cfgk.block_n % 128 == 0
+        cfgk = kernel_config(500, 128, BF16, l=5_000, n=50_000,
+                             variant=variant)
+        assert cfgk.block_l % 16 == 0 and cfgk.block_n % 128 == 0
+
+
+def test_flat_past_vmem_runs_as_loop():
+    """exemplar_eval's default flat variant has no tile at k=80 (n=64):
+    it runs the loop kernel instead and still matches the oracle."""
+    from repro.kernels.ops import kernel_config
+    V, S, lengths, d_e0 = _problem(64, 8, 80, 8, seed=7)
+    with pytest.raises(ValueError, match="VMEM"):
+        kernel_config(80, 128, FP32, l=8, n=64, variant="flat")
+    want = ref.exemplar_eval_ref(V, S, lengths, d_e0)
+    got = ops.exemplar_eval(V, S, lengths, d_e0, mode="fused",
+                            variant="flat", interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
 def test_grid_covers_problem():
     """Paper eq. 8: the grid tiles the whole work matrix."""
     from repro.kernels.ops import kernel_config, _round_up

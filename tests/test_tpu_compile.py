@@ -106,9 +106,27 @@ def test_multiset_kernels_compile_at_paper_size(one_chip, mode, variant,
                                                 pname):
     """fused_eval (flat and loop) and the paper's two-pass W kernel, with
     the tiles ``kernel_config`` picks at N=50k, l=5k, k=10, d=100."""
-    policy = POLICY[pname]
-    n, l, k, d = PAPER["n"], PAPER["l"], PAPER["k"], PAPER["d"]
-    cfgk = ops.kernel_config(k, ops._round_up(d, ops.LANE), policy, l, n)
+    _compile_multiset(one_chip, mode, variant, POLICY[pname], PAPER["k"])
+
+
+@pytest.mark.parametrize("mode,variant,pname,k", [
+    ("fused", "flat", "fp32", 45), ("two_pass", "loop", "fp32", 45),
+    ("fused", "flat", "bf16", 45),
+    ("fused", "loop", "fp32", 500), ("fused", "loop", "bf16", 500),
+    ("two_pass", "loop", "fp32", 500)])
+def test_multiset_kernels_compile_across_paper_k_sweep(one_chip, mode,
+                                                       variant, pname, k):
+    """The paper sweeps k to 500 (configs/paper.py): there the S tile, not
+    the distance tile, bounds Bl, and the one-slice variants shrink it to
+    one sublane tile. Flat at fp32 has no tile from k≈39 and runs as loop
+    (``ops.multiset_tile``)."""
+    _compile_multiset(one_chip, mode, variant, POLICY[pname], k)
+
+
+def _compile_multiset(one_chip, mode, variant, policy, k):
+    n, l, d = PAPER["n"], PAPER["l"], PAPER["d"]
+    variant, cfgk = ops.multiset_tile(k, ops._round_up(d, ops.LANE), policy,
+                                      l, n, mode, variant)
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     lowered = ops._exemplar_eval_padded.lower(
         sds((n, d), jnp.float32), sds((l, k, d), jnp.float32),
