@@ -7,7 +7,6 @@ CSV rows: name,us_per_call,derived. Mapping to the paper:
   precision       — §V-B FP16 runtimes + the deferred quality question
   chunking        — §IV-B-3 memory-budgeted evaluation
   greedy_modes    — beyond-paper optimizer-aware greedy + engine modes
-  kernel_roofline — TPU roofline of the Pallas kernels at paper sizes
   optimizers      — §IV-A optimizer evaluation-count profile + engine plans
   streaming       — sieve family: per-element host loop vs device block offer
   functions       — zoo objectives through the shared engine at n ∈ {4k, 32k}
@@ -43,8 +42,7 @@ import traceback
 from repro.core.compile_cache import enable_compile_cache
 
 MODULES = ["sweeps", "precision", "chunking", "greedy_modes",
-           "kernel_roofline", "optimizers", "streaming", "functions",
-           "serving", "contracts"]
+           "optimizers", "streaming", "functions", "serving", "contracts"]
 
 
 def main() -> None:

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import distances as dist_mod
+from repro.core import tracing
 from repro.core.multiset import PackedMultiset
 from repro.core.precision import (PrecisionPolicy, check_kernel_policy,
                                   resolve as resolve_policy)
@@ -339,6 +340,7 @@ def e0_distances(
     return pair(V, e0[None, :], resolve_policy(policy))[:, 0]
 
 
+@partial(jax.profiler.annotate_function, name=tracing.EVALUATE_MULTISET)
 def evaluate_multiset(
     V: jax.Array,
     packed: PackedMultiset,
@@ -346,9 +348,13 @@ def evaluate_multiset(
     d_e0: Optional[jax.Array] = None,
     e0: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """L(S_j ∪ {e0}) for every set in the multiset. Returns (l,) float32."""
+    """L(S_j ∪ {e0}) for every set in the multiset. Returns (l,) float32.
+
+    The whole call, and the e0 column it computes when ``d_e0`` is not
+    given, are host spans (:mod:`repro.core.tracing`)."""
     if d_e0 is None:
-        d_e0 = e0_distances(V, e0, cfg.distance, cfg.policy)
+        with jax.profiler.TraceAnnotation(tracing.E0_DISTANCES):
+            d_e0 = e0_distances(V, e0, cfg.distance, cfg.policy)
     if cfg.backend == "jnp":
         out = _eval_jnp(V, packed, d_e0, cfg)
     elif cfg.backend == "naive":

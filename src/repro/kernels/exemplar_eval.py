@@ -210,6 +210,7 @@ def fused_eval(
         out_specs=pl.BlockSpec((block_l, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((l_pad, 1), jnp.float32),
         interpret=interpret,
+        name=f"exemplar_eval_{variant}",
     )(V, S, lengths, d_e0)
 
 
@@ -250,4 +251,5 @@ def two_pass_eval(
         out_specs=pl.BlockSpec((block_l, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((l_pad, n_pad), jnp.float32),
         interpret=interpret,
+        name="exemplar_eval_two_pass",
     )(V, S, lengths, d_e0)

@@ -165,6 +165,7 @@ def gain_eval(
         out_specs=pl.BlockSpec((block_m, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m_pad, 1), jnp.float32),
         interpret=interpret,
+        name="gain_eval",
     )(V, C, cache)
 
 
@@ -239,6 +240,7 @@ def gain_update_eval(
             jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
         ),
         interpret=interpret,
+        name="gain_update_eval",
     )(V, C, cache, winner, w_valid)
 
 
@@ -306,6 +308,7 @@ def gain_eval_batched(
         out_specs=pl.BlockSpec((1, block_m, 1), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, m_pad, 1), jnp.float32),
         interpret=interpret,
+        name="gain_eval_batched",
     )(V, C, cache)
 
 
@@ -383,6 +386,7 @@ def gain_update_eval_batched(
             jax.ShapeDtypeStruct((B, n_pad, 1), jnp.float32),
         ),
         interpret=interpret,
+        name="gain_update_eval_batched",
     )(V, C, cache, winner, w_valid)
 
 
@@ -439,6 +443,7 @@ def sieve_gain_eval(
         out_specs=pl.BlockSpec((block_s, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((s_pad, 1), jnp.float32),
         interpret=interpret,
+        name="sieve_gain_eval",
     )(T, dvec)
 
 
@@ -493,4 +498,5 @@ def sieve_gain_eval_batched(
         out_specs=pl.BlockSpec((1, block_s, 1), lambda p, i, j: (p, i, 0)),
         out_shape=jax.ShapeDtypeStruct((P, s_pad, 1), jnp.float32),
         interpret=interpret,
+        name="sieve_gain_eval_batched",
     )(T, dvec)

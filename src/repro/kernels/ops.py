@@ -27,6 +27,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core import tracing
 from repro.core.evaluator import plan_chunks
 from repro.core.precision import FP32, PrecisionPolicy, check_kernel_policy
 from repro.kernels import exemplar_eval as _ee
@@ -74,7 +75,8 @@ def kernel_config(k: int, d_pad: int, policy: PrecisionPolicy,
     writes a double-buffered (Bl, Bn) W tile. The paper's rule fixes Bn=256
     and spends all VMEM on Bl, which over-weights the V term; for l·k ≫ n
     the S term dominates and a balanced split is up to ~1.9× less traffic
-    (see benchmarks/kernel_roofline.py).
+    by this model. What the chip reaches is measured against the work of
+    the call from its shapes alone (bench/work.py, ``roofline.multiset``).
 
     Raises ``ValueError`` when no tile of the variant fits the working-set
     budget (``"flat"`` from k≈39 at fp32, d_pad=128).
@@ -202,6 +204,7 @@ def _exemplar_eval_padded(V, S, lengths, d_e0, *, policy, mode, variant,
     raise ValueError(f"unknown mode {mode!r}")
 
 
+@functools.partial(jax.profiler.annotate_function, name=tracing.EXEMPLAR_EVAL)
 def exemplar_eval(
     V: jax.Array,
     S: jax.Array,            # (l, k, d)
@@ -216,7 +219,8 @@ def exemplar_eval(
     rbf_gamma: Optional[float] = None,
 ) -> jax.Array:
     """L(S_j ∪ {e0}) for the packed multiset — (l,) float32. The variant
-    and tile come from :func:`multiset_tile`."""
+    and tile come from :func:`multiset_tile`. The whole call, plan,
+    slices and dispatch, is a host span (:mod:`repro.core.tracing`)."""
     _check_compiled_policy(policy, interpret)
     n, d = V.shape
     l, k, _ = S.shape

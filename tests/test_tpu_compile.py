@@ -13,6 +13,7 @@ and pytest-xdist workers all import every test file. Where it cannot be
 described the fixture skips.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -57,8 +58,20 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.fixture(scope="module")
+def compiled():
+    """Compiled program text by case, so that the kernel-name tests read
+    the programs the compile tests already built."""
+    return {}
+
+
 def _compiled_text(fn, *shapes) -> str:
     return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _has_kernel(text: str, name: str) -> bool:
+    """The compiled text holds the kernel ``name`` (``%name.1 = ...``)."""
+    return re.search(rf"%{re.escape(name)}(\.\d+)? = ", text) is not None
 
 
 def _gain_operands(sds, policy, batched):
@@ -73,7 +86,16 @@ def _gain_operands(sds, policy, batched):
 @pytest.mark.parametrize("kernel", ["gain_eval", "gain_update_eval",
                                     "gain_eval_batched",
                                     "gain_update_eval_batched"])
-def test_gain_kernels_compile_for_v5e(one_chip, kernel, pname, fold):
+def test_gain_kernels_compile_for_v5e(one_chip, compiled, kernel, pname,
+                                      fold):
+    assert "tpu_custom_call" in _compile_gain(one_chip, compiled, kernel,
+                                              pname, fold)
+
+
+def _compile_gain(one_chip, compiled, kernel, pname, fold) -> str:
+    key = ("gain", kernel, pname, fold)
+    if key in compiled:
+        return compiled[key]
     policy = POLICY[pname]
     batched = kernel.endswith("_batched")
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
@@ -85,28 +107,38 @@ def test_gain_kernels_compile_for_v5e(one_chip, kernel, pname, fold):
     fn = functools.partial(
         getattr(mg, kernel), n_total=N, policy=policy, block_n=BLOCK,
         block_m=BLOCK, fold=fold, affine=AFFINE[fold])
-    assert "tpu_custom_call" in _compiled_text(fn, *args)
+    compiled[key] = _compiled_text(fn, *args)
+    return compiled[key]
 
 
 @pytest.mark.parametrize("batched", [False, True])
-def test_sieve_kernels_compile_for_v5e(one_chip, batched):
+def test_sieve_kernels_compile_for_v5e(one_chip, compiled, batched):
+    assert "tpu_custom_call" in _compile_sieve(one_chip, compiled, batched)
+
+
+def _compile_sieve(one_chip, compiled, batched) -> str:
+    key = ("sieve", batched)
+    if key in compiled:
+        return compiled[key]
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     lead = (8,) if batched else ()
     kernel = mg.sieve_gain_eval_batched if batched else mg.sieve_gain_eval
     fn = functools.partial(kernel, n_total=N, block_s=64, block_n=512)
-    assert "tpu_custom_call" in _compiled_text(
+    compiled[key] = _compiled_text(
         fn, sds(lead + (128, N), jnp.float32), sds(lead + (1, N), jnp.float32))
+    return compiled[key]
 
 
 @pytest.mark.parametrize("mode,variant,pname", [
     ("fused", "flat", "fp32"), ("fused", "flat", "bf16"),
     ("fused", "loop", "fp32"), ("fused", "loop", "bf16"),
     ("two_pass", "loop", "fp32")])
-def test_multiset_kernels_compile_at_paper_size(one_chip, mode, variant,
-                                                pname):
+def test_multiset_kernels_compile_at_paper_size(one_chip, compiled, mode,
+                                                variant, pname):
     """fused_eval (flat and loop) and the paper's two-pass W kernel, with
     the tiles ``kernel_config`` picks at N=50k, l=5k, k=10, d=100."""
-    _compile_multiset(one_chip, mode, variant, POLICY[pname], PAPER["k"])
+    assert "tpu_custom_call" in _compile_multiset(
+        one_chip, compiled, mode, variant, pname, PAPER["k"])
 
 
 @pytest.mark.parametrize("mode,variant,pname,k", [
@@ -114,26 +146,67 @@ def test_multiset_kernels_compile_at_paper_size(one_chip, mode, variant,
     ("fused", "flat", "bf16", 45),
     ("fused", "loop", "fp32", 500), ("fused", "loop", "bf16", 500),
     ("two_pass", "loop", "fp32", 500)])
-def test_multiset_kernels_compile_across_paper_k_sweep(one_chip, mode,
-                                                       variant, pname, k):
+def test_multiset_kernels_compile_across_paper_k_sweep(one_chip, compiled,
+                                                       mode, variant, pname,
+                                                       k):
     """The paper sweeps k to 500 (configs/paper.py): there the S tile, not
     the distance tile, bounds Bl, and the one-slice variants shrink it to
     one sublane tile. Flat at fp32 has no tile from k≈39 and runs as loop
     (``ops.multiset_tile``)."""
-    _compile_multiset(one_chip, mode, variant, POLICY[pname], k)
+    assert "tpu_custom_call" in _compile_multiset(
+        one_chip, compiled, mode, variant, pname, k)
 
 
-def _compile_multiset(one_chip, mode, variant, policy, k):
+def _compile_multiset(one_chip, compiled, mode, variant, pname, k) -> str:
+    """The compiled text of the multiset program at the paper's size, with
+    the variant and tile ``ops.multiset_tile`` picks."""
     n, l, d = PAPER["n"], PAPER["l"], PAPER["d"]
+    policy = POLICY[pname]
     variant, cfgk = ops.multiset_tile(k, ops._round_up(d, ops.LANE), policy,
                                       l, n, mode, variant)
+    key = ("multiset", mode, variant, pname, k)
+    if key in compiled:
+        return compiled[key]
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     lowered = ops._exemplar_eval_padded.lower(
         sds((n, d), jnp.float32), sds((l, k, d), jnp.float32),
         sds((l,), jnp.int32), sds((n,), jnp.float32), policy=policy,
         mode=mode, variant=variant, interpret=False, rbf_gamma=None,
         n_total=n, cfgk=cfgk)
-    assert "tpu_custom_call" in lowered.compile().as_text()
+    compiled[key] = lowered.compile().as_text()
+    return compiled[key]
+
+
+@pytest.mark.parametrize("mode,variant,k,name", [
+    ("fused", "flat", 10, "exemplar_eval_flat"),
+    ("fused", "flat", 500, "exemplar_eval_loop"),
+    ("two_pass", "loop", 10, "exemplar_eval_two_pass")])
+def test_multiset_kernel_names_at_paper_size(one_chip, compiled, mode,
+                                             variant, k, name):
+    """Each multiset kernel carries its own name into the compiled program,
+    and so into the device trace: the flat variant at k=10, the loop
+    variant that flat becomes at k=500, the paper's two-pass W kernel."""
+    text = _compile_multiset(one_chip, compiled, mode, variant, "fp32", k)
+    assert _has_kernel(text, name)
+    others = {"exemplar_eval_flat", "exemplar_eval_loop",
+              "exemplar_eval_two_pass"} - {name}
+    assert not any(_has_kernel(text, o) for o in others)
+
+
+@pytest.mark.parametrize("kernel", ["gain_eval", "gain_update_eval",
+                                    "gain_eval_batched",
+                                    "gain_update_eval_batched"])
+def test_gain_kernel_names(one_chip, compiled, kernel):
+    """Each gain kernel is named after its function in the compiled
+    program (the fp32 min-fold program of the compile test above)."""
+    assert _has_kernel(_compile_gain(one_chip, compiled, kernel, "fp32",
+                                     "min"), kernel)
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_sieve_kernel_names(one_chip, compiled, batched):
+    name = "sieve_gain_eval_batched" if batched else "sieve_gain_eval"
+    assert _has_kernel(_compile_sieve(one_chip, compiled, batched), name)
 
 
 @pytest.mark.parametrize("pname", ["fp16", "fp16_strict"])
