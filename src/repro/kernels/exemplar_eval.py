@@ -13,23 +13,34 @@ This is the TPU-native version of the paper's GPU kernel (Algorithm 3):
 Every kernel reads S pre-transposed to ``(k, l, d)`` ("k-major"), the TPU
 analogue of the paper's round-robin interleave (§IV-B-2): vector *lanes* hold
 consecutive sets for a fixed k, and the k-th slice of an S tile is a
-leading-axis index. Two compute orders (kernel *variants*):
+leading-axis index. Two compute orders (kernel *variants*) share one tile
+body, which contracts a ``(Bn, d)`` V tile with a chunk of ``kc`` slices in
+a single ``(Bn, d)·(d, kc·Bl)`` matmul, splits the result into a
+``(Bn, kc, Bl)`` tile and takes a sublane min over kc:
 
-* ``loop`` — the kernel loops over k, issuing one ``(Bn,d)·(d,Bl)`` matmul
-  per step with a running elementwise min (the paper's per-set loop).
-* ``flat`` — a single ``(Bn, d)·(d, k·Bl)`` matmul computes every (set, k)
-  pair at once and the min over k is a clean sublane reduction of a
-  ``(Bn, k, Bl)`` tile.
+* ``flat`` — the chunk is all k slices: one grid step per (set, vector)
+  tile, on a ``(l_tiles, n_tiles)`` grid.
+* ``loop`` — k is tiled across a third, innermost grid axis in chunks of
+  ``kc`` slices (k zero-padded to a multiple of kc; the length mask drops
+  the pad), and a ``(Bn, Bl)`` VMEM scratch carries the running min from
+  chunk to chunk (the paper's per-set loop over k, a chunk at a time). The
+  set tile stays lane-dense (Bl ≥ 128) at any k: the S block is
+  ``(kc, Bl, d)``, not all k slices, and the V block does not change
+  across the chunks, so the pipeline copies it once per (set, vector)
+  tile.
 
 Two reduction modes:
 
 * ``fused`` (beyond paper) — the row-sum over n is accumulated across grid
   steps directly in the output block; W never reaches HBM.
 * ``two_pass`` (paper-faithful) — W tiles are written to HBM and reduced by a
-  second pass, exactly like the paper's ``W·1`` GEMV.
+  second pass, exactly like the paper's ``W·1`` GEMV. Its kernel loops over
+  the k slices of a whole-k ``(k, Bl, d)`` S tile, one ``(Bl,d)·(d,Bn)``
+  matmul per slice.
 
-Grid: ``(l_tiles, n_tiles)`` with n innermost so the fused accumulator block
-(indexed by the l tile only) stays resident while n streams past.
+Grid: l tiles, then n tiles (then k chunks for ``loop``), innermost last, so
+the fused accumulator block (indexed by the l tile only) stays resident
+while n and the chunks stream past.
 """
 from __future__ import annotations
 
@@ -39,6 +50,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.precision import PrecisionPolicy, matmul_precision
 
@@ -74,6 +86,77 @@ def _dist_tile(v, s, policy: PrecisionPolicy, rbf_gamma):
 # ---------------------------------------------------------------------------
 
 
+def _chunk_min(v, s3, len_ref, policy: PrecisionPolicy, rbf_gamma,
+               start=None):
+    """Masked min over the slices of a k-major ``(kc, Bl, d)`` S chunk
+    whose first slice is ``start`` (None: the first of k), against the
+    (Bl, 1) lengths. One matmul for all (set, slice) pairs; returns the
+    ``(Bn, Bl)`` tile."""
+    kk, bl, d = s3.shape
+    bn = v.shape[0]
+    s2 = s3.reshape(kk * bl, d)                          # merge leading dims
+    d2 = _dist_tile(v, s2, policy, rbf_gamma)            # (Bn, kc·Bl)
+    d2 = d2.reshape(bn, kk, bl)                          # lane dim (Bl) intact
+    lens = len_ref[...][:, 0]                            # (Bl,)
+    if start is not None:
+        lens = lens - start                              # slices left
+    kidx = jax.lax.broadcasted_iota(jnp.int32, (1, kk, bl), 1)
+    valid = kidx < lens[None, None, :]
+    d2 = jnp.where(valid, d2, _BIG)
+    return jnp.min(d2, axis=1)                           # (Bn, Bl)
+
+
+def _fused_loop_kernel(v_ref, s_ref, len_ref, e0_ref, out_ref, min_ref, *,
+                       n_total: int, policy: PrecisionPolicy, rbf_gamma):
+    """S tile is one (kc, Bl, d) chunk; ``min_ref`` carries the running
+    (Bn, Bl) min across the chunks of grid axis 2."""
+    j, c = pl.program_id(1), pl.program_id(2)
+    kc = s_ref.shape[0]
+
+    @pl.when((j == 0) & (c == 0))
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(c == 0)
+    def _seed():
+        e0 = e0_ref[...].astype(policy.accum_dtype)      # (Bn, 1)
+        min_ref[...] = jnp.broadcast_to(e0, min_ref.shape).astype(
+            min_ref.dtype)
+
+    v = v_ref[...].astype(policy.compute_dtype)          # (Bn, d)
+    s3 = s_ref[...].astype(policy.compute_dtype)         # (kc, Bl, d)
+    dmin = _chunk_min(v, s3, len_ref, policy, rbf_gamma, start=c * kc)
+    min_ref[...] = jnp.minimum(min_ref[...], dmin.astype(jnp.float32))
+
+    @pl.when(c == pl.num_programs(2) - 1)
+    def _fold():
+        partial = jnp.sum(min_ref[...], axis=0) / n_total
+        out_ref[...] += partial[:, None]
+
+
+def _fused_flat_kernel(v_ref, s_ref, len_ref, e0_ref, out_ref, *,
+                       n_total: int, policy: PrecisionPolicy, rbf_gamma):
+    """S tile is (k, Bl, d) "k-major": one matmul for all (set, k) pairs."""
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    v = v_ref[...].astype(policy.compute_dtype)          # (Bn, d)
+    s3 = s_ref[...].astype(policy.compute_dtype)         # (k, Bl, d)
+    dmin = _chunk_min(v, s3, len_ref, policy, rbf_gamma)
+    e0 = e0_ref[...].astype(dmin.dtype)                  # (Bn, 1)
+    dmin = jnp.minimum(dmin, e0)
+    partial = jnp.sum(dmin.astype(jnp.float32), axis=0) / n_total
+    out_ref[...] += partial[:, None]
+
+
+# ---------------------------------------------------------------------------
+# two-pass (paper-faithful) kernel: materialize W tiles
+# ---------------------------------------------------------------------------
+
+
 def _loop_min(v, s_ref, lens, e0, *, k: int, policy: PrecisionPolicy,
               rbf_gamma, unroll: int):
     """Running min over the k slices of a k-major ``(k, Bl, d)`` S tile.
@@ -99,55 +182,6 @@ def _loop_min(v, s_ref, lens, e0, *, k: int, policy: PrecisionPolicy,
     return jax.lax.fori_loop(0, k, body, minval)
 
 
-def _fused_loop_kernel(v_ref, s_ref, len_ref, e0_ref, out_ref, *,
-                       k: int, n_total: int, policy: PrecisionPolicy,
-                       rbf_gamma, unroll: int):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    v = v_ref[...].astype(policy.compute_dtype)          # (Bn, d)
-    minval = _loop_min(v, s_ref, len_ref[...], e0_ref[...], k=k,
-                       policy=policy, rbf_gamma=rbf_gamma, unroll=unroll)
-    out_ref[...] += jnp.sum(minval.astype(jnp.float32), axis=1,
-                            keepdims=True) / n_total     # (Bl, 1)
-
-
-def _fused_flat_kernel(v_ref, s_ref, len_ref, e0_ref, out_ref, *,
-                       k: int, n_total: int, policy: PrecisionPolicy,
-                       rbf_gamma):
-    """S tile is (k, Bl, d) "k-major": one matmul for all (set, k) pairs."""
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    v = v_ref[...].astype(policy.compute_dtype)          # (Bn, d)
-    s3 = s_ref[...].astype(policy.compute_dtype)         # (k, Bl, d)
-    kk, bl, d = s3.shape
-    bn = v.shape[0]
-    s2 = s3.reshape(kk * bl, d)                          # merge leading dims
-    d2 = _dist_tile(v, s2, policy, rbf_gamma)            # (Bn, k·Bl)
-    d2 = d2.reshape(bn, kk, bl)                          # lane dim (Bl) intact
-    lens = len_ref[...][:, 0]                            # (Bl,)
-    kidx = jax.lax.broadcasted_iota(jnp.int32, (1, kk, bl), 1)
-    valid = kidx < lens[None, None, :]
-    d2 = jnp.where(valid, d2, _BIG)
-    dmin = jnp.min(d2, axis=1)                           # (Bn, Bl)
-    e0 = e0_ref[...].astype(d2.dtype)                    # (Bn, 1)
-    dmin = jnp.minimum(dmin, e0)
-    partial = jnp.sum(dmin.astype(jnp.float32), axis=0) / n_total
-    out_ref[...] += partial[:, None]
-
-
-# ---------------------------------------------------------------------------
-# two-pass (paper-faithful) kernel: materialize W tiles
-# ---------------------------------------------------------------------------
-
-
 def _two_pass_kernel(v_ref, s_ref, len_ref, e0_ref, w_ref, *,
                      k: int, n_total: int, policy: PrecisionPolicy,
                      rbf_gamma, unroll: int):
@@ -165,52 +199,67 @@ def _two_pass_kernel(v_ref, s_ref, len_ref, e0_ref, w_ref, *,
 
 def fused_eval(
     V: jax.Array,            # (n_pad, d_pad)
-    S: jax.Array,            # (k, l_pad, d_pad) k-major
+    S: jax.Array,            # (k_pad, l_pad, d_pad) k-major
     lengths: jax.Array,      # (l_pad, 1) int32
-    d_e0: jax.Array,         # flat: (n_pad, 1); loop: (1, n_pad) float32
+    d_e0: jax.Array,         # (n_pad, 1) float32
     *,
     n_total: int,
     policy: PrecisionPolicy,
     block_n: int,
     block_l: int,
+    block_k: Optional[int] = None,
     variant: str = "flat",
     rbf_gamma: Optional[float] = None,
-    unroll: int = 16,
     interpret: bool = False,
 ) -> jax.Array:
     """Returns (l_pad, 1) float32 sums L(S_j ∪ {e0}).
 
-    ``d_e0`` (already transformed) is a column for ``flat``, whose tile is
-    (Bn, ·), and a row for ``loop``, whose tile is (Bl, Bn).
+    ``d_e0`` (already transformed) is a column. ``flat`` reads all k slices
+    per step; ``loop`` reads ``block_k`` (default all), which must divide
+    ``k_pad``.
     """
     k, l_pad, d_pad = S.shape
+    n_pad = V.shape[0]
     if variant == "flat":
         kern = functools.partial(
-            _fused_flat_kernel, k=k, n_total=n_total, policy=policy,
+            _fused_flat_kernel, n_total=n_total, policy=policy,
             rbf_gamma=rbf_gamma)
-        e0_spec = pl.BlockSpec((block_n, 1), lambda i, j: (j, 0))
-    elif variant == "loop":
-        kern = functools.partial(
-            _fused_loop_kernel, k=k, n_total=n_total, policy=policy,
-            rbf_gamma=rbf_gamma, unroll=unroll)
-        e0_spec = pl.BlockSpec((1, block_n), lambda i, j: (0, j))
-    else:
+        return pl.pallas_call(
+            kern,
+            grid=(l_pad // block_l, n_pad // block_n),
+            in_specs=[
+                pl.BlockSpec((block_n, d_pad), lambda i, j: (j, 0)),
+                pl.BlockSpec((k, block_l, d_pad), lambda i, j: (0, i, 0)),
+                pl.BlockSpec((block_l, 1), lambda i, j: (i, 0)),
+                pl.BlockSpec((block_n, 1), lambda i, j: (j, 0)),
+            ],
+            out_specs=pl.BlockSpec((block_l, 1), lambda i, j: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((l_pad, 1), jnp.float32),
+            interpret=interpret,
+            name="exemplar_eval_flat",
+        )(V, S, lengths, d_e0)
+    if variant != "loop":
         raise ValueError(f"unknown variant {variant!r}")
-    n_pad = V.shape[0]
-    grid = (l_pad // block_l, n_pad // block_n)
+    kc = k if block_k is None else block_k
+    if k % kc:
+        raise ValueError(f"block_k={kc} does not divide k_pad={k}")
+    kern = functools.partial(
+        _fused_loop_kernel, n_total=n_total, policy=policy,
+        rbf_gamma=rbf_gamma)
     return pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(l_pad // block_l, n_pad // block_n, k // kc),
         in_specs=[
-            pl.BlockSpec((block_n, d_pad), lambda i, j: (j, 0)),
-            pl.BlockSpec((k, block_l, d_pad), lambda i, j: (0, i, 0)),
-            pl.BlockSpec((block_l, 1), lambda i, j: (i, 0)),
-            e0_spec,
+            pl.BlockSpec((block_n, d_pad), lambda i, j, c: (j, 0)),
+            pl.BlockSpec((kc, block_l, d_pad), lambda i, j, c: (c, i, 0)),
+            pl.BlockSpec((block_l, 1), lambda i, j, c: (i, 0)),
+            pl.BlockSpec((block_n, 1), lambda i, j, c: (j, 0)),
         ],
-        out_specs=pl.BlockSpec((block_l, 1), lambda i, j: (i, 0)),
+        out_specs=pl.BlockSpec((block_l, 1), lambda i, j, c: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((l_pad, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_n, block_l), jnp.float32)],
         interpret=interpret,
-        name=f"exemplar_eval_{variant}",
+        name="exemplar_eval_loop",
     )(V, S, lengths, d_e0)
 
 

@@ -37,6 +37,11 @@ LANE = 128
 SUBLANE = 8
 #: default per-operand VMEM budget for the multiset tile (bytes)
 VMEM_S_BUDGET = 4 * 1024 * 1024
+#: seconds the loop variant takes per distance, per running-min element
+#: per k chunk, and per byte of the traffic model: a least-squares fit on
+#: a TPU v5e to 16 timed (Bn, Bl=128, kc) tiles at k=45, 100 and 500
+#: (fp32, N=50,000, l=5,000, d=100), every tile within 2.8% of the fit
+LOOP_COST_S = (8.3e-12, 15.4e-12, 0.35e-12)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,6 +50,7 @@ class KernelConfig:
 
     block_n: int   # ground vectors per tile (paper: b_x)
     block_l: int   # evaluation sets per tile (paper: b_y)
+    block_k: int   # k slices per grid step: all k, or a chunk ("loop")
 
     def grid(self, n_pad: int, l_pad: int) -> tuple[int, int]:
         # paper eq. 8: g_x = ⌈|V|/b_x⌉, g_y = ⌈|S_multi|/b_y⌉
@@ -67,64 +73,90 @@ def kernel_config(k: int, d_pad: int, policy: PrecisionPolicy,
                 + l·k·d·cs·⌈n/Bn⌉ (S re-read per n-tile column)
     subject to the VMEM working set of the kernel ``variant`` the tile is
     for: the V and S tiles twice (the Pallas pipeline double-buffers
-    inputs) and the f32 distance tile three times (the matmul result, its
-    masked copy and the min's operand live at once; a v5e compile of the
-    paper's size refused the single-count model). ``"flat"`` holds all k
-    distance slices at once and needs a lane-dense Bl for its (Bn, k, Bl)
-    split; ``"loop"`` holds one slice; ``"two_pass"`` holds one slice and
-    writes a double-buffered (Bl, Bn) W tile. The paper's rule fixes Bn=256
-    and spends all VMEM on Bl, which over-weights the V term; for l·k ≫ n
-    the S term dominates and a balanced split is up to ~1.9× less traffic
-    by this model. What the chip reaches is measured against the work of
-    the call from its shapes alone (bench/work.py, ``roofline.multiset``).
+    inputs) and the f32 distance tile three times per live slice (the
+    matmul result, its masked copy and the min's operand live at once; a
+    v5e compile of the paper's size refused the single-count model).
+    ``"flat"`` holds all k slices at once and needs a lane-dense Bl for its
+    (Bn, k, Bl) split; ``"two_pass"`` holds one slice of a whole-k S tile
+    and writes a double-buffered (Bl, Bn) W tile, so large k shrinks its Bl
+    to one sublane tile. The paper's rule fixes Bn=256 and spends all VMEM
+    on Bl, which over-weights the V term; for l·k ≫ n the S term dominates
+    and a balanced split is up to ~1.9× less traffic by this model.
+
+    ``"loop"`` runs flat's tile body on chunks of kc slices tiled across the
+    grid (k padded to k_pad, a multiple of kc), with one more (Bn, Bl) f32
+    tile for the running min, so its S tile is (kc, Bl, d) and Bl stays
+    lane-dense at any k. Its (Bn, Bl, kc) minimizes the time the chip was
+    measured to take (``LOOP_COST_S``): per distance n_pad·l_pad·k_pad,
+    per running-min element loaded and stored once per chunk
+    (n_pad·l_pad·k_pad/kc), and per byte of the traffic above at k_pad.
+    Smaller chunks pay the running min more often, larger tiles leave room
+    for fewer slices; at the paper's size and fp32 it picks (256, 128, 20)
+    at k=500. Ties go to the fewest grid steps. What the chip reaches is
+    measured against the work of the call from its shapes alone
+    (bench/work.py, ``roofline.multiset``).
 
     Raises ``ValueError`` when no tile of the variant fits the working-set
     budget (``"flat"`` from k≈39 at fp32, d_pad=128).
     """
     cs = policy.itemsize
-    # Bl is lane-dense: the flat variant splits its (Bn, k·Bl) distance
-    # tile into (Bn, k, Bl), which Mosaic reshapes only at whole lane rows
+    # Bl is lane-dense: the flat body splits its (Bn, kc·Bl) distance
+    # tile into (Bn, kc, Bl), which Mosaic reshapes only at whole lane rows
     cap_l = min(512, _round_up(l, LANE))
     if mode == "paper":
         per_set = k * d_pad * cs
         bl = max(s_budget_bytes // per_set, LANE)
         bl = min(bl, cap_l)
         bl = (bl // LANE) * LANE
-        return KernelConfig(block_n=min(256, _round_up(n, SUBLANE)), block_l=bl)
+        return KernelConfig(block_n=min(256, _round_up(n, SUBLANE)),
+                            block_l=bl, block_k=k)
 
     if variant not in ("flat", "loop", "two_pass"):
         raise ValueError(f"unknown variant {variant!r}")
     vmem_cap = 3 * s_budget_bytes  # total working-set budget (~12 MiB)
-    live_k = k if variant == "flat" else 1   # distance slices live at once
-    w_tiles = 2 if variant == "two_pass" else 0
-    best, best_t = None, None
+    best, best_key = None, None
     # the two-pass W tile (Bl, Bn) is lane-dense: Bn is a multiple of 128,
     # or the whole padded n when n is smaller than one lane row
     bn_opts = [b for b in (128, 256, 512, 1024)
                if b <= _round_up(n, SUBLANE)] or [_round_up(n, SUBLANE)]
-    # one-slice variants keep Bl on sublanes, so large k may shrink it to
-    # one sublane tile of S: 8 rows at fp32, 16 at bf16
     bl_opts = [b for b in (128, 256, 512) if b <= cap_l]
-    if variant != "flat":
+    if variant == "two_pass":
+        # one slice at a time keeps Bl on sublanes, so large k may shrink
+        # it to one sublane tile of S: 8 rows at fp32, 16 at bf16
         bl_opts = [b for b in (8, 16, 32, 64)
                    if SUBLANE * 4 // cs <= b < cap_l] + bl_opts
+    kc_opts = range(1, k + 1) if variant == "loop" else (k,)
     for bn in bn_opts:
         for bl in bl_opts:
-            work = (2 * (bn * d_pad * cs + bl * k * d_pad * cs)  # V + S
-                    + (3 * live_k + w_tiles) * bn * bl * 4)  # f32 tiles
-            if work > vmem_cap:
-                continue
-            traffic = (n * d_pad * cs * math.ceil(l / bl)
-                       + l * k * d_pad * cs * math.ceil(n / bn))
-            if best_t is None or traffic < best_t:
-                best, best_t = (bn, bl), traffic
+            for kc in kc_opts:
+                if variant == "two_pass":   # one live slice, W tile twice
+                    tiles = 3 + 2
+                else:                       # kc live slices (+ running min)
+                    tiles = 3 * kc + (variant == "loop")
+                work = (2 * (bn * d_pad * cs + bl * kc * d_pad * cs)  # V + S
+                        + tiles * bn * bl * 4)                # f32 tiles
+                if work > vmem_cap:
+                    break                   # grows with kc
+                k_pad = _round_up(k, kc)
+                traffic = (n * d_pad * cs * math.ceil(l / bl)
+                           + l * k_pad * d_pad * cs * math.ceil(n / bn))
+                steps = (math.ceil(l / bl) * math.ceil(n / bn)
+                         * (k_pad // kc))
+                cost = traffic
+                if variant == "loop":
+                    dists = _round_up(n, bn) * _round_up(l, bl) * k_pad
+                    per_dist, per_min, per_byte = LOOP_COST_S
+                    cost = (dists * (per_dist + per_min / kc)
+                            + traffic * per_byte)
+                if best_key is None or (cost, steps) < best_key:
+                    best, best_key = (bn, bl, kc), (cost, steps)
     if best is None:
         raise ValueError(
             f"no {variant!r} tile fits the {vmem_cap >> 20} MiB VMEM working "
             f"set at k={k}, d_pad={d_pad}, {policy.name}"
-            + ("; variant 'loop' keeps one k slice live" if variant == "flat"
+            + ("; variant 'loop' tiles k in chunks" if variant == "flat"
                else ""))
-    return KernelConfig(block_n=best[0], block_l=best[1])
+    return KernelConfig(block_n=best[0], block_l=best[1], block_k=best[2])
 
 
 def multiset_tile(k: int, d_pad: int, policy: PrecisionPolicy, l: int,
@@ -176,24 +208,25 @@ def _exemplar_eval_padded(V, S, lengths, d_e0, *, policy, mode, variant,
     l = lengths.shape[0]
     k = S.shape[1]
     d_pad = _round_up(S.shape[2], LANE)
-    bl, bn = cfgk.block_l, cfgk.block_n
+    bl, bn, bk = cfgk.block_l, cfgk.block_n, cfgk.block_k
     l_pad = _round_up(l, bl)
     n_pad = _round_up(V.shape[0], bn)
 
-    # tiles reach VMEM at the compute dtype kernel_config sized them for
+    # tiles reach VMEM at the compute dtype kernel_config sized them for;
+    # zero slices pad k to whole chunks, and the length mask drops them
     cdt = policy.compute_dtype
     Vp = _pad_axis(_pad_axis(V.astype(cdt), n_pad, 0), d_pad, 1)
     Sp = _pad_axis(_pad_axis(S.astype(cdt), l_pad, 0), d_pad, 2)
+    Sp = _pad_axis(Sp, _round_up(k, bk), 1)
     Sp = jnp.transpose(Sp, (1, 0, 2))  # k-major (paper's interleave)
     lens_p = _pad_axis(lengths.astype(jnp.int32), l_pad, 0)[:, None]
     e0_p = _pad_axis(d_e0.astype(jnp.float32), n_pad, 0)
 
     if mode == "fused":
-        e0_t = e0_p[:, None] if variant == "flat" else e0_p[None, :]
         out = _ee.fused_eval(
-            Vp, Sp, lens_p, e0_t, n_total=n_total, policy=policy,
-            block_n=bn, block_l=bl, variant=variant, rbf_gamma=rbf_gamma,
-            interpret=interpret)
+            Vp, Sp, lens_p, e0_p[:, None], n_total=n_total, policy=policy,
+            block_n=bn, block_l=bl, block_k=bk, variant=variant,
+            rbf_gamma=rbf_gamma, interpret=interpret)
         return out[:l, 0]
     elif mode == "two_pass":
         W = _ee.two_pass_eval(

@@ -60,6 +60,48 @@ def test_fused_pallas_matches_jnp_oracle_ragged(variant):
     np.testing.assert_allclose(got, oracle, atol=1e-4)
 
 
+# (n, l, k, d, block_k, lengths) through the wrapper's padding path with
+# the loop kernel's k chunk forced small: k zero-padded to whole chunks,
+# sets ending inside a chunk or before it, and e0 winning ("far": every
+# set one slice far from V).
+LOOP_CHUNK_CASES = {
+    "k_not_multiple_of_kc": (137, 13, 7, 19, 3, None),
+    "k_below_kc": (65, 9, 3, 129, 4, None),
+    "lengths_end_mid_chunk": (257, 21, 8, 33, 3, (2, 4, 5, 7, 8)),
+    "all_padding_chunk": (97, 11, 6, 17, 2, (1, 2)),
+    "singleton_e0_wins": (70, 5, 5, 9, 2, "far"),
+}
+
+
+@pytest.mark.parametrize("policy", ["fp32", "bf16"])
+@pytest.mark.parametrize("case", list(LOOP_CHUNK_CASES))
+def test_loop_chunks_match_jnp_oracle(case, policy):
+    from repro.core.precision import resolve as resolve_policy
+    from repro.kernels import ops
+
+    n, l, k, d, block_k, lengths = LOOP_CHUNK_CASES[case]
+    V, pk = _ragged_problem(n, l, k, d, seed=14)
+    if lengths == "far":
+        V = V - 2.0
+        pk = PackedMultiset(pk.data + 10.0, jnp.ones((l,), jnp.int32))
+    elif lengths is not None:
+        pk = PackedMultiset(pk.data, jnp.asarray(
+            np.resize(lengths, l).astype(np.int32)))
+    cfg = EvalConfig(mode="fused", policy=policy)
+    oracle = np.asarray(evaluate_multiset(V, pk, cfg))
+    d_e0 = jnp.sum(V ** 2, axis=1)
+    got = np.asarray(ops._exemplar_eval_padded(
+        V, pk.data, pk.lengths, d_e0, policy=resolve_policy(policy),
+        mode="fused", variant="loop", interpret=True, rbf_gamma=None,
+        n_total=n, cfgk=ops.KernelConfig(block_n=64, block_l=128,
+                                         block_k=block_k)))
+    np.testing.assert_allclose(got, oracle, rtol=2e-5,
+                               atol=POLICY_TOLS[policy]["kernel_atol"])
+    if lengths == "far":
+        np.testing.assert_allclose(got, np.mean(np.asarray(d_e0)),
+                                   rtol=1e-5)
+
+
 @pytest.mark.parametrize("policy", sorted(POLICY_TOLS))
 def test_gain_kernels_precision_sweep(policy):
     """marginal_gain + fused_gain_update at each PrecisionPolicy: the kernel

@@ -150,8 +150,9 @@ def test_multiset_kernels_compile_across_paper_k_sweep(one_chip, compiled,
                                                        mode, variant, pname,
                                                        k):
     """The paper sweeps k to 500 (configs/paper.py): there the S tile, not
-    the distance tile, bounds Bl, and the one-slice variants shrink it to
-    one sublane tile. Flat at fp32 has no tile from k≈39 and runs as loop
+    the distance tile, bounds Bl; the two-pass kernel's one-slice loop
+    shrinks it to one sublane tile, and the fused loop variant tiles k in
+    chunks instead. Flat at fp32 has no tile from k≈39 and runs as loop
     (``ops.multiset_tile``)."""
     assert "tpu_custom_call" in _compile_multiset(
         one_chip, compiled, mode, variant, pname, k)
@@ -191,6 +192,21 @@ def test_multiset_kernel_names_at_paper_size(one_chip, compiled, mode,
     others = {"exemplar_eval_flat", "exemplar_eval_loop",
               "exemplar_eval_two_pass"} - {name}
     assert not any(_has_kernel(text, o) for o in others)
+
+
+@pytest.mark.parametrize("pname,k", [("fp32", 45), ("fp32", 500),
+                                     ("bf16", 500)])
+def test_loop_kernel_chunks_k_at_paper_size(one_chip, compiled, pname, k):
+    """Past flat's VMEM limit the program runs ``exemplar_eval_loop`` on
+    a lane-dense set tile, k tiled across the grid in chunks."""
+    policy = POLICY[pname]
+    variant, cfgk = ops.multiset_tile(k, ops._round_up(PAPER["d"], ops.LANE),
+                                      policy, PAPER["l"], PAPER["n"],
+                                      "fused", "flat")
+    assert variant == "loop"
+    assert cfgk.block_l % ops.LANE == 0 and cfgk.block_k < k
+    text = _compile_multiset(one_chip, compiled, "fused", "flat", pname, k)
+    assert _has_kernel(text, "exemplar_eval_loop")
 
 
 @pytest.mark.parametrize("kernel", ["gain_eval", "gain_update_eval",
