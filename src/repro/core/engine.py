@@ -57,6 +57,7 @@ import numpy as np
 from repro.analysis.contracts import contract
 from repro.core import distances as dist_mod
 from repro.core import functions as fx
+from repro.core import tracing
 from repro.core.evaluator import free_memory_bytes
 from repro.core.functions import FnSpec, SubmodularFunction
 from repro.core.precision import resolve as resolve_policy
@@ -982,6 +983,7 @@ def _select_scan_batched(V, seed, row_aux, cand_rounds, w0, k_eff, *, fn,
 # ---------------------------------------------------------------------------
 
 
+@partial(jax.profiler.annotate_function, name=tracing.RUN_SELECTION)
 def run_selection(
     f: SubmodularFunction,
     *,
@@ -1015,54 +1017,61 @@ def run_selection(
     replicated pool runs under the sharded-cache callbacks; selections are
     *not* identical to host greedy but carry the GreeDi constant-factor
     guarantee).
+
+    The whole call, its preparation up to the dispatch and the read of the
+    result back to the host are host spans (:mod:`repro.core.tracing`).
     """
     if k == 0:
         return OptResult([], 0.0, [], 0)
-    fn = f.spec
-    if fn.name not in fx.DEVICE_PLAN_ELIGIBLE:
-        raise ValueError(
-            f"function {fn.name!r} has no n-aligned vec cache to shard or "
-            f"scan over — it runs on the host execution plans only")
-    n_cand = f.n if kind == "lazy" or cand_rounds is None \
-        else len(np.unique(cand_rounds[0] if kind == "dense" else cand_rounds))
-    if k > n_cand:
-        raise ValueError(
-            f"cannot select k={k} exemplars from {n_cand} distinct "
-            f"candidates — once every candidate is taken the argmax would "
-            f"silently re-select one")
-    policy = f.cfg.resolved_policy()
-    backend = f.cfg.backend if f.cfg.backend in ("pallas", "pallas_interpret") \
-        else "jnp"
-    if fx.kernel_template(fn) is None:
-        # no kernel form (saturated coverage): jnp scoring on any backend
-        backend = "jnp"
-    if backend != "jnp" and f.cfg.distance not in dist_mod.MXU_ELIGIBLE:
-        raise ValueError(
-            f"device plans with a pallas backend support "
-            f"{sorted(dist_mod.MXU_ELIGIBLE)}, got {f.cfg.distance!r}")
-    rbf_gamma = dist_mod.RBF_GAMMA \
-        if (backend != "jnp" and f.cfg.distance == "rbf") else None
-    w0 = f.e0 if f.e0 is not None else jnp.zeros((f.dim,), f.V.dtype)
+    with jax.profiler.TraceAnnotation(tracing.RUN_SELECTION_PREPARE):
+        fn = f.spec
+        if fn.name not in fx.DEVICE_PLAN_ELIGIBLE:
+            raise ValueError(
+                f"function {fn.name!r} has no n-aligned vec cache to shard "
+                f"or scan over — it runs on the host execution plans only")
+        n_cand = f.n if kind == "lazy" or cand_rounds is None else len(
+            np.unique(cand_rounds[0] if kind == "dense" else cand_rounds))
+        if k > n_cand:
+            raise ValueError(
+                f"cannot select k={k} exemplars from {n_cand} distinct "
+                f"candidates — once every candidate is taken the argmax "
+                f"would silently re-select one")
+        policy = f.cfg.resolved_policy()
+        backend = f.cfg.backend \
+            if f.cfg.backend in ("pallas", "pallas_interpret") else "jnp"
+        if fx.kernel_template(fn) is None:
+            # no kernel form (saturated coverage): jnp scoring on any backend
+            backend = "jnp"
+        if backend != "jnp" and f.cfg.distance not in dist_mod.MXU_ELIGIBLE:
+            raise ValueError(
+                f"device plans with a pallas backend support "
+                f"{sorted(dist_mod.MXU_ELIGIBLE)}, got {f.cfg.distance!r}")
+        rbf_gamma = dist_mod.RBF_GAMMA \
+            if (backend != "jnp" and f.cfg.distance == "rbf") else None
+        w0 = f.e0 if f.e0 is not None else jnp.zeros((f.dim,), f.V.dtype)
 
-    if kind == "lazy":
-        top_b = max(1, min(top_b or 256, f.n))
-        cand_rounds = np.zeros((1, 0), np.int32)
-        # lazy's widest scoring tile is the bound-seeding pass over all n
-        # candidates (per-round tiles are top_b ≤ n)
-        m_widest = f.n
-    elif cand_rounds is None:
-        raise ValueError(f"strategy {kind!r} needs cand_rounds")
-    else:
-        m_widest = cand_rounds.shape[1]
+        if kind == "lazy":
+            top_b = max(1, min(top_b or 256, f.n))
+            cand_rounds = np.zeros((1, 0), np.int32)
+            # lazy's widest scoring tile is the bound-seeding pass over all n
+            # candidates (per-round tiles are top_b ≤ n)
+            m_widest = f.n
+        elif cand_rounds is None:
+            raise ValueError(f"strategy {kind!r} needs cand_rounds")
+        else:
+            m_widest = cand_rounds.shape[1]
+
+        if plan == "device":
+            bm = block_m if block_m is not None \
+                else _device_block_m(f.n, m_widest)
+            # _select_scan donates the seed: copy it (f.cache_seed may alias
+            # the function's resident d_e0, which must survive this call)
+            seed = jnp.array(f.cache_seed)
+            cand = jnp.asarray(cand_rounds, jnp.int32)
 
     if plan == "device":
-        bm = block_m if block_m is not None \
-            else _device_block_m(f.n, m_widest)
-        # _select_scan donates the seed: copy it (f.cache_seed may alias
-        # the function's resident d_e0, which must survive this call)
         sel, traj, n_scored, _ = _select_scan(
-            f.V, jnp.array(f.cache_seed), f.row_aux,
-            jnp.asarray(cand_rounds, jnp.int32), w0,
+            f.V, seed, f.row_aux, cand, w0,
             fn=fn, kind=kind, k=k, top_b=top_b, distance=f.cfg.distance,
             policy_name=policy.name, block_m=bm, backend=backend,
             rbf_gamma=rbf_gamma, counter_key=counter_key)
@@ -1095,15 +1104,16 @@ def run_selection(
     else:
         raise ValueError(f"unknown execution plan {plan!r}")
 
-    sel = [int(x) for x in np.asarray(sel)]
-    if any(s < 0 for s in sel):
-        bad = sel.index(-1)
-        raise ValueError(
-            f"round {bad} had no untaken candidate (its sample row is "
-            f"exhausted by earlier selections) — the argmax would silently "
-            f"re-select a taken index")
-    traj = [float(x) for x in np.asarray(traj)]
-    return OptResult(sel, traj[-1] if traj else 0.0, traj, int(n_scored))
+    with jax.profiler.TraceAnnotation(tracing.RUN_SELECTION_FETCH):
+        sel = [int(x) for x in np.asarray(sel)]
+        if any(s < 0 for s in sel):
+            bad = sel.index(-1)
+            raise ValueError(
+                f"round {bad} had no untaken candidate (its sample row is "
+                f"exhausted by earlier selections) — the argmax would "
+                f"silently re-select a taken index")
+        traj = [float(x) for x in np.asarray(traj)]
+        return OptResult(sel, traj[-1] if traj else 0.0, traj, int(n_scored))
 
 
 def _stack_batch_payload(fs: Sequence[SubmodularFunction]) -> dict:

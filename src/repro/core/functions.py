@@ -70,6 +70,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import distances as dist_mod
+from repro.core import tracing
 from repro.core.evaluator import EvalConfig, e0_distances, evaluate_multiset
 from repro.core.multiset import PackedMultiset, pack_base_plus_candidates, pack_sets
 from repro.core.precision import resolve as resolve_policy
@@ -581,6 +582,9 @@ class ExemplarClustering(SubmodularFunction):
 
     spec = FnSpec(name="exemplar")
 
+    # a host span (:mod:`repro.core.tracing`): the e0 column and the read
+    # of L({e0}) wait for the device
+    @partial(jax.profiler.annotate_function, name=tracing.FUNCTION_INIT)
     def __init__(self, V: jax.Array, cfg: EvalConfig = EvalConfig(),
                  e0: Optional[jax.Array] = None):
         super().__init__(V, cfg, e0)

@@ -1,10 +1,11 @@
 """The program's host spans (``repro.core.tracing``), read back from a
-profiler trace of ``evaluate_multiset`` on the CPU.
+profiler trace of ``evaluate_multiset`` and of device ``greedy`` on the
+CPU.
 
 The spans land in the trace's ``/host:CPU`` plane under their names, on
 the same clock as the device's operations; the benchmark's per-layer
 metrics read them there, so each must open once per call and the child
-spans must lie inside ``evaluate_multiset``."""
+spans must lie inside ``evaluate_multiset`` or ``run_selection``."""
 import glob
 import os
 
@@ -13,7 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import EvalConfig, evaluate_multiset, pack_sets, tracing
+from repro.core import (EvalConfig, ExemplarClustering, evaluate_multiset,
+                        greedy, pack_sets, tracing)
 from repro.core.evaluator import e0_distances
 
 CALLS = 2
@@ -61,9 +63,10 @@ def test_each_span_opens_once_per_call(tmp_path, backend):
               for name in tracing.SPANS}
     # only the kernel backends go through kernels.ops.exemplar_eval
     kernel = CALLS if backend == "pallas_interpret" else 0
-    assert counts == {tracing.EVALUATE_MULTISET: CALLS,
-                      tracing.E0_DISTANCES: CALLS,
-                      tracing.EXEMPLAR_EVAL: kernel}
+    # no selection span opens inside the evaluator
+    assert counts == dict.fromkeys(tracing.SPANS, 0) | {
+        tracing.EVALUATE_MULTISET: CALLS, tracing.E0_DISTANCES: CALLS,
+        tracing.EXEMPLAR_EVAL: kernel}
     outer = [(s, e) for s, e, n in spans if n == tracing.EVALUATE_MULTISET]
     for s, e, name in spans:
         if name == tracing.EVALUATE_MULTISET:
@@ -81,3 +84,35 @@ def test_no_e0_span_when_the_caller_passes_d_e0(tmp_path, backend):
     names = [n for *_, n in spans]
     assert tracing.E0_DISTANCES not in names
     assert names.count(tracing.EVALUATE_MULTISET) == CALLS
+
+
+def _traced_selections(tmp_path, V, cfg, k=3):
+    greedy(ExemplarClustering(V, cfg), k, mode="device")  # compile
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(CALLS):
+            greedy(ExemplarClustering(V, cfg), k, mode="device")
+    return _spans(tmp_path)
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas_interpret"])
+def test_selection_spans_open_once_per_call(tmp_path, backend):
+    V, _ = _problem(seed=2)
+    spans = _traced_selections(tmp_path, V, EvalConfig(backend=backend))
+    counts = {name: sum(1 for *_, n in spans if n == name)
+              for name in tracing.SPANS}
+    assert counts == dict.fromkeys(tracing.SPANS, 0) | {
+        tracing.RUN_SELECTION: CALLS, tracing.RUN_SELECTION_PREPARE: CALLS,
+        tracing.RUN_SELECTION_FETCH: CALLS, tracing.FUNCTION_INIT: CALLS}
+    outer = [(s, e) for s, e, n in spans if n == tracing.RUN_SELECTION]
+    for s, e, name in spans:
+        inside = [o for o in outer if o[0] <= s and e <= o[1]]
+        if name in (tracing.RUN_SELECTION_PREPARE,
+                    tracing.RUN_SELECTION_FETCH):
+            assert len(inside) == 1, (name, s, e, outer)
+        elif name == tracing.FUNCTION_INIT:
+            # the function is built before the selection starts
+            assert not inside, (name, s, e, outer)
+    # in each call, the preparation ends before the fetch starts
+    prepare = [e for _, e, n in spans if n == tracing.RUN_SELECTION_PREPARE]
+    fetch = [s for s, _, n in spans if n == tracing.RUN_SELECTION_FETCH]
+    assert all(p <= f for p, f in zip(prepare, fetch))
