@@ -250,8 +250,8 @@ def _make_fold_and_score(V, pair, policy, backend, rbf_gamma, block_m,
 
         def fold_and_score(vec, w_row, w_ok, C):
             # block_m only sizes the jnp streaming block (HBM working set);
-            # the kernel tiles its own VMEM blocks and never materializes
-            # the (n, m) matrix, so it keeps its default tile size
+            # the kernel never materializes the (n, m) matrix and takes
+            # its VMEM tile from ops.gain_tile
             return kops.fused_gain_update(
                 V, C, vec, w_row, policy=policy, rbf_gamma=rbf_gamma,
                 fold=tmpl[0], score_affine=tmpl[1], n_total=n_total,

@@ -42,6 +42,17 @@ VMEM_S_BUDGET = 4 * 1024 * 1024
 #: a TPU v5e to 16 timed (Bn, Bl=128, kc) tiles at k=45, 100 and 500
 #: (fp32, N=50,000, l=5,000, d=100), every tile within 2.8% of the fit
 LOOP_COST_S = (8.3e-12, 15.4e-12, 0.35e-12)
+#: the gain kernels' tile edges (Bn and Bm), the range GAIN_COST_S was
+#: fitted over
+GAIN_TILES = (256, 512, 1024, 2048)
+#: seconds the fused gain kernel takes per distance, per grid step, per
+#: ground-set row per candidate tile (n_pad·m_tiles: V, its norms, the
+#: winner's column and the cache, once per step) and per candidate row
+#: per ground-set tile (m_pad·n_tiles: C, its norms and the gain column):
+#: a least-squares fit on a TPU v5e to 16 timed (Bn, Bm) tiles of
+#: ``gain_update_eval`` at n = m = 50,000 (fp32, d=100, min fold), every
+#: tile within 1.4% of the fit (6.4% at m = 5,000, which it reads low)
+GAIN_COST_S = (7.88e-12, 0.229e-6, 1.19e-9, 0.040e-9)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -172,6 +183,69 @@ def multiset_tile(k: int, d_pad: int, policy: PrecisionPolicy, l: int,
         if tile_variant != "flat":
             raise
     return "loop", kernel_config(k, d_pad, policy, l, n, variant="loop")
+
+
+def gain_working_set(block_n: int, block_m: int, d_pad: int,
+                     policy: PrecisionPolicy) -> int:
+    """VMEM bytes the gain kernels hold at tile (Bn, Bm): the V and C
+    tiles twice (the pipeline double-buffers them) and f32 columns at 128
+    lanes, which is how VMEM holds a (B, 1) block: ten of Bn rows (the
+    cache in and out, twice each, and the kernel's own columns: norms,
+    the winner's distances, the folded cache) and three of Bm (the gains
+    twice, and their row sum). The (Bn, Bm) distance tile takes no copy.
+    Both match what a v5e compile of the batched fused kernel asks for at
+    fp32, d_pad=128, to 0.125 MiB: 8.4 MiB at 1024×1024, 14.5 MiB at
+    2048×1024 and 17.0 MiB at 2048×2048, of which the distance tile alone
+    would be 16 MiB; the unbatched kernel asks for less (5.4 and 11.0 MiB
+    at the squares).
+    """
+    return (2 * (block_n + block_m) * d_pad * policy.itemsize
+            + (10 * block_n + 3 * block_m) * LANE * 4)
+
+
+def gain_tile(n: int, m: int, d_pad: int,
+              policy: PrecisionPolicy) -> tuple[int, int]:
+    """(Bn, Bm) of the gain kernels for n ground-set rows and m candidates.
+
+    Minimizes the time fitted on the chip (``GAIN_COST_S``: per distance
+    n_pad·m_pad, per grid step, per ground-set row each candidate tile
+    reads again and per candidate row each ground-set tile reads again)
+    over the tile edges ``GAIN_TILES``, each clamped to the padded
+    problem (``_round_up(n, 8)``, ``_round_up(m, 8)``), with the working
+    set of :func:`gain_working_set` under the multiset plan's cap (3 ×
+    ``VMEM_S_BUDGET``, 12 MiB; a v5e kernel may take 16). Larger tiles
+    take fewer steps and re-read less, until the working set leaves
+    VMEM. Ties go to the fewest steps. Where no tile fits (d in the
+    thousands) the smallest is returned. Every gain kernel, unbatched,
+    batched and on a mesh shard, tiles by this one plan, so equal shapes
+    score in one accumulation order.
+    """
+    bn_opts = sorted({min(b, _round_up(n, SUBLANE)) for b in GAIN_TILES})
+    bm_opts = sorted({min(b, _round_up(m, SUBLANE)) for b in GAIN_TILES})
+    per_dist, per_step, per_v_row, per_c_row = GAIN_COST_S
+    best, best_key = (bn_opts[0], bm_opts[0]), None
+    for bn in bn_opts:
+        for bm in bm_opts:
+            if gain_working_set(bn, bm, d_pad, policy) > 3 * VMEM_S_BUDGET:
+                continue
+            n_tiles, m_tiles = -(-n // bn), -(-m // bm)
+            steps = n_tiles * m_tiles
+            cost = (steps * (bn * bm * per_dist + per_step
+                             + bn * per_v_row + bm * per_c_row))
+            if best_key is None or (cost, steps) < best_key:
+                best, best_key = (bn, bm), (cost, steps)
+    return best
+
+
+def _gain_blocks(n: int, m: int, d: int, policy: PrecisionPolicy,
+                 block_n: Optional[int], block_m: Optional[int]
+                 ) -> tuple[int, int]:
+    """The gain kernels' (Bn, Bm): :func:`gain_tile`, or an explicit
+    override clamped to the padded problem as the plan's tiles are."""
+    plan = gain_tile(n, m, _round_up(d, LANE), policy)
+    bn = plan[0] if block_n is None else min(block_n, _round_up(n, SUBLANE))
+    bm = plan[1] if block_m is None else min(block_m, _round_up(m, SUBLANE))
+    return bn, bm
 
 
 def _round_up(x: int, m: int) -> int:
@@ -338,8 +412,8 @@ def marginal_gain(
     policy: PrecisionPolicy = FP32,
     interpret: bool = False,
     rbf_gamma: Optional[float] = None,
-    block_n: int = 256,
-    block_m: int = 256,
+    block_n: Optional[int] = None,
+    block_m: Optional[int] = None,
     n_total: Optional[int] = None,
     fold: str = "min",
     score_affine: Optional[tuple] = None,
@@ -363,20 +437,22 @@ def marginal_gain(
     ``mincache (B, n)`` and the call routes to the grid-over-B kernel —
     one launch scores all B requests with per-request block shapes identical
     to the unbatched path (bit-compatible per-request gains).
+
+    The tile is :func:`gain_tile`'s for (n, m); ``block_n``/``block_m``
+    override it.
     """
     _check_compiled_policy(policy, interpret)
     if V.ndim == 3:
         n = V.shape[1]
-        bn = min(block_n, _round_up(n, SUBLANE))
-        bm = min(block_m, _round_up(C.shape[1], SUBLANE))
+        bn, bm = _gain_blocks(n, C.shape[1], V.shape[2], policy, block_n,
+                              block_m)
         return _marginal_gain_padded_batched(
             V, C, mincache, policy=policy, interpret=interpret,
             rbf_gamma=rbf_gamma, n_total=n_total if n_total is not None else n,
             block_n=bn, block_m=bm, fold=fold,
             score_affine=None if score_affine is None else tuple(score_affine))
     n = V.shape[0]
-    bn = min(block_n, _round_up(n, SUBLANE))
-    bm = min(block_m, _round_up(C.shape[0], SUBLANE))
+    bn, bm = _gain_blocks(n, C.shape[0], V.shape[1], policy, block_n, block_m)
     return _marginal_gain_padded(
         V, C, mincache, policy=policy, interpret=interpret,
         rbf_gamma=rbf_gamma, n_total=n_total if n_total is not None else n,
@@ -433,8 +509,8 @@ def fused_gain_update(
     policy: PrecisionPolicy = FP32,
     interpret: bool = False,
     rbf_gamma: Optional[float] = None,
-    block_n: int = 256,
-    block_m: int = 256,
+    block_n: Optional[int] = None,
+    block_m: Optional[int] = None,
     n_total: Optional[int] = None,
     fold: str = "min",
     score_affine: Optional[tuple] = None,
@@ -458,12 +534,15 @@ def fused_gain_update(
     launch folds+scores all B requests; the per-request ``w_valid`` lane
     doubles as the ragged-k gate (a request past its effective k passes 0
     and its cache stays frozen in-kernel).
+
+    The tile is :func:`gain_tile`'s for (n, m); ``block_n``/``block_m``
+    override it.
     """
     _check_compiled_policy(policy, interpret)
     if V.ndim == 3:
         n = V.shape[1]
-        bn = min(block_n, _round_up(n, SUBLANE))
-        bm = min(block_m, _round_up(C.shape[1], SUBLANE))
+        bn, bm = _gain_blocks(n, C.shape[1], V.shape[2], policy, block_n,
+                              block_m)
         if w_valid is None:
             w_valid = jnp.ones((V.shape[0],), jnp.float32)
         return _fused_gain_update_padded_batched(
@@ -473,8 +552,7 @@ def fused_gain_update(
             block_n=bn, block_m=bm, fold=fold,
             score_affine=None if score_affine is None else tuple(score_affine))
     n = V.shape[0]
-    bn = min(block_n, _round_up(n, SUBLANE))
-    bm = min(block_m, _round_up(C.shape[0], SUBLANE))
+    bn, bm = _gain_blocks(n, C.shape[0], V.shape[1], policy, block_n, block_m)
     if w_valid is None:
         w_valid = jnp.float32(1.0)
     return _fused_gain_update_padded(

@@ -197,6 +197,120 @@ def test_max_template_gain_kernels_precision_sweep(policy):
                                atol=tol["kernel_atol"])
 
 
+#: n and m larger than the planned gain tile and no multiple of it
+GAIN_PLAN_SHAPE = (2600, 2300, 19)
+
+
+def _gain_plan_problem(fold):
+    """V, C, cache, winner and kernel keywords of the min template
+    (sqeuclidean) or the max template (rbf similarity) at
+    ``GAIN_PLAN_SHAPE``, with the fp32 jnp fold-and-score reference."""
+    from repro.core import distances as dist_mod
+    from repro.core.functions import SIM_ALPHA, SIM_BETA
+    from repro.core.precision import FP32
+
+    rng = np.random.default_rng(29)
+    n, m, d = GAIN_PLAN_SHAPE
+    if fold == "min":
+        V = jnp.asarray((rng.normal(size=(n, d)) + 1.5).astype(np.float32))
+        cache = jnp.asarray(rng.uniform(1.0, 5.0, size=n).astype(np.float32))
+        kw = dict(rbf_gamma=None, fold="min", score_affine=None)
+        pair = dist_mod.resolve_pairwise("sqeuclidean")
+    else:
+        V = jnp.asarray((rng.normal(size=(n, d)) * 0.3).astype(np.float32))
+        cache = jnp.asarray(rng.uniform(0.0, 0.8, size=n).astype(np.float32))
+        kw = dict(rbf_gamma=dist_mod.RBF_GAMMA, fold="max",
+                  score_affine=(SIM_ALPHA, SIM_BETA))
+        pair = dist_mod.resolve_pairwise("rbf")
+    C = V[rng.permutation(n)[:m]]
+    w = V[n // 2]
+    dw = pair(V, w[None, :], FP32)[:, 0].astype(jnp.float32)
+    D = pair(V, C, FP32)
+    if fold == "min":
+        cache_f = jnp.minimum(cache, dw)
+        g = jnp.maximum(cache_f[:, None] - D, 0.0)
+    else:
+        cache_f = jnp.maximum(cache, jnp.maximum(SIM_ALPHA + SIM_BETA * dw,
+                                                 0.0))
+        g = jnp.maximum((SIM_ALPHA + SIM_BETA * D) - cache_f[:, None], 0.0)
+    ref = (np.asarray(cache_f), np.asarray(jnp.sum(g, axis=0) / n))
+    return V, C, cache, w, dict(kw, policy=FP32, interpret=True), ref
+
+
+@pytest.mark.parametrize("fold", ["min", "max"])
+def test_planned_gain_tile_matches_fixed_tile_and_oracle(fold):
+    """Both gain kernels at the planned tile score what the fixed 256×256
+    tile and the jnp reference score, with n and m past the tile and no
+    multiple of it, so the plan's padding is exercised."""
+    from repro.core.precision import FP32
+    from repro.kernels import ops
+
+    n, m, d = GAIN_PLAN_SHAPE
+    bn, bm = ops.gain_tile(n, m, ops._round_up(d, ops.LANE), FP32)
+    assert (bn, bm) != (256, 256) and n > bn and m > bm and n % bn and m % bm
+    V, C, cache, w, kw, (cache_ref, g_ref) = _gain_plan_problem(fold)
+    g, nc = ops.fused_gain_update(V, C, cache, w, **kw)
+    g256, nc256 = ops.fused_gain_update(V, C, cache, w, block_n=256,
+                                        block_m=256, **kw)
+    np.testing.assert_allclose(np.asarray(nc), np.asarray(nc256),
+                               rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(np.asarray(nc), cache_ref, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(g), np.asarray(g256),
+                               rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(np.asarray(g), g_ref, rtol=1e-5, atol=1e-6)
+    # the scoring kernel alone, against the folded cache
+    s = ops.marginal_gain(V, C, nc, **kw)
+    s256 = ops.marginal_gain(V, C, nc, block_n=256, block_m=256, **kw)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(s256),
+                               rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(np.asarray(s), g_ref, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("fold", ["min", "max"])
+def test_batched_b1_equals_unbatched_at_planned_tile(fold):
+    """The batched kernels at B = 1 take the unbatched plan's tile and
+    return its gains and cache bit for bit."""
+    from repro.kernels import ops
+
+    V, C, cache, w, kw, _ = _gain_plan_problem(fold)
+    g, nc = ops.fused_gain_update(V, C, cache, w, **kw)
+    gb, ncb = ops.fused_gain_update(V[None], C[None], cache[None], w[None],
+                                    w_valid=jnp.ones((1,), jnp.float32), **kw)
+    np.testing.assert_array_equal(np.asarray(gb[0]), np.asarray(g))
+    np.testing.assert_array_equal(np.asarray(ncb[0]), np.asarray(nc))
+    s = ops.marginal_gain(V, C, cache, **kw)
+    sb = ops.marginal_gain(V[None], C[None], cache[None], **kw)
+    np.testing.assert_array_equal(np.asarray(sb[0]), np.asarray(s))
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_gain_entry_points_launch_the_planned_tile(monkeypatch, batched):
+    """marginal_gain and fused_gain_update, unbatched and batched, launch
+    their kernels at ``gain_tile``'s (Bn, Bm) for the call's (n, m)."""
+    from repro.core.precision import FP32
+    from repro.kernels import marginal_gain as mg
+    from repro.kernels import ops
+
+    launched = {}
+    for name in ("gain_eval", "gain_update_eval", "gain_eval_batched",
+                 "gain_update_eval_batched"):
+        def spy(*args, _kernel=getattr(mg, name), _name=name, **kw):
+            launched[_name] = (kw["block_n"], kw["block_m"])
+            return _kernel(*args, **kw)
+        monkeypatch.setattr(mg, name, spy)
+    V, C, cache, w, kw, _ = _gain_plan_problem("min")
+    # shapes no other test traces, so the jitted wrappers trace here
+    V, C, cache, w = V[:2590], C[:2290], cache[:2590], w
+    if batched:
+        V, C, cache, w = V[None], C[None], cache[None], w[None]
+    ops.marginal_gain(V, C, cache, **kw)
+    ops.fused_gain_update(V, C, cache, w, **kw)
+    tile = ops.gain_tile(2590, 2290, ops.LANE, FP32)
+    suffix = "_batched" if batched else ""
+    assert launched == {"gain_eval" + suffix: tile,
+                        "gain_update_eval" + suffix: tile}
+
+
 def test_sieve_gains_max_template_matches_jnp():
     """The sieve table × element kernel under the max template (facility
     location streaming): per-row gains vs the protocol's jnp form, on a

@@ -26,6 +26,10 @@ def _problem(n, l, k, d, seed=0):
     return V, S, lengths, d_e0
 
 
+#: the gain kernels' tile at n = m = 50,000, d_pad = 128, fp32: the
+#: fastest of the (Bn, Bm) sweep timed on a TPU v5e
+GREEDY_CELL_TILE = (1024, 2048)
+
 SHAPES = [(64, 8, 3, 16), (257, 19, 7, 33), (512, 64, 10, 100),
           (100, 5, 1, 128), (96, 24, 16, 200)]
 
@@ -226,6 +230,66 @@ def test_flat_past_vmem_runs_as_loop():
                             variant="flat", interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+def _gain_working_set(bn, bm, d_pad, itemsize):
+    """VMEM bytes of a gain tile: V and C double buffered, and f32 (B, 1)
+    columns at 128 lanes, ten of Bn rows and three of Bm."""
+    return 2 * (bn + bm) * d_pad * itemsize + (10 * bn + 3 * bm) * 128 * 4
+
+
+GAIN_SHAPES = [(50_000, 50_000, 128), (50_000, 5_000, 128),
+               (1 << 20, 256, 128), (12_500, 50_000, 256),
+               (50_000, 50_000, 1024), (300, 41, 128)]
+
+
+@pytest.mark.parametrize("pname", ["fp32", "bf16"])
+@pytest.mark.parametrize("shape", GAIN_SHAPES)
+def test_gain_tile_respects_working_set(shape, pname):
+    """The gain plan keeps its working set, (B, 1) blocks counted at 128
+    lanes, under the same cap as the multiset plan."""
+    from repro.kernels.ops import VMEM_S_BUDGET, gain_tile
+    n, m, d_pad = shape
+    policy = POLICIES[pname]
+    bn, bm = gain_tile(n, m, d_pad, policy)
+    assert _gain_working_set(bn, bm, d_pad, policy.itemsize) \
+        <= 3 * VMEM_S_BUDGET
+    assert bn % 8 == 0 and bm % 8 == 0
+
+
+@pytest.mark.parametrize("pname", ["fp32", "bf16"])
+def test_gain_tile_lane_dense_at_greedy_cell(pname):
+    """At the Greedy cell's shape (n = m = 50,000, d = 100 in 128 lanes)
+    both tile edges are whole lane rows, and the grid is smaller than the
+    fixed 256×256 tile's 196 × 196 steps."""
+    from repro.kernels.ops import gain_tile
+    bn, bm = gain_tile(50_000, 50_000, 128, POLICIES[pname])
+    assert bn % 128 == 0 and bm % 128 == 0
+    assert -(-50_000 // bn) * -(-50_000 // bm) < 196 * 196
+
+
+@pytest.mark.parametrize("n,m", [(1, 1), (7, 300), (300, 7), (133, 41),
+                                 (257, 1000), (2000, 9)])
+def test_gain_tile_clamps_small_problems(n, m):
+    """A tile edge never passes the padded problem: at most
+    _round_up(n, 8) rows and _round_up(m, 8) candidates, and the whole of
+    either when it is smaller than the smallest tile."""
+    from repro.kernels.ops import GAIN_TILES, _round_up, gain_tile
+    bn, bm = gain_tile(n, m, 128, FP32)
+    assert bn <= _round_up(n, 8) and bm <= _round_up(m, 8)
+    if n < GAIN_TILES[0]:
+        assert bn == _round_up(n, 8)
+    if m < GAIN_TILES[0]:
+        assert bm == _round_up(m, 8)
+    bn_o, bm_o = ops._gain_blocks(n, m, 100, FP32, 4096, 4096)
+    assert (bn_o, bm_o) == (_round_up(n, 8), _round_up(m, 8))
+
+
+def test_gain_tile_picks_chip_fitted_tile():
+    """At the Greedy cell's shape and fp32 the plan picks the tile the
+    fitted chip costs (``ops.GAIN_COST_S``) put first."""
+    from repro.kernels.ops import gain_tile
+    assert gain_tile(50_000, 50_000, 128, FP32) == GREEDY_CELL_TILE
 
 
 def test_grid_covers_problem():

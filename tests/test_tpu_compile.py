@@ -27,6 +27,7 @@ from repro.kernels import ops
 N, M, D, B = 8192, 512, 128, 4          # serving-size ground set, d in lanes
 BLOCK = 256
 PAPER = dict(n=50_000, l=5_000, k=10, d=100)   # configs/paper.py
+GREEDY = dict(n=50_000, d=100)          # the Greedy cell's ground set
 POLICY = {"fp32": FP32, "bf16": BF16}
 AFFINE = {"min": None, "max": (1.0, -0.5)}
 
@@ -109,6 +110,29 @@ def _compile_gain(one_chip, compiled, kernel, pname, fold) -> str:
         block_m=BLOCK, fold=fold, affine=AFFINE[fold])
     compiled[key] = _compiled_text(fn, *args)
     return compiled[key]
+
+
+@pytest.mark.parametrize("kernel", ["gain_update_eval",
+                                    "gain_update_eval_batched"])
+def test_gain_update_compiles_at_greedy_cell_size(one_chip, kernel):
+    """The fused gain kernels, through their padding wrappers, at the
+    Greedy cell's size (bench/configs/paper_v_a_greedy.json: N = m =
+    50,000, d = 100 in 128 lanes, fp32) with the tile ``ops.gain_tile``
+    picks there, which is where the plan meets the chip's VMEM."""
+    n, d = GREEDY["n"], GREEDY["d"]
+    bn, bm = ops.gain_tile(n, n, ops._round_up(d, ops.LANE), FP32)
+    batched = kernel.endswith("_batched")
+    lead = (B,) if batched else ()
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    wrapper = (ops._fused_gain_update_padded_batched if batched
+               else ops._fused_gain_update_padded)
+    text = wrapper.lower(
+        sds(lead + (n, d), jnp.float32), sds(lead + (n, d), jnp.float32),
+        sds(lead + (n,), jnp.float32), sds(lead + (d,), jnp.float32),
+        sds(lead, jnp.float32), policy=FP32, interpret=False,
+        rbf_gamma=None, n_total=n, block_n=bn, block_m=bm, fold="min",
+        score_affine=None).compile().as_text()
+    assert "tpu_custom_call" in text and _has_kernel(text, kernel)
 
 
 @pytest.mark.parametrize("batched", [False, True])
