@@ -126,17 +126,19 @@ def phase_multiset(n: int, l: int, k: int, d: int, backend: str,
 
 def _scan_hlo(f, kind: str, k: int) -> str:
     """Compiled HLO of the one-dispatch scan ``run_selection`` issues for
-    ``f`` on the device plan (same operands and statics)."""
+    ``f`` on the device plan (same operands, one request as B = 1, and
+    statics)."""
     import jax.numpy as jnp
 
     from repro.core import engine
 
     n = f.n
-    cand = (np.zeros((1, 0), np.int32) if kind == "lazy"
-            else np.arange(n, dtype=np.int32)[None, :])
+    cand = (np.zeros((1, 1, 0), np.int32) if kind == "lazy"
+            else np.arange(n, dtype=np.int32)[None, None, :])
     return engine._select_scan.lower(
-        f.V, jnp.array(f.cache_seed), f.row_aux, jnp.asarray(cand),
-        jnp.zeros((f.dim,), f.V.dtype), fn=f.spec, kind=kind, k=k,
+        f.V[None], f.cache_seed[None], f.row_aux[None], jnp.asarray(cand),
+        jnp.zeros((1, f.dim), f.V.dtype), jnp.asarray([k], jnp.int32),
+        fn=f.spec, kind=kind, k=k,
         top_b=min(256, n) if kind == "lazy" else 0,
         distance=f.cfg.distance, policy_name=f.cfg.resolved_policy().name,
         block_m=engine._device_block_m(n, n), backend=f.cfg.backend,

@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import dataclasses
 from collections import Counter
-from functools import partial
 from typing import Callable, Optional
 
 import jax
@@ -121,48 +120,34 @@ def _precision_fields(policy: str, batch: int = 1):
 # --- single-device selection scans -----------------------------------------
 
 
-def _device_case(kind, fname, backend, policy, batch=None):
+def _device_case(kind, fname, backend, policy, batch):
     from repro.core import engine as eng
 
     spec = SPECS[fname]
     be = _eff_backend(spec, backend)
-    nb = batch or 1
     bm = min(BLOCK_M, max(_m_eff(kind), 1))
 
     def build():
-        if batch is None:
-            fn = eng._select_scan
-            args = (_sds((N, D), np.float32), _sds((N,), np.float32),
-                    _sds((N,), np.float32),
-                    _sds(_cand_shape(kind), np.int32),
-                    _sds((D,), np.float32))
-            kwargs = {}
-        else:
-            fn = eng._select_scan_batched
-            args = (_sds((batch, N, D), np.float32),
-                    _sds((batch, N), np.float32),
-                    _sds((batch, N), np.float32),
-                    _sds(_cand_shape(kind, batch), np.int32),
-                    _sds((batch, D), np.float32),
-                    _sds((batch,), np.int32))
-            kwargs = {}
-        kwargs.update(fn=spec, kind=kind, k=K, top_b=TOP_B,
+        args = (_sds((batch, N, D), np.float32),
+                _sds((batch, N), np.float32),
+                _sds((batch, N), np.float32),
+                _sds(_cand_shape(kind, batch), np.int32),
+                _sds((batch, D), np.float32),
+                _sds((batch,), np.int32))
+        kwargs = dict(fn=spec, kind=kind, k=K, top_b=TOP_B,
                       distance="sqeuclidean", policy_name=policy,
                       block_m=bm, backend=be, rbf_gamma=None,
-                      counter_key=f"audit_device_{batch or 1}")
-        return fn, args, kwargs
+                      counter_key=f"audit_device_{batch}")
+        return eng._select_scan, args, kwargs
 
     # lazy's ub0 bound seeding scores all n candidates OUTSIDE the rounds
     # scan; on the jnp backend that is _score_blocked's lax.map — one extra
     # top-level scan. Kernel backends score it in one pallas_call.
     extra_scans = 1 if (kind == "lazy" and be == "jnp") else 0
-    widen, half_dot = _precision_fields(policy, nb)
-    name = "engine.select_scan" if batch is None \
-        else "engine.select_scan_batched"
+    widen, half_dot = _precision_fields(policy, batch)
     return AuditCase(
-        contract=name,
-        label=f"{'device' if batch is None else f'batched[B={batch}]'}"
-              f".{kind}.{fname}.{be}.{policy}",
+        contract="engine.select_scan",
+        label=f"device[B={batch}].{kind}.{fname}.{be}.{policy}",
         build=build,
         expect=Expect(
             rounds=K, top_scans=1 + extra_scans, driving=1,
@@ -181,63 +166,7 @@ def audit_mesh():
                          axis_types=(AxisType.Auto,))
 
 
-def _sharded_case(kind, fname, backend, policy, pool_plan):
-    from repro.core import distributed as dist
-
-    spec = SPECS[fname]
-    be = _eff_backend(spec, backend)
-    gc = 1 if fname == "graph_cut" else 0
-    plan = "device_sharded" if pool_plan == "replicated" \
-        else "device_sharded_pool"
-
-    def build():
-        mesh = audit_mesh()
-        run = dist.make_selection_scan(
-            mesh, ("data",), fn=spec, kind=kind, k=K, top_b=TOP_B,
-            n_total=N, block_m=BLOCK_M, distance="sqeuclidean",
-            policy_name=policy, counter_key=f"audit_{plan}",
-            backend=be, rbf_gamma=None, pool_plan=pool_plan)
-        args = (_sds((N, D), np.float32), _sds((N, D), np.float32),
-                _sds((N,), np.float32), _sds((N,), np.float32),
-                _sds(_cand_shape(kind), np.int32), _sds((D,), np.float32))
-        return run, args, {}
-
-    # Static psum census, from make_selection_scan's body:
-    #   every case: v0 seeding (1) + the final trajectory value (1)
-    #   dense/stoch round body: ONE gains+stat psum; graph cut's fold_aux
-    #     owner-gather adds one (executed unconditionally), and the final
-    #     fold adds it once more
-    #   lazy: + the ub0 seeding batch (1); the round body's psum sits in the
-    #     while loop (one per re-score iteration at run time, one statically)
-    # The sharded pool adds the take collectives: one blocked-take psum
-    # inside the streamed scoring map, and the winner-column take per round.
-    if pool_plan == "replicated":
-        total = (4 if kind == "lazy" else 3) + 2 * gc
-        body = 1 + gc
-        max_bytes = (_m_scored_max(kind) + 1) * 4
-        extra_scans = 1 if (kind == "lazy" and be == "jnp") else 0
-    else:
-        total = (7 if kind == "lazy" else 5) + 2 * gc
-        body = 3 + gc
-        bm = min(BLOCK_M, max(8, N))    # run_sharded_selection's pool cap
-        max_bytes = max((_m_scored_max(kind) + 1) * 4, bm * D * 4)
-        # the lazy seeding pass streams blocked takes through ONE top-level
-        # lax.map (jnp sub-blocking nests inside it)
-        extra_scans = 1 if kind == "lazy" else 0
-    widen, half_dot = _precision_fields(policy)
-    return AuditCase(
-        contract=f"distributed.selection_scan[{pool_plan}]",
-        label=f"{plan}.{kind}.{fname}.{be}.{policy}",
-        build=build,
-        expect=Expect(
-            rounds=K, top_scans=1 + extra_scans, driving=1,
-            whiles=1 if kind == "lazy" else 0,
-            collectives=Counter({"psum": total}),
-            body_psums=body, max_collective_bytes=max_bytes,
-            donated=0, min_widen_elems=widen, require_half_dot=half_dot))
-
-
-def _batched_sharded_case(kind, fname, backend, policy, pool_plan, batch):
+def _sharded_case(kind, fname, backend, policy, pool_plan, batch):
     from repro.core import distributed as dist
 
     spec = SPECS[fname]
@@ -249,7 +178,7 @@ def _batched_sharded_case(kind, fname, backend, policy, pool_plan, batch):
 
     def build():
         mesh = audit_mesh()
-        run = dist.make_selection_scan_batched(
+        run = dist.make_selection_scan(
             mesh, ("data",), fn=spec, kind=kind, k=K, top_b=TOP_B,
             n_total=N, block_m=BLOCK_M, distance="sqeuclidean",
             policy_name=policy, counter_key=f"audit_b{batch}_{plan}",
@@ -260,12 +189,19 @@ def _batched_sharded_case(kind, fname, backend, policy, pool_plan, batch):
                 _sds((nb, D), np.float32), _sds((nb,), np.int32))
         return run, args, {}
 
-    # The batched factory's psum census is STRUCTURALLY IDENTICAL to the
-    # unbatched one (_sharded_case): every per-request collective batches
-    # its OPERAND to (B, …) — the vmapped fold_aux gather, the stacked
-    # gains+stat payload, the (B, bm, d) take slabs — so the batch axis
-    # multiplies collective BYTES, never collective COUNT. That equality is
-    # exactly the tentpole claim ("one psum of O(B·m), not B collectives");
+    # Static psum census, from make_selection_scan's body:
+    #   every case: v0 seeding (1) + the final trajectory value (1)
+    #   dense/stoch round body: ONE gains+stat psum; graph cut's fold_aux
+    #     owner-gather adds one (executed unconditionally), and the final
+    #     fold adds it once more
+    #   lazy: + the ub0 seeding batch (1); the round body's psum sits in the
+    #     while loop (one per re-score iteration at run time, one statically)
+    # The sharded pool adds the take collectives: one blocked-take psum
+    # inside the streamed scoring map, and the winner-column take per round.
+    # Every per-request collective batches its OPERAND to (B, …) — the
+    # vmapped fold_aux gather, the stacked gains+stat payload, the
+    # (B, bm, d) take slabs — so the batch axis multiplies collective
+    # BYTES, never collective COUNT: the count is the same at every B, and
     # a per-tenant psum migration would show up here as count × B.
     if pool_plan == "replicated":
         total = (4 if kind == "lazy" else 3) + 2 * gc
@@ -275,13 +211,15 @@ def _batched_sharded_case(kind, fname, backend, policy, pool_plan, batch):
     else:
         total = (7 if kind == "lazy" else 5) + 2 * gc
         body = 3 + gc
-        bm = min(BLOCK_M, max(8, N))    # run_sharded_selection_batch's cap
+        bm = min(BLOCK_M, max(8, N))    # run_sharded_selection(_batch)'s cap
         max_bytes = max(nb * (_m_scored_max(kind) + 1) * 4, nb * bm * D * 4)
+        # the lazy seeding pass streams blocked takes through ONE top-level
+        # lax.map (jnp sub-blocking nests inside it)
         extra_scans = 1 if kind == "lazy" else 0
     widen, half_dot = _precision_fields(policy, nb)
     return AuditCase(
-        contract=f"distributed.selection_scan_batched[{pool_plan}]",
-        label=f"{plan}.batched[B={batch}].{kind}.{fname}.{be}.{policy}",
+        contract=f"distributed.selection_scan[{pool_plan}]",
+        label=f"{plan}[B={batch}].{kind}.{fname}.{be}.{policy}",
         build=build,
         expect=Expect(
             rounds=K, top_scans=1 + extra_scans, driving=1,
@@ -448,41 +386,30 @@ def _stream_batched_case(variant, fname, backend):
 MEM_N, MEM_D, MEM_BM = 1024, 8, 64
 
 
-def _memory_case(batch=None):
+def _memory_case(batch):
     from repro.core import engine as eng
 
-    nb = batch or 1
-
     def build():
-        if batch is None:
-            fn = eng._select_scan
-            args = (_sds((MEM_N, MEM_D), np.float32),
-                    _sds((MEM_N,), np.float32), _sds((MEM_N,), np.float32),
-                    _sds((1, MEM_N), np.int32), _sds((MEM_D,), np.float32))
-        else:
-            fn = eng._select_scan_batched
-            args = (_sds((batch, MEM_N, MEM_D), np.float32),
-                    _sds((batch, MEM_N), np.float32),
-                    _sds((batch, MEM_N), np.float32),
-                    _sds((batch, 1, MEM_N), np.int32),
-                    _sds((batch, MEM_D), np.float32),
-                    _sds((batch,), np.int32))
+        args = (_sds((batch, MEM_N, MEM_D), np.float32),
+                _sds((batch, MEM_N), np.float32),
+                _sds((batch, MEM_N), np.float32),
+                _sds((batch, 1, MEM_N), np.int32),
+                _sds((batch, MEM_D), np.float32),
+                _sds((batch,), np.int32))
         kwargs = dict(fn=FnSpec(), kind="dense", k=K, top_b=0,
                       distance="sqeuclidean", policy_name="fp32",
                       block_m=MEM_BM, backend="jnp", rbf_gamma=None,
-                      counter_key=f"audit_mem_{nb}")
-        return fn, args, kwargs
+                      counter_key=f"audit_mem_{batch}")
+        return eng._select_scan, args, kwargs
 
     # Working set: the streamed (B·n, block_m) distance tile plus O(B·n)
     # carries — NEVER the full (B·n, m) matrix. Bound: 6 tiles of headroom
     # (scan double-buffering, gather scratch) + 1 MiB slack; a full-matrix
     # regression costs B·n·m·4 = 4B MiB and trips it immediately.
-    tile = nb * MEM_N * MEM_BM * 4
+    tile = batch * MEM_N * MEM_BM * 4
     return AuditCase(
-        contract="engine.select_scan" if batch is None
-        else "engine.select_scan_batched",
-        label=f"memory.{'device' if batch is None else f'batched[B={batch}]'}"
-              f".dense.exemplar.jnp.fp32",
+        contract="engine.select_scan",
+        label=f"memory.device[B={batch}].dense.exemplar.jnp.fp32",
         build=build,
         expect=Expect(
             rounds=K, top_scans=1, driving=1, whiles=0,
@@ -506,15 +433,12 @@ def build_cases(quick: bool = False) -> list[AuditCase]:
                 for policy in POLICIES:
                     if _eff_backend(SPECS[fname], backend) != backend:
                         continue    # satcov normalizes to jnp: skip the dup
-                    cases.append(_device_case(kind, fname, backend, policy))
                     for batch in (1, 64):
                         cases.append(_device_case(kind, fname, backend,
-                                                  policy, batch=batch))
+                                                  policy, batch))
                     for pool_plan in ("replicated", "sharded"):
-                        cases.append(_sharded_case(kind, fname, backend,
-                                                   policy, pool_plan))
                         for batch in (1, 4):
-                            cases.append(_batched_sharded_case(
+                            cases.append(_sharded_case(
                                 kind, fname, backend, policy, pool_plan,
                                 batch))
     for fname in fnames:
@@ -533,8 +457,8 @@ def build_cases(quick: bool = False) -> list[AuditCase]:
                     cases.append(_stream_case(variant, fname, backend,
                                               sharded))
                 cases.append(_stream_batched_case(variant, fname, backend))
-    cases.append(_memory_case())
-    cases.append(_memory_case(batch=4))
+    cases.append(_memory_case(1))
+    cases.append(_memory_case(4))
     if quick:
         seen: dict[str, AuditCase] = {}
         for c in cases:
@@ -635,12 +559,14 @@ def _rt_donation_live() -> tuple[bool, str]:
     rng = np.random.default_rng(4)
     V = jnp.asarray(rng.standard_normal((32, 4)).astype(np.float32))
     f = ExemplarClustering(V, EvalConfig())
-    seed = jnp.array(f.cache_seed)
+    seed = f.cache_seed[None]
     out = eng._select_scan(
-        f.V, seed, f.row_aux, jnp.arange(32, dtype=jnp.int32)[None, :],
-        jnp.zeros((4,), jnp.float32), fn=f.spec, kind="dense", k=3,
-        top_b=0, distance="sqeuclidean", policy_name="fp32", block_m=32,
-        backend="jnp", rbf_gamma=None, counter_key="audit_rt_donate")
+        f.V[None], seed, f.row_aux[None],
+        jnp.arange(32, dtype=jnp.int32)[None, None, :],
+        jnp.zeros((1, 4), jnp.float32), jnp.asarray([3], jnp.int32),
+        fn=f.spec, kind="dense", k=3, top_b=0, distance="sqeuclidean",
+        policy_name="fp32", block_m=32, backend="jnp", rbf_gamma=None,
+        counter_key="audit_rt_donate")
     jax.block_until_ready(out)
     if not seed.is_deleted():
         return False, "donated seed buffer survived the dispatch"

@@ -56,11 +56,9 @@ from repro.core import distances as dist_mod
 from repro.core import functions as fx
 from repro.core.engine import (DEVICE_TRACE_COUNTS, _device_block_m,
                                _score_blocked, drive_selection_scan,
-                               drive_selection_scan_batched,
                                mesh_tiles_per_memory)
 from repro.core.evaluator import EvalConfig
 from repro.core.functions import FnSpec, gains_formula
-from repro.core.multiset import PackedMultiset
 from repro.core.precision import resolve as resolve_policy
 
 
@@ -161,10 +159,15 @@ def make_distributed_cache_update(mesh: Mesh, cfg: EvalConfig,
 
 
 # ---------------------------------------------------------------------------
-# Mesh-sharded selection scan — the engine's device_sharded execution plan.
-# All k rounds run in ONE dispatch inside shard_map; each scored candidate
-# batch crosses the mesh as exactly one psum of O(m) bytes (one per
-# dense/stochastic round, one per CELF re-scoring iteration).
+# Mesh-sharded selection scan — the engine's device_sharded execution plans.
+# All k rounds of B same-signature requests run in ONE dispatch inside
+# shard_map; one request is B = 1. Every per-request (n,) state gains its
+# mesh layout here: the B caches row-shard WITH V ((B, n_loc) per device),
+# and each scored batch's B per-request gain partials stack into ONE psum
+# of O(B·m) bytes (trajectory scalars riding along) — one per
+# dense/stochastic round, one per CELF re-scoring iteration — never B
+# collectives. Ragged k stays the k_eff freeze mask, so bucket padding is
+# inert on every shard.
 # ---------------------------------------------------------------------------
 
 _SELECTION_SCAN_CACHE: dict = {}
@@ -174,23 +177,27 @@ _SELECTION_SCAN_CACHE: dict = {}
     "distributed.selection_scan[sharded]",
     factory=True,
     collective_kinds=("psum",),
-    claim="one dispatch; the round body streams blocked O(Bm·d) takes and "
-          "ONE O(m) gains psum — no collective ever carries O(n·d) or "
-          "O(n·m) bytes; candidate payload resident O(n/p·d) per device")
+    donate=("seed_sh",),
+    claim="B tenants, one dispatch, O(B·n/p·d) resident per device; the "
+          "round body streams blocked O(B·m·d) takes and ONE O(B·m) gains "
+          "psum — per-tenant partials stack into the same collective, "
+          "never B collectives; the (B, n/p) cache seed is donated")
 @contract(
     "distributed.selection_scan[replicated]",
     factory=True,
     collective_kinds=("psum",),
-    claim="one dispatch; ONE O(m) gains psum per scored batch (plus graph "
-          "cut's owner-gather fold); v0 seeding and the final trajectory "
-          "value are the only round-independent collectives")
+    donate=("seed_sh",),
+    claim="B tenants, one dispatch; ONE O(B·m) gains psum per scored batch "
+          "(every tenant's partials + trajectory scalars in one collective "
+          "— not B psums); the (B, n/p) sharded cache seed is donated and "
+          "aliased onto the final cache output")
 def make_selection_scan(
     mesh: Mesh,
     data_axes: Sequence[str],
     *,
     fn: FnSpec = FnSpec(),   # the function's static identity
     kind: str,               # "dense" | "stochastic" | "lazy"
-    k: int,                  # selection rounds
+    k: int,                  # shared scan length (max per-request k)
     top_b: int,              # CELF re-score width (lazy only)
     n_total: int,            # global ground-set size (the gain normalizer)
     block_m: int,            # per-shard candidate block (bounds the tile)
@@ -203,57 +210,74 @@ def make_selection_scan(
 ):
     """Build (and cache) the jitted mesh-sharded k-round selection scan.
 
-    Returns ``run(V_sh, pool, seed_sh, aux_sh, cand_rounds, w0) -> (sel,
-    traj, n_scored)`` where ``V_sh``/``seed_sh``/``aux_sh`` are row-sharded
-    over ``data_axes`` (the function's cache seed and static per-row
-    auxiliary, padded with its sentinel values — see
-    :func:`functions.pad_seed` / :func:`functions.pad_row_aux`) and
-    ``cand_rounds`` is (k, m) int32 for stochastic, ONE (1, m) row for dense
-    (closed over by every round, never replicated k times), (1, 0) for lazy.
-    The builder is cached per (mesh, fn, statics) so repeat runs reuse one
-    traced executable; ``fn`` rides the cache key exactly like a jit static.
+    B same-signature requests lay their state out as (B, n/p) per device —
+    ``V_sh`` is (B, n_pad, d) sharded ``P(None, data_axes, None)``, the
+    per-request cache seeds / row auxiliaries (padded with the function's
+    sentinel values — see :func:`functions.pad_seed` /
+    :func:`functions.pad_row_aux`) row-shard with it, and ``cand_rounds``
+    is the (B, k, m) per-request candidate indices: (B, k, m) for
+    stochastic, ONE (B, 1, m) row for dense (closed over by every round),
+    (B, 1, 0) for lazy. Returns ``run(V_sh, pool, seed_sh, aux_sh,
+    cand_rounds, w0, k_eff) -> (sel (k, B), traj (k, B), n_scored (B,),
+    cache_vec (B, n_pad))``; one request is B = 1. The builder is cached per
+    (mesh, fn, statics) so repeat runs reuse one traced executable; ``fn``
+    rides the cache key exactly like a jit static.
+
+    Collective budget: each scored candidate batch reduces ALL B requests'
+    (m,) per-shard gain partials in ONE psum of O(B·m) bytes — the batch
+    axis rides the collective's payload, never its count — with each
+    request's stat row-sum (the trajectory scalar) concatenated into the
+    same payload, so selections, trajectories and per-request eval counts
+    are bit-equal to B separate sharded runs. CELF's certification loop
+    (:func:`engine.make_lazy_step`) takes its trajectory value from the
+    re-score psum; frozen requests' values emerge from the same collective,
+    masked out of bound/count updates.
 
     The cache is the function's ``(vec, aux)`` pytree: the vec row-shards
     with V, the scalar aux (graph cut's pairwise penalty) stays replicated —
     its winner-indexed update is an owner-shard gather psum'd in
     :func:`functions.fold_aux` (executed unconditionally so the collective
-    pattern is uniform across shards, then gated on winner validity). Graph
+    pattern is uniform across shards, then gated on winner validity; the
+    per-request vmap batches its operand, still one collective). Graph
     cut's index-addressed gain extra is a per-shard partial by construction
     (the owner contributes the one real term, every other shard 0), so it
     rides the existing per-batch gains psum with no extra collective.
 
+    ``seed_sh`` is DONATED: the final folded (B, n_pad) cache vector rides
+    out with the same NamedSharding, so XLA aliases the carry onto the
+    seed's per-device buffers — warm buckets reuse O(B·n/p) bytes instead
+    of allocating per dispatch. ``k_eff`` (B,) int32 is the ragged-k freeze
+    mask (0 = inert bucket-padding slot).
+
     ``pool_plan`` picks the candidate-payload memory plan:
 
-    * ``"replicated"`` — ``pool`` is the full candidate payload, resident
-      on every device; candidate rows gather locally and each scored batch
-      costs one O(m) psum.
+    * ``"replicated"`` — ``pool`` is the full (B, n, d) candidate payload,
+      resident on every device; candidate rows gather locally and each
+      scored batch costs one O(B·m) psum.
     * ``"sharded"`` — ``pool`` row-shards over ``data_axes`` exactly like V
       (callers pass V's own shard — zero extra resident bytes, O(n/p·d) per
-      device). Candidate *indices* resolve through a ``take`` that
-      psum-materializes only the requested columns from their owning shards
-      (zero-padded rows elsewhere make the psum an exact gather): scoring
-      streams ⌈m/block_m⌉ such O(Bm·d) collectives per batch and the round
-      winner's (d,) column is all-gathered the same way, so no shard ever
-      holds more than one candidate block. The CELF ub0 seeding pass and
-      top-B re-scores run through the identical blocked takes; the O(n)
-      scalar bound state stays replicated (it is the documented exception —
-      bounds are per-candidate scalars, not payload).
+      device and request). Candidate *indices* resolve through a ``take``
+      that psum-materializes only the requested (B, block) slabs from their
+      owning shards (zero-padded rows elsewhere make the psum an exact
+      gather): scoring streams ⌈m/block_m⌉ such collectives per batch and
+      the round winners' columns are all-gathered the same way, so no shard
+      ever holds more than one candidate block. The CELF ub0 seeding pass
+      and top-B re-scores run through the identical blocked takes; the O(n)
+      scalar bound state stays replicated (bounds are per-candidate
+      scalars, not payload).
 
     On ``backend="pallas"``/``"pallas_interpret"`` each shard scores its
-    local (n_loc, m) tile through the shared min/max Pallas kernel template
+    local (B, n_loc, m) tile through the min/max Pallas kernel template
     (:func:`repro.kernels.ops.fused_gain_update` for dense/stochastic
     rounds of fused-eligible functions — the winner fold rides in-tile —
     and ``marginal_gain`` for CELF re-scoring and graph cut's add-fold
     rounds; the sharded pool streams take-blocks through ``marginal_gain``
-    with an explicit fold, since a block materializes only after the fold's
-    winner column is gathered). The kernels already normalize by the
-    *global* ``n_total``, so the per-shard outputs are exact gain partials
-    and the one-psum-per-batch collective pattern is byte-identical to the
-    jnp path. Shard-tile blocking note: ``block_m`` bounds the *jnp* path's
-    streamed HBM tile (and the sharded pool's take-block width) only; the
-    kernels tile their own VMEM blocks from the local shard height (padding
-    n_loc/m to block multiples in-wrapper), so the MXU tiling is per-shard
-    and never sees mesh topology.
+    with an explicit fold). The kernels normalize by the *global*
+    ``n_total``, so the per-shard outputs are exact gain partials and the
+    collective pattern is byte-identical to the jnp path. ``block_m``
+    bounds the *jnp* path's streamed HBM tile (and the sharded pool's
+    take-block width) only; the kernels tile their own VMEM blocks from the
+    local shard height, so the MXU tiling never sees mesh topology.
     """
     axes = tuple(data_axes)
     key = (mesh, axes, fn, kind, k, top_b, n_total, block_m, distance,
@@ -270,130 +294,153 @@ def make_selection_scan(
     if use_kernel:
         from repro.kernels import ops as kops
 
-    def local_scan(V_loc, pool, seed_loc, aux_loc, cand_rounds, w0):
-        n_loc = V_loc.shape[0]
+    def local_scan(V_loc, pool, seed_loc, aux_loc, cand_rounds, w0, k_eff):
+        B, n_loc, _d = V_loc.shape
         off = jax.lax.axis_index(axes) * n_loc
         seedf = seed_loc.astype(jnp.float32)
+        # (B,) per-request v0 in ONE psum — the batch axis is payload
         v0 = jax.lax.psum(
-            jnp.sum(fx.stat_rows(fn, seedf, aux_loc)), axes) / n_total
+            jnp.sum(fx.stat_rows(fn, seedf, aux_loc), axis=1), axes) / n_total
         psum_ = lambda x: jax.lax.psum(x, axes)  # noqa: E731
 
         def value_of(cache):
             vec, aux = cache
             mean_stat = jax.lax.psum(
-                jnp.sum(fx.stat_rows(fn, vec, aux_loc)) / n_total, axes)
+                jnp.sum(fx.stat_rows(fn, vec, aux_loc), axis=1) / n_total,
+                axes)
             return fx.value_from_stat(fn, v0, mean_stat, aux, n_total)
 
         def fold(cache, w):
             vec, aux = cache
             row, gidx = w
-            dw = pair(V_loc, row[None, :], policy)[:, 0]
+            dw = jax.vmap(
+                lambda Vb, rb: pair(Vb, rb[None, :], policy)[:, 0])(V_loc, row)
             folded = fx.fold_vec_rows(fn, vec, dw.astype(jnp.float32))
-            # aux advances from the PRE-fold vec; its psum (graph cut's
-            # owner gather) executes unconditionally so every shard issues
-            # the same collectives, and the where gates after
-            new_aux = fx.fold_aux(fn, vec, aux, gidx, off, n_loc, psum=psum_)
+            # aux advances from the PRE-fold vec. vmapping the per-request
+            # fold batches graph cut's owner-gather psum OPERAND to (B,) —
+            # still ONE collective — and every shard executes it
+            # unconditionally before the gate
+            new_aux = jax.vmap(
+                lambda vb, ab, gb: fx.fold_aux(fn, vb, ab, gb, off, n_loc,
+                                               psum=psum_))(vec, aux, gidx)
             ok = gidx >= 0
-            return (jnp.where(ok, folded, vec), jnp.where(ok, new_aux, aux))
+            return (jnp.where(ok[:, None], folded, vec),
+                    jnp.where(ok, new_aux, aux))
 
         def psum_gains_val(g_part, cache):
-            """ONE O(m)-byte collective per scored batch: the (m,) per-shard
-            gain partials plus the shard's stat row-sum ride one psum."""
+            """ONE O(B·m)-byte collective per scored batch: all B requests'
+            (m,) gain partials plus their stat row-sums ride one psum —
+            each request's column is the (m+1,) payload it would send
+            alone."""
             vec, aux = cache
-            payload = jnp.concatenate(
-                [g_part.astype(jnp.float32),
-                 (jnp.sum(fx.stat_rows(fn, vec, aux_loc)) / n_total)[None]])
-            out = jax.lax.psum(payload, axes)
-            return out[:-1], fx.value_from_stat(fn, v0, out[-1], aux, n_total)
+            stat = (jnp.sum(fx.stat_rows(fn, vec, aux_loc), axis=1)
+                    / n_total)[:, None]
+            out = jax.lax.psum(
+                jnp.concatenate([g_part.astype(jnp.float32), stat], axis=1),
+                axes)
+            return out[:, :-1], fx.value_from_stat(fn, v0, out[:, -1], aux,
+                                                   n_total)
 
         def score_part(vec, C):
-            # per-shard gain partials: the kernel path tiles VMEM blocks
-            # itself, the jnp path streams (n_loc, block_m) tiles — neither
-            # materializes an (n_loc, m) distance block on any shard
+            # per-shard (B, m) gain partials: the kernels tile
+            # grid-over-(B, m_tiles, n_tiles) VMEM blocks themselves; the
+            # jnp path vmaps the (n_loc, block_m)-streamed reduction —
+            # neither materializes a (B, n_loc, m) block on any shard
             sc = fx.score_cache_rows(fn, vec, aux_loc)
             if use_kernel:
                 return kops.marginal_gain(
                     V_loc, C, sc, policy=policy, rbf_gamma=rbf_gamma,
                     fold=tmpl[0], score_affine=tmpl[1],
                     interpret=(backend != "pallas"), n_total=n_total)
-            return _score_blocked(V_loc, C, sc, pair, policy, block_m,
-                                  n_total=n_total, fn=fn, row_aux=aux_loc)
+            return jax.vmap(
+                lambda Vb, Cb, scb, rb: _score_blocked(
+                    Vb, Cb, scb, pair, policy, block_m, n_total=n_total,
+                    fn=fn, row_aux=rb))(V_loc, C, sc, aux_loc)
 
-        cache0 = (seedf, jnp.float32(0.0))
-        w0c = (w0.astype(pool.dtype), jnp.asarray(-1, jnp.int32))
+        def gains_extra(vec, idx):
+            # graph cut's index-addressed per-shard partial, per request
+            # (None for every other function — vmap passes None through)
+            return jax.vmap(
+                lambda vb, ib: fx.gains_index_extra(fn, vb, ib, off, n_loc,
+                                                    n_total))(vec, idx)
+
+        cache0 = (seedf, jnp.zeros((B,), jnp.float32))
+        w0c = (w0.astype(pool.dtype), jnp.full((B,), -1, jnp.int32))
 
         if sharded_pool:
-            n_loc_pool = pool.shape[0]
+            n_loc_pool = pool.shape[1]
             off_pool = jax.lax.axis_index(axes) * n_loc_pool
 
             def take_rows(idxv):
-                """Materialize pool rows for *global* indices: one psum of
-                the owner's rows against everyone else's zeros (exact — the
-                psum adds one real row and p−1 zero rows)."""
+                """Materialize (B, mb, d) pool slabs for *global* indices:
+                one psum of each owner's rows against everyone else's
+                zeros, all B requests in the same collective."""
                 rel = idxv - off_pool
                 own = (rel >= 0) & (rel < n_loc_pool)
-                rows = pool[jnp.clip(rel, 0, n_loc_pool - 1)]
+                rows = jnp.take_along_axis(
+                    pool, jnp.clip(rel, 0, n_loc_pool - 1)[:, :, None],
+                    axis=1, mode="clip")
                 return jax.lax.psum(
-                    jnp.where(own[:, None], rows, jnp.zeros_like(rows)),
+                    jnp.where(own[:, :, None], rows, jnp.zeros_like(rows)),
                     axes)
 
             def take(j):
-                return take_rows(jnp.atleast_1d(j))[0], j
+                return take_rows(j[:, None])[:, 0], j
 
             def score_idx(cache, idx):
-                # stream index blocks: take-materialize (block_m, d), score
-                # the local tile, never hold two blocks at once
+                # stream per-request index blocks in lockstep: one
+                # take-materialized (B, bm, d) slab at a time, never two
                 vec, _aux = cache
-                m = idx.shape[0]
+                m = idx.shape[1]
                 bm = min(block_m, m)
                 m_pad = -(-m // bm) * bm
-                idx_p = jnp.pad(idx, (0, m_pad - m))
+                idx_p = jnp.pad(idx, ((0, 0), (0, m_pad - m)))
+                blocks = jnp.moveaxis(idx_p.reshape(B, -1, bm), 1, 0)
                 parts = jax.lax.map(
-                    lambda ib: score_part(vec, take_rows(ib)),
-                    idx_p.reshape(-1, bm)).reshape(-1)[:m]
-                extra = fx.gains_index_extra(fn, vec, idx, off, n_loc,
-                                             n_total)
+                    lambda ib: score_part(vec, take_rows(ib)), blocks)
+                parts = jnp.moveaxis(parts, 0, 1).reshape(B, -1)[:, :m]
+                extra = gains_extra(vec, idx)
                 return parts if extra is None else parts + extra
 
             def score_idx_val(cache, idx):
                 return psum_gains_val(score_idx(cache, idx), cache)
 
             def fold_score_val(cache, w_prev, cand_t):
-                # the fold stays explicit: the winner column was already
-                # gathered last round, and candidate blocks only
-                # materialize inside the streamed scoring below
                 cache = fold(cache, w_prev)
                 gains, val = score_idx_val(cache, cand_t)
                 return gains, cache, val
 
             def seed_val(cache):
-                return score_idx_val(
-                    cache, jnp.arange(n_total, dtype=jnp.int32))
+                return score_idx_val(cache, jnp.broadcast_to(
+                    jnp.arange(n_total, dtype=jnp.int32), (B, n_total)))
 
-            return drive_selection_scan(
-                kind=kind, k=k, top_b=top_b, n_global=n_total, take=take,
-                n_pool=n_total, seed_val=seed_val,
-                score_idx_val=score_idx_val, cand_rounds=cand_rounds,
-                cache0=cache0, w0=w0c, fold=fold,
-                fold_score_val=fold_score_val, value_of=value_of)
+            sel, traj, n_scored, cache_f = drive_selection_scan(
+                kind=kind, k=k, top_b=top_b, n_global=n_total, k_eff=k_eff,
+                take=take, n_pool=n_total, seed_val=seed_val,
+                cand_rounds=cand_rounds, cache0=cache0, w0=w0c, fold=fold,
+                score_idx_val=score_idx_val, fold_score_val=fold_score_val,
+                value_of=value_of)
+            return sel, traj, n_scored, cache_f[0]
 
         def score_idx_val(cache, idx):
             vec, _aux = cache
-            g = score_part(vec, pool[idx])
-            extra = fx.gains_index_extra(fn, vec, idx, off, n_loc, n_total)
+            g = score_part(vec, jnp.take_along_axis(
+                pool, idx[:, :, None], axis=1, mode="clip"))
+            extra = gains_extra(vec, idx)
             return psum_gains_val(g if extra is None else g + extra, cache)
 
         if use_kernel and fx.kernel_fused_ok(fn):
 
             def fold_score_val(cache, w_prev, cand_t):
-                # fused dense/stochastic round: the winner fold happens
-                # inside the kernel on the local shard tile (fused-eligible
-                # functions carry no aux and no index extra)
+                # fused dense/stochastic round: each request's winner fold
+                # happens inside the kernel on its local shard tile
                 vec, aux = cache
                 row, gidx = w_prev
                 g_part, vec2 = kops.fused_gain_update(
-                    V_loc, pool[cand_t], vec, row, policy=policy,
-                    rbf_gamma=rbf_gamma, fold=tmpl[0], score_affine=tmpl[1],
+                    V_loc, jnp.take_along_axis(
+                        pool, cand_t[:, :, None], axis=1, mode="clip"),
+                    vec, row, policy=policy, rbf_gamma=rbf_gamma,
+                    fold=tmpl[0], score_affine=tmpl[1],
                     interpret=(backend != "pallas"), n_total=n_total,
                     w_valid=(gidx >= 0).astype(jnp.float32))
                 cache2 = (vec2, aux)
@@ -406,30 +453,31 @@ def make_selection_scan(
                 gains, val = score_idx_val(cache2, cand_t)
                 return gains, cache2, val
 
-        return drive_selection_scan(
-            kind=kind, k=k, top_b=top_b, n_global=n_total, pool=pool,
-            cand_rounds=cand_rounds, cache0=cache0, w0=w0c, fold=fold,
-            score_idx_val=score_idx_val, fold_score_val=fold_score_val,
-            value_of=value_of)
+        sel, traj, n_scored, cache_f = drive_selection_scan(
+            kind=kind, k=k, top_b=top_b, n_global=n_total, k_eff=k_eff,
+            pool=pool, cand_rounds=cand_rounds, cache0=cache0, w0=w0c,
+            fold=fold, score_idx_val=score_idx_val,
+            fold_score_val=fold_score_val, value_of=value_of)
+        return sel, traj, n_scored, cache_f[0]
 
     smapped = shard_map(
         local_scan,
         mesh=mesh,
-        in_specs=(P(axes, None),
-                  P(axes, None) if sharded_pool else P(None, None),
-                  P(axes), P(axes), P(None, None), P(None)),
-        out_specs=(P(None), P(None), P()),  # sel, traj, scalar n_scored
+        in_specs=(P(None, axes, None),
+                  P(None, axes, None) if sharded_pool else P(None, None, None),
+                  P(None, axes), P(None, axes), P(None, None, None),
+                  P(None, None), P(None)),
+        out_specs=(P(None), P(None), P(None), P(None, axes)),
         check_vma=False,
     )
 
-    @jax.jit
-    def run(V_sh, pool, seed_sh, aux_sh, cand_rounds, w0):
+    @partial(jax.jit, donate_argnums=(2,))
+    def run(V_sh, pool, seed_sh, aux_sh, cand_rounds, w0, k_eff):
         DEVICE_TRACE_COUNTS[counter_key] += 1
-        return smapped(V_sh, pool, seed_sh, aux_sh, cand_rounds, w0)
+        return smapped(V_sh, pool, seed_sh, aux_sh, cand_rounds, w0, k_eff)
 
     _SELECTION_SCAN_CACHE[key] = run
     return run
-
 
 def _resolve_mesh(mesh: Optional[Mesh], data_axes: Sequence[str]) -> Mesh:
     if mesh is None:
@@ -489,6 +537,7 @@ def _placed_sharded(f, mesh: Mesh, axes: tuple, replicated_pool: bool):
     return entry
 
 
+
 def run_sharded_selection(
     f,                       # SubmodularFunction (untyped: avoids circularity)
     cand_rounds: jax.Array,  # (k, m) int32 global candidate indices
@@ -506,8 +555,11 @@ def run_sharded_selection(
     rbf_gamma: Optional[float] = None,
     pool_plan: str = "replicated",
 ):
-    """Place operands on the mesh and run the sharded selection scan.
+    """Run one request through the sharded selection scan, as B = 1.
 
+    The operands come from :func:`_placed_sharded`'s cached placement,
+    lifted to a leading request axis of 1 (the lifted seed is a fresh
+    buffer, so the scan's donation leaves the cached seed alone).
     ``pool_plan="replicated"`` keeps the candidate payload resident on
     every device (O(n·d) — fine for sampled/lazy candidates);
     ``pool_plan="sharded"`` passes V's own row-shard as the pool (zero
@@ -522,7 +574,7 @@ def run_sharded_selection(
     p×). Under the sharded pool the take-block width is additionally
     capped at n_loc so the transient gathered block never exceeds the
     resident shard — the O(n/p) peak-memory claim covers transients too.
-    Returns ``(sel, traj, n_scored)`` device arrays.
+    Returns ``(sel (k,), traj (k,), n_scored)`` device arrays.
     """
     mesh = _resolve_mesh(mesh, data_axes)
     axes = tuple(data_axes)
@@ -535,300 +587,17 @@ def run_sharded_selection(
     if pool_plan == "sharded":
         bm = min(bm, max(8, n_loc))
     entry = _placed_sharded(f, mesh, axes, pool_plan == "replicated")
-    V_sh, seed_sh, aux_sh = entry["V_sh"], entry["seed_sh"], entry["aux_sh"]
-    pool = entry["pool"] if pool_plan == "replicated" else V_sh
+    V1 = entry["V_sh"][None]
+    pool = entry["pool"][None] if pool_plan == "replicated" else V1
     scan = make_selection_scan(
         mesh, axes, fn=f.spec, kind=kind, k=k, top_b=top_b, n_total=n,
         block_m=bm, distance=f.cfg.distance,
         policy_name=f.cfg.resolved_policy().name, counter_key=counter_key,
         backend=backend, rbf_gamma=rbf_gamma, pool_plan=pool_plan)
-    return scan(V_sh, pool, seed_sh, aux_sh, cand_rounds, w0)
-
-
-# ---------------------------------------------------------------------------
-# Batched × sharded composition — B tenants in one (B, n/p) mesh dispatch.
-# Every per-request (n,) state from the single-device batched engine gains
-# its mesh layout here: the B min-caches row-shard WITH V ((B, n_loc) per
-# device), and each scored batch's B per-request gain partials stack into
-# the SAME psum (O(B·m) bytes, trajectory scalars riding along) instead of
-# issuing B collectives. Ragged k stays the k_eff freeze mask, so bucket
-# padding is inert on every shard.
-# ---------------------------------------------------------------------------
-
-_SELECTION_SCAN_BATCHED_CACHE: dict = {}
-
-
-@contract(
-    "distributed.selection_scan_batched[sharded]",
-    factory=True,
-    collective_kinds=("psum",),
-    donate=("seed_sh",),
-    claim="B tenants, one dispatch, O(B·n/p·d) resident per device; the "
-          "round body streams blocked O(B·m·d) takes and ONE O(B·m) gains "
-          "psum — per-tenant partials stack into the same collective, "
-          "never B collectives; the (B, n/p) cache seed is donated")
-@contract(
-    "distributed.selection_scan_batched[replicated]",
-    factory=True,
-    collective_kinds=("psum",),
-    donate=("seed_sh",),
-    claim="B tenants, one dispatch; ONE O(B·m) gains psum per scored batch "
-          "(every tenant's partials + trajectory scalars in one collective "
-          "— not B psums); the (B, n/p) sharded cache seed is donated and "
-          "aliased onto the final cache output")
-def make_selection_scan_batched(
-    mesh: Mesh,
-    data_axes: Sequence[str],
-    *,
-    fn: FnSpec = FnSpec(),   # the function's static identity
-    kind: str,               # "dense" | "stochastic" | "lazy"
-    k: int,                  # shared scan length (max per-request k)
-    top_b: int,              # CELF re-score width (lazy only)
-    n_total: int,            # global ground-set size (the gain normalizer)
-    block_m: int,            # per-shard candidate block (bounds the tile)
-    distance: str,
-    policy_name: str,
-    counter_key: str,
-    backend: str = "jnp",    # "jnp" | "pallas" | "pallas_interpret"
-    rbf_gamma: Optional[float] = None,
-    pool_plan: str = "replicated",  # "replicated" | "sharded"
-):
-    """Build (and cache) the jitted batched mesh-sharded k-round scan.
-
-    The batched composition of :func:`make_selection_scan`: B same-signature
-    requests lay their state out as (B, n/p) per device — ``V_sh`` is
-    (B, n_pad, d) sharded ``P(None, data_axes, None)``, the B per-tenant
-    cache seeds / row auxiliaries row-shard with it, and ``cand_rounds`` is
-    the (B, k, m) per-request candidate indices. Returns ``run(V_sh, pool,
-    seed_sh, aux_sh, cand_rounds, w0, k_eff) -> (sel (k, B), traj (k, B),
-    n_scored (B,), cache_vec (B, n_pad))``.
-
-    Collective budget: each scored candidate batch reduces ALL B requests'
-    (m,) per-shard gain partials in ONE psum of O(B·m) bytes — the batch
-    axis rides the collective's payload, never its count — with each
-    request's stat row-sum (the trajectory scalar) concatenated into the
-    same payload. The per-request column of that payload is byte-identical
-    to the unbatched plan's (m+1,) psum, which is why batched-sharded
-    selections, trajectories, AND per-request eval counts are bit-equal to
-    B separate sharded runs. Batched CELF shares the unbatched step's
-    certification loop via :func:`engine.make_batched_lazy_step_val` (the
-    trajectory value rides the re-score psum; frozen requests' values
-    emerge from the same collective, masked out of bound/count updates).
-
-    ``seed_sh`` is DONATED: the final folded (B, n_pad) cache vector rides
-    out with the same NamedSharding, so XLA aliases the carry onto the
-    seed's per-device buffers — warm buckets reuse O(B·n/p) bytes instead
-    of allocating per dispatch. ``k_eff`` (B,) int32 is the ragged-k freeze
-    mask (0 = inert bucket-padding slot).
-
-    On kernel backends each shard scores its local (B, n_loc, m) tile
-    through the grid-over-(B, m_tiles, n_tiles) batched Pallas kernels
-    (:mod:`repro.kernels.marginal_gain`) with the *global* ``n_total``
-    normalizer, so per-shard tiles stay exact psum partials exactly like
-    the unbatched sharded kernels. ``pool_plan`` has the same two memory
-    plans as the unbatched factory — ``"sharded"`` passes V's own (B, n/p)
-    shard as the pool and psum-materializes (B, block) candidate slabs
-    from their owning shards.
-    """
-    axes = tuple(data_axes)
-    key = (mesh, axes, fn, kind, k, top_b, n_total, block_m, distance,
-           policy_name, counter_key, backend, rbf_gamma, pool_plan)
-    if key in _SELECTION_SCAN_BATCHED_CACHE:
-        return _SELECTION_SCAN_BATCHED_CACHE[key]
-    if pool_plan not in ("replicated", "sharded"):
-        raise ValueError(f"unknown pool_plan {pool_plan!r}")
-    policy = resolve_policy(policy_name)
-    pair = dist_mod.resolve_pairwise(distance)
-    tmpl = fx.kernel_template(fn)
-    use_kernel = backend in ("pallas", "pallas_interpret") and tmpl is not None
-    sharded_pool = pool_plan == "sharded"
-    if use_kernel:
-        from repro.kernels import ops as kops
-
-    def local_scan(V_loc, pool, seed_loc, aux_loc, cand_rounds, w0, k_eff):
-        B, n_loc, _d = V_loc.shape
-        off = jax.lax.axis_index(axes) * n_loc
-        seedf = seed_loc.astype(jnp.float32)
-        # (B,) per-request v0 in ONE psum — the batch axis is payload
-        v0 = jax.lax.psum(
-            jnp.sum(fx.stat_rows(fn, seedf, aux_loc), axis=1), axes) / n_total
-        psum_ = lambda x: jax.lax.psum(x, axes)  # noqa: E731
-
-        def value_of(cache):
-            vec, aux = cache
-            mean_stat = jax.lax.psum(
-                jnp.sum(fx.stat_rows(fn, vec, aux_loc), axis=1) / n_total,
-                axes)
-            return fx.value_from_stat(fn, v0, mean_stat, aux, n_total)
-
-        def fold(cache, w):
-            vec, aux = cache
-            row, gidx = w
-            dw = jax.vmap(
-                lambda Vb, rb: pair(Vb, rb[None, :], policy)[:, 0])(V_loc, row)
-            folded = fx.fold_vec_rows(fn, vec, dw.astype(jnp.float32))
-            # aux advances from the PRE-fold vec. vmapping the per-request
-            # fold batches graph cut's owner-gather psum OPERAND to (B,) —
-            # still ONE collective — and every shard executes it
-            # unconditionally before the gate, exactly like unbatched
-            new_aux = jax.vmap(
-                lambda vb, ab, gb: fx.fold_aux(fn, vb, ab, gb, off, n_loc,
-                                               psum=psum_))(vec, aux, gidx)
-            ok = gidx >= 0
-            return (jnp.where(ok[:, None], folded, vec),
-                    jnp.where(ok, new_aux, aux))
-
-        def psum_gains_val(g_part, cache):
-            """ONE O(B·m)-byte collective per scored batch: all B requests'
-            (m,) gain partials plus their stat row-sums ride one psum —
-            each request's column is byte-identical to the unbatched
-            plan's (m+1,) payload."""
-            vec, aux = cache
-            stat = (jnp.sum(fx.stat_rows(fn, vec, aux_loc), axis=1)
-                    / n_total)[:, None]
-            out = jax.lax.psum(
-                jnp.concatenate([g_part.astype(jnp.float32), stat], axis=1),
-                axes)
-            return out[:, :-1], fx.value_from_stat(fn, v0, out[:, -1], aux,
-                                                   n_total)
-
-        def score_part(vec, C):
-            # per-shard (B, m) gain partials: the batched kernels tile
-            # grid-over-(B, m_tiles, n_tiles) VMEM blocks themselves; the
-            # jnp path vmaps the (n_loc, block_m)-streamed reduction —
-            # neither materializes a (B, n_loc, m) block on any shard
-            sc = fx.score_cache_rows(fn, vec, aux_loc)
-            if use_kernel:
-                return kops.marginal_gain(
-                    V_loc, C, sc, policy=policy, rbf_gamma=rbf_gamma,
-                    fold=tmpl[0], score_affine=tmpl[1],
-                    interpret=(backend != "pallas"), n_total=n_total)
-            return jax.vmap(
-                lambda Vb, Cb, scb, rb: _score_blocked(
-                    Vb, Cb, scb, pair, policy, block_m, n_total=n_total,
-                    fn=fn, row_aux=rb))(V_loc, C, sc, aux_loc)
-
-        def gains_extra(vec, idx):
-            # graph cut's index-addressed per-shard partial, per request
-            # (None for every other function — vmap passes None through)
-            return jax.vmap(
-                lambda vb, ib: fx.gains_index_extra(fn, vb, ib, off, n_loc,
-                                                    n_total))(vec, idx)
-
-        cache0 = (seedf, jnp.zeros((B,), jnp.float32))
-        w0c = (w0.astype(pool.dtype), jnp.full((B,), -1, jnp.int32))
-
-        if sharded_pool:
-            n_loc_pool = pool.shape[1]
-            off_pool = jax.lax.axis_index(axes) * n_loc_pool
-
-            def take_rows(idxv):
-                """Materialize (B, mb, d) pool slabs for *global* indices:
-                one psum of each owner's rows against everyone else's
-                zeros, all B requests in the same collective."""
-                rel = idxv - off_pool
-                own = (rel >= 0) & (rel < n_loc_pool)
-                rows = jnp.take_along_axis(
-                    pool, jnp.clip(rel, 0, n_loc_pool - 1)[:, :, None],
-                    axis=1)
-                return jax.lax.psum(
-                    jnp.where(own[:, :, None], rows, jnp.zeros_like(rows)),
-                    axes)
-
-            def take(j):
-                return take_rows(j[:, None])[:, 0], j
-
-            def score_idx(cache, idx):
-                # stream per-request index blocks in lockstep: one
-                # take-materialized (B, bm, d) slab at a time, never two
-                vec, _aux = cache
-                m = idx.shape[1]
-                bm = min(block_m, m)
-                m_pad = -(-m // bm) * bm
-                idx_p = jnp.pad(idx, ((0, 0), (0, m_pad - m)))
-                blocks = jnp.moveaxis(idx_p.reshape(B, -1, bm), 1, 0)
-                parts = jax.lax.map(
-                    lambda ib: score_part(vec, take_rows(ib)), blocks)
-                parts = jnp.moveaxis(parts, 0, 1).reshape(B, -1)[:, :m]
-                extra = gains_extra(vec, idx)
-                return parts if extra is None else parts + extra
-
-            def score_idx_val(cache, idx):
-                return psum_gains_val(score_idx(cache, idx), cache)
-
-            def fold_score_val(cache, w_prev, cand_t):
-                cache = fold(cache, w_prev)
-                gains, val = score_idx_val(cache, cand_t)
-                return gains, cache, val
-
-            def seed_val(cache):
-                return score_idx_val(cache, jnp.broadcast_to(
-                    jnp.arange(n_total, dtype=jnp.int32), (B, n_total)))
-
-            sel, traj, n_scored, cache_f = drive_selection_scan_batched(
-                kind=kind, k=k, top_b=top_b, n_global=n_total, k_eff=k_eff,
-                take=take, n_pool=n_total, seed_val=seed_val,
-                cand_rounds=cand_rounds, cache0=cache0, w0=w0c, fold=fold,
-                score_idx_val=score_idx_val, fold_score_val=fold_score_val,
-                value_of=value_of)
-            return sel, traj, n_scored, cache_f[0]
-
-        def score_idx_val(cache, idx):
-            vec, _aux = cache
-            g = score_part(vec, jnp.take_along_axis(
-                pool, idx[:, :, None], axis=1))
-            extra = gains_extra(vec, idx)
-            return psum_gains_val(g if extra is None else g + extra, cache)
-
-        if use_kernel and fx.kernel_fused_ok(fn):
-
-            def fold_score_val(cache, w_prev, cand_t):
-                # fused dense/stochastic round: each request's winner fold
-                # happens inside the batched kernel on its local shard tile
-                vec, aux = cache
-                row, gidx = w_prev
-                g_part, vec2 = kops.fused_gain_update(
-                    V_loc, jnp.take_along_axis(
-                        pool, cand_t[:, :, None], axis=1),
-                    vec, row, policy=policy, rbf_gamma=rbf_gamma,
-                    fold=tmpl[0], score_affine=tmpl[1],
-                    interpret=(backend != "pallas"), n_total=n_total,
-                    w_valid=(gidx >= 0).astype(jnp.float32))
-                cache2 = (vec2, aux)
-                gains, val = psum_gains_val(g_part, cache2)
-                return gains, cache2, val
-        else:
-
-            def fold_score_val(cache, w_prev, cand_t):
-                cache2 = fold(cache, w_prev)
-                gains, val = score_idx_val(cache2, cand_t)
-                return gains, cache2, val
-
-        sel, traj, n_scored, cache_f = drive_selection_scan_batched(
-            kind=kind, k=k, top_b=top_b, n_global=n_total, k_eff=k_eff,
-            pool=pool, cand_rounds=cand_rounds, cache0=cache0, w0=w0c,
-            fold=fold, score_idx_val=score_idx_val,
-            fold_score_val=fold_score_val, value_of=value_of)
-        return sel, traj, n_scored, cache_f[0]
-
-    smapped = shard_map(
-        local_scan,
-        mesh=mesh,
-        in_specs=(P(None, axes, None),
-                  P(None, axes, None) if sharded_pool else P(None, None, None),
-                  P(None, axes), P(None, axes), P(None, None, None),
-                  P(None, None), P(None)),
-        out_specs=(P(None), P(None), P(None), P(None, axes)),
-        check_vma=False,
-    )
-
-    @partial(jax.jit, donate_argnums=(2,))
-    def run(V_sh, pool, seed_sh, aux_sh, cand_rounds, w0, k_eff):
-        DEVICE_TRACE_COUNTS[counter_key] += 1
-        return smapped(V_sh, pool, seed_sh, aux_sh, cand_rounds, w0, k_eff)
-
-    _SELECTION_SCAN_BATCHED_CACHE[key] = run
-    return run
+    sel, traj, n_scored, _ = scan(
+        V1, pool, entry["seed_sh"][None], entry["aux_sh"][None],
+        cand_rounds[None], w0[None], jnp.asarray([k], jnp.int32))
+    return sel[:, 0], traj[:, 0], n_scored[0]
 
 
 def stage_sharded_batch(
@@ -841,12 +610,12 @@ def stage_sharded_batch(
     """Pad, stack, and shard-place a bucket of B same-signature requests.
 
     Host-stacks each request's V/seed/aux (padding rows to the mesh extent
-    with the function's inert sentinels, exactly like the unbatched
+    with the function's inert sentinels, exactly like
     :func:`_placed_sharded`) and issues ONE ``jax.device_put`` per operand
     with its (B, n/p) NamedSharding — async on accelerators, so a serving
     loop can stage the NEXT bucket while the current dispatch runs. No
     placement is cached on the functions: the seed must be FRESH per
-    dispatch (the batched scan donates it) and bucket composition changes
+    dispatch (the scan donates it) and bucket composition changes
     call to call. The returned payload is single-use and carries the
     (mesh, axes, pool_plan) it was staged for.
     """
@@ -880,7 +649,7 @@ def stage_sharded_batch(
     if pool_plan == "replicated":
         # UNPADDED (B, n, d): the replicated pool is candidate payload, and
         # lazy's ub0 seeding scores every pool row — a padded row would be
-        # a real-looking candidate (matches the unbatched "pool" entry)
+        # a real-looking candidate (matches _placed_sharded's "pool")
         payload["pool"] = jax.device_put(
             np.stack(V_np), NamedSharding(mesh, P(None, None, None)))
     return payload
@@ -904,7 +673,7 @@ def run_sharded_selection_batch(
     pool_plan: str = "replicated",
     staged: Optional[dict] = None,
 ):
-    """Place a bucket's (B, n/p) operands and run the batched sharded scan.
+    """Place a bucket's (B, n/p) operands and run the sharded scan.
 
     The gain tile autotunes from B·n_loc rows — the LOCAL shard height
     times the batch (never B·n global, which would under-fill every shard
@@ -935,7 +704,7 @@ def run_sharded_selection_batch(
                                      pool_plan=pool_plan)
     V_sh = staged["V_sh"]
     pool = staged["pool"] if pool_plan == "replicated" else V_sh
-    scan = make_selection_scan_batched(
+    scan = make_selection_scan(
         mesh, axes, fn=f0.spec, kind=kind, k=k, top_b=top_b, n_total=n,
         block_m=bm, distance=f0.cfg.distance,
         policy_name=f0.cfg.resolved_policy().name, counter_key=counter_key,
@@ -957,6 +726,22 @@ def run_sharded_selection_batch(
 # ---------------------------------------------------------------------------
 
 _GREEDI_SCAN_CACHE: dict = {}
+
+
+def _lead(tree):
+    """Give every leaf a leading request axis of extent 1."""
+    return jax.tree_util.tree_map(lambda x: x[None], tree)
+
+
+def _one_request_callbacks(fold, fold_score_val, value_of):
+    """Lift one request's (n,)-cache callbacks to the driver's leading
+    request axis of extent 1 (:func:`engine.drive_selection_scan`)."""
+    first = partial(jax.tree_util.tree_map, lambda x: x[0])
+    return dict(
+        fold=lambda cache, w: _lead(fold(first(cache), first(w))),
+        fold_score_val=lambda cache, w, cand: _lead(
+            fold_score_val(first(cache), first(w), cand[0])),
+        value_of=lambda cache: value_of(first(cache))[None])
 
 
 @contract(
@@ -1081,16 +866,19 @@ def make_greedi_scan(
                 return g, cache2, value_local(cache2)
 
         pad_taken = (jnp.arange(n_loc, dtype=jnp.int32) + off) >= n_total
-        sel1, _, nsc1 = drive_selection_scan(
-            kind="dense", k=k, top_b=0, n_global=n_total, pool=V_loc,
-            taken0=pad_taken,
-            cand_rounds=jnp.arange(n_loc, dtype=jnp.int32)[None, :],
-            cache0=cache0, w0=w0c, fold=fold_local,
-            fold_score_val=fold_score_local, value_of=value_local)
+        one = jnp.full((1,), k, jnp.int32)   # each phase is one request
+        sel1, _, nsc1, _ = drive_selection_scan(
+            kind="dense", k=k, top_b=0, n_global=n_total, k_eff=one,
+            take=lambda j: (V_loc[j], j), n_pool=n_loc,
+            taken0=pad_taken[None],
+            cand_rounds=jnp.arange(n_loc, dtype=jnp.int32)[None, None, :],
+            cache0=_lead(cache0), w0=_lead(w0c),
+            **_one_request_callbacks(fold_local, fold_score_local,
+                                     value_local))
+        sel1, nsc1 = sel1[:, 0], nsc1[0]
 
         # ---- all-gather the p·k partial solutions: each shard owns one
         # slot of the (p, k, ·) buffers, one psum fills them all
-        sel1 = sel1.astype(jnp.int32)
         slot = jnp.arange(p_total, dtype=jnp.int32) == lin
         merged_vec = jax.lax.psum(
             jnp.where(slot[:, None, None], V_loc[sel1][None], 0),
@@ -1177,13 +965,16 @@ def make_greedi_scan(
                 gains, val = psum_gains_val(g, cache2)
                 return gains, cache2, val
 
-        sel2, traj2, nsc2 = drive_selection_scan(
-            kind="dense", k=k, top_b=0, n_global=n_total,
+        sel2, traj2, nsc2, _ = drive_selection_scan(
+            kind="dense", k=k, top_b=0, n_global=n_total, k_eff=one,
             take=lambda j: (merged_vec[j], merged_idx[j]),
             n_pool=p_total * k,
-            cand_rounds=jnp.arange(p_total * k, dtype=jnp.int32)[None, :],
-            cache0=cache0, w0=w0c, fold=fold_global,
-            fold_score_val=fold_score_merge, value_of=value_global)
+            cand_rounds=jnp.arange(p_total * k,
+                                   dtype=jnp.int32)[None, None, :],
+            cache0=_lead(cache0), w0=_lead(w0c),
+            **_one_request_callbacks(fold_global, fold_score_merge,
+                                     value_global))
+        sel2, traj2, nsc2 = sel2[:, 0], traj2[:, 0], nsc2[0]
 
         # ---- best-of-both: return whichever of (merged greedy, best
         # single-partition solution) scores higher globally; ties keep the

@@ -27,6 +27,10 @@ orthogonal choices:
                        ``psum`` of O(m) bytes reduces them; the argmax (and
                        the CELF bound state) stays replicated.
 
+The scan always runs B independent requests with a leading request axis
+on every operand and carry; one request is B = 1 (:func:`run_selection`),
+a bucket of tenants is B > 1 (:func:`run_selection_batch`).
+
 The min-distance cache recurrence (see :mod:`repro.core.optimizers`) is the
 shared substrate: a round is one (n × m) distance evaluation plus an O(n)
 fold of the winner. On Pallas backends the fold rides inside the fused gain
@@ -205,277 +209,19 @@ def _score_blocked(V, C, sc, pair, policy, block_m: int,
     return gains[:mc]
 
 
-def _make_score_payload(V, pair, policy, backend, rbf_gamma, block_m,
-                        fn: FnSpec, row_aux, n_total=None):
-    """Build ``score(sc, C) -> gains`` over candidate payload rows.
-
-    Routes through the shared min/max Pallas kernel template when the
-    function has one and the backend asks for kernels; otherwise the blocked
-    jnp reduction. Gains exclude the index-addressed extra term.
-    """
-    tmpl = fx.kernel_template(fn)
-    if backend != "jnp" and tmpl is not None:
-        from repro.kernels import ops as kops
-
-        def score(sc, C):
-            return kops.marginal_gain(
-                V, C, sc, policy=policy, rbf_gamma=rbf_gamma,
-                fold=tmpl[0], score_affine=tmpl[1], n_total=n_total,
-                interpret=(backend != "pallas"))
-    else:
-
-        def score(sc, C):
-            return _score_blocked(V, C, sc, pair, policy, block_m,
-                                  n_total=n_total, fn=fn, row_aux=row_aux)
-
-    return score
-
-
-def _make_fold_and_score(V, pair, policy, backend, rbf_gamma, block_m,
-                         fn: FnSpec = FnSpec(), row_aux=None, n_total=None):
-    """Build fold-winner-then-score for a dense/stochastic scan step.
-
-    Returns ``step(vec, w_row, w_ok, C) -> (gains, new_vec)`` over the cache
-    *vector*: fold the previous winner's row in (gated by the float ``w_ok``
-    — round 0 has no winner, and the max/additive folds are not idempotent),
-    then score candidate payload ``C`` against the updated cache. On Pallas
-    backends with a fused-eligible function the fold rides inside the fused
-    gain kernel; otherwise an explicit O(n) fold precedes (kernel or
-    blocked-jnp) scoring. Scalar aux state and index-addressed gain extras
-    are the caller's business (they need global winner/candidate indices).
-    """
-    tmpl = fx.kernel_template(fn)
-    if backend != "jnp" and tmpl is not None and fx.kernel_fused_ok(fn):
-        from repro.kernels import ops as kops
-
-        def fold_and_score(vec, w_row, w_ok, C):
-            # block_m only sizes the jnp streaming block (HBM working set);
-            # the kernel never materializes the (n, m) matrix and takes
-            # its VMEM tile from ops.gain_tile
-            return kops.fused_gain_update(
-                V, C, vec, w_row, policy=policy, rbf_gamma=rbf_gamma,
-                fold=tmpl[0], score_affine=tmpl[1], n_total=n_total,
-                w_valid=w_ok, interpret=(backend != "pallas"))
-    else:
-        score = _make_score_payload(V, pair, policy, backend, rbf_gamma,
-                                    block_m, fn, row_aux, n_total=n_total)
-
-        def fold_and_score(vec, w_row, w_ok, C):
-            dw = pair(V, w_row[None, :], policy)[:, 0]
-            folded = fx.fold_vec_rows(fn, vec, dw.astype(jnp.float32))
-            vec = jnp.where(w_ok > 0, folded, vec)
-            gains = score(fx.score_cache_rows(fn, vec, row_aux), C)
-            return gains, vec
-
-    return fold_and_score
-
-
 # ---------------------------------------------------------------------------
 # Shared round-step builders — the ONE definition of a selection round,
-# consumed by both the single-device scan below and the mesh-sharded scan in
-# repro.core.distributed (which differs only in its score/fold callbacks).
-# ---------------------------------------------------------------------------
-
-
-def make_rounds_step(take, fold_score_val):
-    """Dense/stochastic scan step over per-round candidate index rows.
-
-    ``fold_score_val(cache, w_prev, cand_t) -> (gains, new_cache, value)``
-    folds the previous winner and scores the round's candidate indices; how
-    the candidate *payload* materializes is the plan's business
-    (single-device: one gather from the resident pool; sharded pool: index
-    blocks psum-materialized from their owning shards, never all at once).
-    ``take(idx)`` resolves a winner index to its ``(payload row, global
-    index)`` carry — the row is the per-round "winner column all-gather"
-    that replaces carrying a materialized candidate block; the global index
-    feeds the next round's gated fold (and index-addressed aux state). The
-    ``cache`` is the function's ``(vec, aux)`` pytree.
-    """
-
-    def step(carry, cand_t):
-        cache, taken, w_prev = carry
-        gains, cache, val = fold_score_val(cache, w_prev, cand_t)
-        live = ~taken[cand_t]
-        gains = jnp.where(live, gains, -jnp.inf)
-        p = jnp.argmax(gains)
-        j = cand_t[p]
-        # a round whose candidates are all taken has no legitimate argmax:
-        # emit the -1 sentinel (the engine boundary raises on it) instead of
-        # silently re-selecting whatever index argmax fell through to
-        j_out = jnp.where(gains[p] > -jnp.inf, j, -1)
-        # cache includes winners 0..t-1 here → val is trajectory[t-1]
-        return ((cache, taken.at[j].set(True), take(j)),
-                (j_out, val, jnp.sum(live).astype(jnp.int32)))
-
-    return step
-
-
-def celf_max_iters(n: int, top_b: int) -> int:
-    """CELF while-loop backstop shared by both execution plans: ⌈n/B⌉
-    iterations re-score every candidate (the loop has then degenerated to a
-    full re-score), +1 slack. The sharded plan's per-iteration psums only
-    line up across shards because every plan agrees on this bound."""
-    return -(-n // top_b) + 1
-
-
-def make_lazy_step(take, n_pool, fold, score_idx_val, top_b: int,
-                   max_iters: int):
-    """CELF scan step: while-loop of top-B re-scoring over stale bounds.
-
-    ``fold(cache, w) -> cache`` folds the previous ``(row, index)`` winner
-    once per round (gated internally on index ≥ 0);
-    ``score_idx_val(cache, idx) -> (gains, value)`` scores candidate
-    *indices* (replicated plans gather-and-score in one batch; the sharded
-    pool streams blocked takes so the transient block never exceeds the
-    resident shard even when top_b > n/p) with one psum carrying both on
-    mesh plans; ``take(idx)`` resolves the winner's index to its
-    ``(payload row, global index)`` carry (sharded pool: one psum
-    materializing only that column — the bound state itself stays a
-    replicated (n,) scalar array, never an (n, d) payload). The loop body
-    always runs ≥ once per round (nothing starts fresh), so ``val`` is
-    always the round's true f(S_t); it stops when the fresh-top invariant —
-    best re-scored gain ≥ every remaining stale bound — certifies the
-    winner, degenerating to a full re-score after ⌈n/B⌉ iterations.
-    """
-
-    def step(carry, _):
-        cache, taken, w_prev, ub = carry
-        cache = fold(cache, w_prev)
-
-        def invariant_fails(st):
-            ub_c, fresh, _, _, it = st
-            stale_max = jnp.max(jnp.where(fresh | taken, -jnp.inf, ub_c))
-            fresh_best = jnp.max(jnp.where(fresh & ~taken, ub_c, -jnp.inf))
-            return (fresh_best < stale_max) & (it < max_iters)
-
-        def rescore_top_b(st):
-            ub_c, fresh, scored, _, it = st
-            stale = jnp.where(fresh | taken, -jnp.inf, ub_c)
-            top_ub, top_idx = jax.lax.top_k(stale, top_b)
-            live = top_ub > -jnp.inf
-            gains_b, val = score_idx_val(cache, top_idx)
-            gains_b = jnp.where(live, gains_b, -jnp.inf)
-            ub_c = ub_c.at[top_idx].set(
-                jnp.where(live, gains_b, ub_c[top_idx]))
-            fresh = fresh.at[top_idx].set(fresh[top_idx] | live)
-            return ub_c, fresh, scored + jnp.sum(live), val, it + 1
-
-        ub, fresh, scored, val, _ = jax.lax.while_loop(
-            invariant_fails, rescore_top_b,
-            (ub, jnp.zeros((n_pool,), bool), jnp.asarray(0, jnp.int32),
-             jnp.asarray(0.0, jnp.float32), jnp.asarray(0, jnp.int32)))
-        j = jnp.argmax(jnp.where(fresh & ~taken, ub, -jnp.inf))
-        # cache includes winners 0..t-1 here → val is trajectory[t-1]
-        return ((cache, taken.at[j].set(True), take(j), ub),
-                (j, val, scored))
-
-    return step
-
-
-# ---------------------------------------------------------------------------
-# Shared scan driver — ub0 seeding, n_scored accounting, final fold, and
-# trajectory concat were once duplicated between the single-device scan below
-# and distributed.make_selection_scan; both now supply only callbacks.
-# ---------------------------------------------------------------------------
-
-
-def drive_selection_scan(*, kind, k, top_b, n_global, pool=None, take=None,
-                         n_pool=None, taken0=None, seed_val=None,
-                         score_idx_val=None, cand_rounds, cache0, w0,
-                         fold, fold_score_val=None, value_of=None,
-                         with_final_cache=False):
-    """Run k selection rounds for any execution plan, given its callbacks.
-
-    The plan supplies only how a candidate batch is scored and how the
-    winner folds into the (possibly sharded) cache; everything else — CELF's
-    ub0 bound seeding, the dense one-row closure vs the stochastic per-round
-    scan xs, ``n_scored`` accounting, the final fold, and the trajectory
-    concat — is plan-independent and lives here, once. The cache is the
-    function's ``(vec, aux)`` pytree; the winner carry is a ``(payload row,
-    global index)`` pair whose index is −1 before round 0 (folds gate on it
-    — the max/additive folds of the function zoo are not idempotent).
-
-    The candidate payload is addressed through ``take(idx) -> (row,
-    gidx)``: pass a resident ``pool`` (single-device / replicated plans;
-    ``take`` defaults to ``(pool[idx], idx)``) or an explicit ``take`` +
-    ``n_pool`` when no plan-wide payload exists (sharded pool: ``take``
-    psum-materializes the requested columns from their owning shards) or
-    when pool-local and global indices differ (GreeDi's merge round).
-    ``taken0`` optionally pre-marks pool rows as taken (GreeDi partitions
-    mask their zero-padding rows this way); ``seed_val`` overrides CELF's
-    ub0 seeding pass (sharded pool: blocked take-and-score, so no transient
-    ever exceeds the resident shard).
-
-    Callbacks (single-device: plain jnp/kernel ops; sharded: the same ops on
-    the local shard with ONE psum per scored batch riding the gains):
-
-    * ``fold(cache, w) -> cache`` — fold a winner ``(row, gidx)`` into the
-      cache (used per lazy round and for the final trajectory point),
-      gated internally on gidx ≥ 0.
-    * ``score_idx_val(cache, idx) -> (gains, value)`` — score candidate
-      indices against the already-folded cache (lazy rescore + ub0 seeding).
-    * ``fold_score_val(cache, w_prev, cand_t) -> (gains, cache, value)`` —
-      the fused dense/stochastic round step over the round's candidate
-      *indices* (on Pallas backends the fold rides inside the gain kernel;
-      sharded pool: blocked take-and-score).
-    * ``value_of(cache) -> scalar`` — the global f(S) of the cache.
-
-    Returns ``(sel, traj, n_scored)`` per-round stacked outputs;
-    ``with_final_cache=True`` appends the fully-folded final cache pytree
-    (jitted callers return its vec so a donated seed buffer aliases it).
-    """
-    if take is None:
-        take = lambda idx: (pool[idx], idx)  # noqa: E731 — replicated default
-        n_pool = pool.shape[0]
-    taken_init = taken0 if taken0 is not None \
-        else jnp.zeros((n_pool,), bool)
-    if kind == "lazy":
-        step = make_lazy_step(take, n_pool, fold, score_idx_val, top_b,
-                              celf_max_iters(n_global, top_b))
-        # round -1: fresh singleton gains seed the bounds (counts one eval
-        # per pool row, exactly like host CELF's initial full scoring)
-        if seed_val is not None:
-            ub0, _ = seed_val(cache0)
-        else:
-            ub0, _ = score_idx_val(
-                cache0, jnp.arange(n_pool, dtype=jnp.int32))
-        init = (cache0, taken_init, w0, ub0)
-        (cache, _, w_last, _), (sel, vals, scored) = jax.lax.scan(
-            step, init, None, length=k)
-        n_scored = jnp.asarray(n_pool, jnp.int32) + jnp.sum(scored)
-    else:
-        step = make_rounds_step(take, fold_score_val)
-        init = (cache0, taken_init, w0)
-        if kind == "dense":
-            # one candidate row closed over by all k rounds
-            cand_row = cand_rounds[0]
-            (cache, _, w_last), (sel, vals, scored) = jax.lax.scan(
-                lambda carry, _: step(carry, cand_row), init, None, length=k)
-        else:
-            (cache, _, w_last), (sel, vals, scored) = jax.lax.scan(
-                step, init, cand_rounds)
-        n_scored = jnp.sum(scored)
-
-    # one final fold for the last trajectory point
-    cache_f = fold(cache, w_last)
-    final_val = value_of(cache_f)
-    traj = jnp.concatenate([vals[1:], final_val[None]])
-    if with_final_cache:
-        return sel.astype(jnp.int32), traj, n_scored, cache_f
-    return sel.astype(jnp.int32), traj, n_scored
-
-
-# ---------------------------------------------------------------------------
-# Batched multi-tenant stepping — B independent requests, ONE dispatch.
+# consumed by the single-device scan below and the mesh-sharded scans in
+# repro.core.distributed (which differ only in their score/fold callbacks).
 #
-# Every carry leaf grows a leading B axis ((B, n) caches, (B, n) taken
-# masks, (B, d) winner rows, (B, n) CELF bounds); gains/argmax/fold/top_k
-# run batched per step. Ragged k rides as a per-request ``k_eff`` vector:
-# rounds t ≥ k_eff[b] freeze request b's carry (its transient fold still
-# produces the correct trajectory value f(S_{k_eff})), emit the −1 sentinel,
-# and count zero evaluations — so bucket-padding slots (k_eff = 0) are
-# completely inert. Per-request selections, trajectories, and evaluation
-# counts are identical to running the unbatched engine B times.
+# Every carry leaf has a leading request axis B ((B, n) caches, (B, n) taken
+# masks, (B, d) winner rows, (B, n) CELF bounds); one request is B = 1.
+# Ragged k rides as a per-request ``k_eff`` vector: rounds t ≥ k_eff[b]
+# freeze request b's carry (its transient fold still produces the correct
+# trajectory value f(S_{k_eff})), emit the −1 sentinel, and count zero
+# evaluations — so bucket-padding slots (k_eff = 0) are completely inert,
+# and each request's selections, trajectory and evaluation count are those
+# of the same request run alone.
 # ---------------------------------------------------------------------------
 
 
@@ -489,15 +235,20 @@ def _freeze_where(act, new, old):
         new, old)
 
 
-def make_batched_rounds_step(take, fold_score_val, k_eff):
-    """Batched :func:`make_rounds_step` — dense/stochastic rounds over a
-    leading request axis.
+def make_rounds_step(take, fold_score_val, k_eff):
+    """Dense/stochastic scan step over per-round candidate index rows.
 
     ``fold_score_val(cache, w_prev, cand_t) -> (gains (B, m), cache,
     value (B,))`` folds each request's previous winner and scores its own
-    candidate row; ``take(idx (B,)) -> ((B, d) rows, idx)`` resolves the
-    per-request winners. ``k_eff`` (B,) int32 is the ragged-k mask: the xs
-    carry the round index t, and requests with t ≥ k_eff freeze.
+    candidate row; how the candidate *payload* materializes is the plan's
+    business (single-device: one gather from the resident pool; sharded
+    pool: index blocks psum-materialized from their owning shards, never
+    all at once). ``take(idx (B,)) -> ((B, d) rows, idx)`` resolves the
+    per-request winners to the next round's ``(payload row, global index)``
+    carry, whose index feeds the gated fold (and index-addressed aux
+    state). The ``cache`` is the function's ``(vec, aux)`` pytree.
+    ``k_eff`` (B,) int32 is the ragged-k mask: the xs carry the round index
+    t, and requests with t ≥ k_eff freeze.
     """
     B = k_eff.shape[0]
     rows = jnp.arange(B)
@@ -506,114 +257,61 @@ def make_batched_rounds_step(take, fold_score_val, k_eff):
         cand_t, t = xs
         cache, taken, w_prev = carry
         gains, cache2, val = fold_score_val(cache, w_prev, cand_t)
-        live = ~jnp.take_along_axis(taken, cand_t, axis=1)
+        live = ~jnp.take_along_axis(taken, cand_t, axis=1, mode="clip")
         gains = jnp.where(live, gains, -jnp.inf)
         p = jnp.argmax(gains, axis=1)
         j = jnp.take_along_axis(cand_t, p[:, None], axis=1)[:, 0]
         best = jnp.take_along_axis(gains, p[:, None], axis=1)[:, 0]
         act = t < k_eff
-        # exhausted sample row → −1 sentinel, exactly like the unbatched
-        # step; frozen rounds also emit −1 (demux truncates them away)
+        # a round whose candidates are all taken has no legitimate argmax:
+        # emit the -1 sentinel (the engine boundary raises on it) instead of
+        # silently re-selecting whatever index argmax fell through to;
+        # frozen rounds also emit −1 (demux truncates them away)
         j_out = jnp.where(act & (best > -jnp.inf), j, -1)
         new_carry = (cache2, taken.at[rows, j].set(True), take(j))
         carry = _freeze_where(act, new_carry, carry)
         scored = jnp.where(act, jnp.sum(live, axis=1).astype(jnp.int32), 0)
+        # cache includes winners 0..t-1 here → val is trajectory[t-1]
         return carry, (j_out, val, scored)
 
     return step
 
 
-def make_batched_lazy_step(take, fold, score_idx, value_of, top_b: int,
-                           max_iters: int, k_eff):
-    """Batched :func:`make_lazy_step` — per-request CELF bound state.
-
-    Each request carries its own (n,) stale bounds, freshness is tracked
-    per request, and the while-loop condition is "ANY request still fails
-    the fresh-top invariant" — a certified (or frozen) request stops
-    scoring immediately (its ``live`` lanes mask out), so per-request
-    evaluation counts match the unbatched engine exactly: within a round a
-    request is active for consecutive iterations 0..c_b−1 and its c_b is
-    the same count the unbatched while-loop would run (the global
-    ``max_iters`` backstop cuts every request at the same iteration the
-    unbatched loop would, because certification is monotone within a
-    round).
-
-    Unlike the unbatched step, the trajectory value is computed directly as
-    ``value_of(cache2)`` rather than riding the re-score callback — the
-    single-device batched plan has no psum to share, and frozen requests
-    (which skip the loop entirely) still need their f(S_{k_eff}) emitted.
-    """
-    B = k_eff.shape[0]
-    rows = jnp.arange(B)[:, None]
-
-    def step(carry, t):
-        cache, taken, w_prev, ub = carry
-        cache2 = fold(cache, w_prev)
-        act = t < k_eff
-        val = value_of(cache2)
-
-        def request_active(ub_c, fresh):
-            stale_max = jnp.max(
-                jnp.where(fresh | taken, -jnp.inf, ub_c), axis=1)
-            fresh_best = jnp.max(
-                jnp.where(fresh & ~taken, ub_c, -jnp.inf), axis=1)
-            return (fresh_best < stale_max) & act
-
-        def invariant_fails(st):
-            ub_c, fresh, _, it = st
-            return jnp.any(request_active(ub_c, fresh)) & (it < max_iters)
-
-        def rescore_top_b(st):
-            ub_c, fresh, scored, it = st
-            active = request_active(ub_c, fresh)
-            stale = jnp.where(fresh | taken, -jnp.inf, ub_c)
-            top_ub, top_idx = jax.lax.top_k(stale, top_b)
-            live = (top_ub > -jnp.inf) & active[:, None]
-            gains_b = score_idx(cache2, top_idx)
-            gains_b = jnp.where(live, gains_b, -jnp.inf)
-            prev = jnp.take_along_axis(ub_c, top_idx, axis=1)
-            ub_c = ub_c.at[rows, top_idx].set(
-                jnp.where(live, gains_b, prev))
-            fresh = fresh.at[rows, top_idx].set(
-                jnp.take_along_axis(fresh, top_idx, axis=1) | live)
-            scored = scored + jnp.sum(live, axis=1).astype(jnp.int32)
-            return ub_c, fresh, scored, it + 1
-
-        ub2, fresh, scored, _ = jax.lax.while_loop(
-            invariant_fails, rescore_top_b,
-            (ub, jnp.zeros(taken.shape, bool),
-             jnp.zeros((B,), jnp.int32), jnp.asarray(0, jnp.int32)))
-        j = jnp.argmax(jnp.where(fresh & ~taken, ub2, -jnp.inf), axis=1)
-        new_carry = (cache2, taken.at[rows[:, 0], j].set(True), take(j), ub2)
-        carry = _freeze_where(act, new_carry, carry)
-        return carry, (jnp.where(act, j, -1), val,
-                       jnp.where(act, scored, 0))
-
-    return step
+def celf_max_iters(n: int, top_b: int) -> int:
+    """CELF while-loop backstop shared by every execution plan: ⌈n/B⌉
+    iterations re-score every candidate (the loop has then degenerated to a
+    full re-score), +1 slack. The sharded plans' per-iteration psums only
+    line up across shards because every plan agrees on this bound."""
+    return -(-n // top_b) + 1
 
 
-def make_batched_lazy_step_val(take, fold, score_idx_val, top_b: int,
-                               max_iters: int, k_eff):
-    """Batched CELF step whose trajectory value RIDES the re-score callback
-    — the mesh-sharded form of :func:`make_batched_lazy_step`.
+def make_lazy_step(take, fold, score_idx_val, top_b: int, max_iters: int,
+                   k_eff):
+    """CELF scan step: while-loop of top-B re-scoring over stale bounds.
 
-    ``score_idx_val(cache, idx (B, m)) -> ((B, m) gains, (B,) value)`` is
-    the sharded plans' one-psum-per-batch callback: every request's gain
-    partials and its stat row-sum cross the mesh in the SAME collective, so
-    a round body issues exactly one O(B·m) psum per re-score iteration and
-    no separate value collective. The value part is computed from the
-    (loop-invariant) folded cache, so whichever iteration runs last yields
-    the same per-request f(S_t) — including frozen requests, whose
-    transient fold still produces their f(S_{k_eff}).
+    ``fold(cache, w) -> cache`` folds the previous ``(rows, idx)`` winners
+    once per round (gated internally on idx ≥ 0);
+    ``score_idx_val(cache, idx (B, m)) -> ((B, m) gains, (B,) value)``
+    scores candidate *indices* against the already-folded cache (mesh
+    plans: every request's gain partials and its stat row-sum cross the
+    mesh in ONE O(B·m) psum per re-score iteration, with no separate value
+    collective; the sharded pool streams blocked takes so the transient
+    block never exceeds the resident shard); ``take(idx)`` resolves the
+    winners (sharded pool: one psum materializing only those columns — the
+    bound state itself stays a replicated (B, n) scalar array, never an
+    (n, d) payload).
 
-    The one structural difference from the single-device batched step: the
-    while loop runs AT LEAST one iteration even when every request is
-    frozen (``it == 0`` keeps the condition alive), because the frozen
-    requests' trajectory values only exist inside the psum the loop body
-    issues. The extra iteration is inert — ``live`` masks every lane, so
-    bounds, freshness, and per-request eval counts are untouched — and on
-    rounds where any request is active the trip count is identical to the
-    single-device batched step's.
+    Each request carries its own stale bounds and freshness, and the
+    while loop runs while ANY active request fails the fresh-top invariant
+    — best re-scored gain ≥ every remaining stale bound — or, on the
+    first iteration, always: the value part of ``score_idx_val`` comes from
+    the (loop-invariant) folded cache, so that iteration yields every
+    request's f(S_t), frozen ones included. A certified (or frozen) request
+    stops scoring at once (its ``live`` lanes mask out), so its evaluation
+    count is the same as alone: within a round a request is active for
+    consecutive iterations, and the global ``max_iters`` backstop cuts every
+    request at the same iteration, because certification is monotone
+    within a round. A full re-score follows after ⌈n/top_b⌉ iterations.
     """
     B = k_eff.shape[0]
     rows = jnp.arange(B)[:, None]
@@ -658,66 +356,88 @@ def make_batched_lazy_step_val(take, fold, score_idx_val, top_b: int,
         j = jnp.argmax(jnp.where(fresh & ~taken, ub2, -jnp.inf), axis=1)
         new_carry = (cache2, taken.at[rows[:, 0], j].set(True), take(j), ub2)
         carry = _freeze_where(act, new_carry, carry)
+        # cache includes winners 0..t-1 here → val is trajectory[t-1]
         return carry, (jnp.where(act, j, -1), val,
                        jnp.where(act, scored, 0))
 
     return step
 
 
-def drive_selection_scan_batched(*, kind, k, top_b, n_global, pool=None,
-                                 k_eff, take=None, n_pool=None,
-                                 seed_val=None, cand_rounds, cache0, w0,
-                                 fold, score_idx=None, score_idx_val=None,
-                                 fold_score_val=None, value_of=None):
-    """Batched :func:`drive_selection_scan` — one scan, B requests.
+# ---------------------------------------------------------------------------
+# Shared scan driver — ub0 seeding, n_scored accounting, final fold, and
+# trajectory concat live here once; every plan supplies only callbacks.
+# ---------------------------------------------------------------------------
 
-    ``pool`` is the (B, n, d) stacked payload; ``cand_rounds`` is
-    (B, k, m) (dense callers broadcast one row; lazy passes (B, 1, 0));
-    ``k_eff`` (B,) int32 the per-request effective k (ragged-k masking —
-    bucket-padding slots pass 0). The callbacks are the batched analogues
-    of the unbatched driver's: ``fold(cache, (rows, idx)) -> cache``,
-    ``score_idx(cache, idx (B, m)) -> (B, m) gains``,
-    ``fold_score_val(cache, w_prev, cand_t) -> (gains, cache, (B,) value)``,
-    ``value_of(cache) -> (B,)``.
 
-    Like the unbatched driver, plans with no resident per-request payload
-    pass an explicit ``take(idx (B,)) -> ((B, d) rows, idx)`` + ``n_pool``
-    instead of ``pool`` (the batched sharded pool psum-materializes each
-    request's columns from their owning shards), and ``seed_val`` overrides
-    CELF's ub0 seeding pass. Mesh plans pass ``score_idx_val`` (gains and
-    per-request trajectory values riding ONE psum —
-    :func:`make_batched_lazy_step_val`) where single-device plans pass
-    ``score_idx``/``value_of`` separately.
+def drive_selection_scan(*, kind, k, top_b, n_global, k_eff, pool=None,
+                         take=None, n_pool=None, taken0=None, seed_val=None,
+                         cand_rounds, cache0, w0, fold, score_idx_val=None,
+                         fold_score_val=None, value_of=None):
+    """Run k selection rounds of B requests for any execution plan, given
+    its callbacks.
+
+    The plan supplies only how a candidate batch is scored and how the
+    winner folds into the (possibly sharded) cache; everything else — CELF's
+    ub0 bound seeding, the dense one-row closure vs the stochastic per-round
+    scan xs, ``n_scored`` accounting, the final fold, and the trajectory
+    concat — is plan-independent and lives here, once. The cache is the
+    function's ``(vec, aux)`` pytree with a leading request axis; the
+    winner carry is a ``((B, d) payload rows, (B,) global indices)`` pair
+    whose index is −1 before round 0 (folds gate on it — the max/additive
+    folds of the function zoo are not idempotent).
+
+    ``cand_rounds`` is (B, k, m) (dense: (B, 1, m), one row closed over by
+    every round, never materialized k times; lazy: (B, 1, 0)); ``k_eff``
+    (B,) int32 the per-request effective k (ragged-k masking —
+    bucket-padding slots pass 0). The candidate payload is addressed
+    through ``take(idx (B,)) -> ((B, d) rows, idx)``: pass a resident
+    (B, n, d) ``pool`` (``take`` defaults to each request's own row) or an
+    explicit ``take`` + ``n_pool`` when no plan-wide payload exists
+    (sharded pool: ``take`` psum-materializes the requested columns from
+    their owning shards) or when pool-local and global indices differ
+    (GreeDi's merge round). ``taken0`` (B, n_pool) optionally pre-marks
+    pool rows as taken (GreeDi partitions mask their zero-padding rows this
+    way); ``seed_val`` overrides CELF's ub0 seeding pass (sharded pool:
+    blocked take-and-score, so no transient ever exceeds the resident
+    shard).
+
+    Callbacks (single-device: plain jnp/kernel ops; sharded: the same ops
+    on the local shard with ONE psum per scored batch riding the gains):
+
+    * ``fold(cache, w) -> cache`` — fold the winners ``(rows, idx)`` into
+      the cache (used per lazy round and for the final trajectory point),
+      gated internally on idx ≥ 0.
+    * ``score_idx_val(cache, idx (B, m)) -> (gains (B, m), value (B,))`` —
+      score candidate indices against the already-folded cache (lazy
+      rescore + ub0 seeding).
+    * ``fold_score_val(cache, w_prev, cand_t) -> (gains, cache, value)`` —
+      the fused dense/stochastic round step over the round's candidate
+      *indices* (on Pallas backends the fold rides inside the gain kernel;
+      sharded pool: blocked take-and-score).
+    * ``value_of(cache) -> (B,)`` — the global f(S) of each cache.
 
     Returns ``(sel (k, B), traj (k, B), n_scored (B,), final cache)`` —
-    the final cache rides out so the jitted dispatch can alias its vec
-    onto the donated seed buffer.
+    the final cache rides out so a jitted dispatch can alias its vec onto
+    the donated seed buffer.
     """
     B = k_eff.shape[0]
     if take is None:
         rows = jnp.arange(B)
         take = lambda idx: (pool[rows, idx], idx)  # noqa: E731
         n_pool = pool.shape[1]
-    taken_init = jnp.zeros((B, n_pool), bool)
+    taken_init = taken0 if taken0 is not None \
+        else jnp.zeros((B, n_pool), bool)
     ts = jnp.arange(k, dtype=jnp.int32)
     if kind == "lazy":
-        if score_idx_val is not None:
-            step = make_batched_lazy_step_val(
-                take, fold, score_idx_val, top_b,
-                celf_max_iters(n_global, top_b), k_eff)
-        else:
-            step = make_batched_lazy_step(
-                take, fold, score_idx, value_of, top_b,
-                celf_max_iters(n_global, top_b), k_eff)
+        step = make_lazy_step(take, fold, score_idx_val, top_b,
+                              celf_max_iters(n_global, top_b), k_eff)
         # round -1: per-request singleton gains seed the bounds (counts one
-        # eval per pool row for every request that runs ≥ 1 round)
+        # eval per pool row for every request that runs ≥ 1 round, exactly
+        # like host CELF's initial full scoring)
         if seed_val is not None:
             ub0, _ = seed_val(cache0)
-        elif score_idx_val is not None:
-            ub0, _ = score_idx_val(cache0, jnp.broadcast_to(
-                jnp.arange(n_pool, dtype=jnp.int32), (B, n_pool)))
         else:
-            ub0 = score_idx(cache0, jnp.broadcast_to(
+            ub0, _ = score_idx_val(cache0, jnp.broadcast_to(
                 jnp.arange(n_pool, dtype=jnp.int32), (B, n_pool)))
         init = (cache0, taken_init, w0, ub0)
         (cache, _, w_last, _), (sel, vals, scored) = jax.lax.scan(
@@ -726,7 +446,7 @@ def drive_selection_scan_batched(*, kind, k, top_b, n_global, pool=None,
             k_eff > 0,
             jnp.asarray(n_pool, jnp.int32) + jnp.sum(scored, axis=0), 0)
     else:
-        step = make_batched_rounds_step(take, fold_score_val, k_eff)
+        step = make_rounds_step(take, fold_score_val, k_eff)
         init = (cache0, taken_init, w0)
         if kind == "dense":
             cand_row = cand_rounds[:, 0, :]
@@ -746,7 +466,7 @@ def drive_selection_scan_batched(*, kind, k, top_b, n_global, pool=None,
 
 
 # ---------------------------------------------------------------------------
-# Single-device one-dispatch scan (plans: device)
+# Single-device one-dispatch scan (plan: device)
 # ---------------------------------------------------------------------------
 
 
@@ -754,140 +474,51 @@ def drive_selection_scan_batched(*, kind, k, top_b, n_global, pool=None,
     "engine.select_scan",
     donate=("seed",),
     memory=True,
-    claim="all k rounds in ONE dispatch; collective-free; the cache seed "
-          "is donated and aliased onto the final cache output; gains stay "
-          "in the compute dtype; temp bytes stay at blocked-tile scale")
+    claim="all k rounds of B independent requests in ONE dispatch; "
+          "collective-free; the (B, n) cache seed is donated and aliased "
+          "onto the final cache output; gains stay in the compute dtype; "
+          "per-request temp bytes stay at blocked-tile scale")
 @partial(jax.jit, static_argnames=("fn", "kind", "k", "top_b", "distance",
                                    "policy_name", "block_m", "backend",
                                    "rbf_gamma", "counter_key"),
          donate_argnums=(1,))
-def _select_scan(V, seed, row_aux, cand_rounds, w0, *, fn, kind, k, top_b,
-                 distance, policy_name, block_m, backend, rbf_gamma,
+def _select_scan(V, seed, row_aux, cand_rounds, w0, k_eff, *, fn, kind, k,
+                 top_b, distance, policy_name, block_m, backend, rbf_gamma,
                  counter_key):
-    """All k selection rounds in one dispatch, for any vec-cache function.
+    """All k selection rounds of B requests in one dispatch, for any
+    vec-cache function; one request is B = 1.
 
-    ``seed`` is DONATED and the final folded cache vector — same (n,)
-    float32 shape — rides out as the 4th output, so XLA aliases the carry's
-    final buffer onto the seed's allocation: repeated same-signature calls
-    (warm-bucket serving) reuse the cache buffer instead of allocating a
-    fresh one per dispatch. Callers therefore pass a freshly-built seed
-    (:func:`run_selection` copies ``f.cache_seed``, which may alias the
-    function's resident ``d_e0``).
+    ``V (B, n, d)``, ``seed / row_aux (B, n)``, ``cand_rounds (B, k, m)``,
+    ``w0 (B, d)``, ``k_eff (B,)``. ``fn`` is the function's static
+    :class:`~repro.core.functions.FnSpec`; ``seed``/``row_aux`` its cache
+    seeds and per-row auxiliaries. The identical cache-semantics helpers
+    the host protocol methods use are re-traced here around the scan (they
+    broadcast over the leading axis; the two index-addressed ones, graph
+    cut's ``gains_index_extra`` / ``fold_aux`` gathers, vmap per request),
+    which is what makes host and device selections agree.
 
-    ``fn`` is the function's static :class:`~repro.core.functions.FnSpec`;
-    ``seed``/``row_aux`` its cache seed and per-row auxiliary. The identical
-    cache-semantics helpers the host protocol methods use are re-traced here
-    around the scan, which is what makes host and device selections agree.
+    ``seed`` is DONATED and the final folded (B, n) float32 cache vector
+    rides out as the 4th output, so XLA aliases the carry's final buffer
+    onto the seed's allocation: repeated same-signature calls (warm-bucket
+    serving) reuse the cache buffer instead of allocating a fresh one per
+    dispatch. Callers therefore pass a freshly-built seed
+    (``f.cache_seed`` may alias the function's resident ``d_e0``).
 
-    ``cand_rounds`` holds the candidate indices: (1, m) for dense (ONE row,
-    closed over by every round — never materialized k times), (k, m) for
-    stochastic (pre-sampled per round), (1, 0) for lazy, which derives its
-    candidates from the carried stale bounds. The carry is ``((vec, aux)
-    cache, taken-mask, previous (row, idx) winner[, stale bounds])``; the
-    winner is folded into the cache at the *start* of the next round (gated
-    on idx ≥ 0 — round 0 has no winner and the max/additive folds are not
-    idempotent) — for dense/stochastic on the Pallas backend with a
-    fused-eligible function the fold rides inside the fused gain kernel so
-    the winner's distance column never re-materializes in HBM; lazy folds
-    once explicitly because its while-loop re-scores variable candidate
-    batches against the already-folded cache.
+    ``cand_rounds`` holds the candidate indices: (B, 1, m) for dense (ONE
+    row a request, closed over by every round), (B, k, m) for stochastic
+    (pre-sampled per round), (B, 1, 0) for lazy, which derives its
+    candidates from the carried stale bounds. The winner is folded into the
+    cache at the *start* of the next round (gated on idx ≥ 0 — round 0 has
+    no winner and the max/additive folds are not idempotent) — for
+    dense/stochastic on the Pallas backend with a fused-eligible function
+    the fold rides inside the fused gain kernel (:mod:`repro.kernels.ops`)
+    so the winner's distance column never re-materializes in HBM; lazy
+    folds once explicitly because its while-loop re-scores variable
+    candidate batches against the already-folded cache. Without a kernel,
+    scoring is a vmapped :func:`_score_blocked`.
 
     Per-round ys are ``(selected index, trajectory value, #actually-scored
     candidates)`` — the last is the engine's honest ``evaluations`` unit.
-    """
-    DEVICE_TRACE_COUNTS[counter_key] += 1
-    policy = resolve_policy(policy_name)
-    pair = dist_mod.resolve_pairwise(distance)
-    n = V.shape[0]
-    seedf = seed.astype(jnp.float32)
-    v0 = jnp.mean(fx.stat_rows(fn, seedf, row_aux))
-
-    def value_of(cache):
-        vec, aux = cache
-        return fx.value_from_stat(
-            fn, v0, jnp.mean(fx.stat_rows(fn, vec, row_aux)), aux, n)
-
-    def fold(cache, w):
-        vec, aux = cache
-        row, idx = w
-        dw = pair(V, row[None, :], policy)[:, 0]
-        folded = fx.fold_vec_rows(fn, vec, dw.astype(jnp.float32))
-        new_aux = fx.fold_aux(fn, vec, aux, idx, 0, n)
-        ok = idx >= 0
-        return (jnp.where(ok, folded, vec), jnp.where(ok, new_aux, aux))
-
-    score = _make_score_payload(V, pair, policy, backend, rbf_gamma,
-                                block_m, fn, row_aux)
-
-    def score_idx(cache, idx):
-        vec, _aux = cache
-        gains = score(fx.score_cache_rows(fn, vec, row_aux), V[idx])
-        extra = fx.gains_index_extra(fn, vec, idx, 0, n, n)
-        return gains if extra is None else gains + extra
-
-    def score_idx_val(cache, idx):
-        return score_idx(cache, idx), value_of(cache)
-
-    fold_score_val = None
-    if kind != "lazy":
-        # no outer candidate padding: _score_blocked (jnp) and the fused
-        # kernel (pallas) both pad internally, so the step construction is
-        # identical to the device_sharded plan's
-        if backend != "jnp" and fx.kernel_fused_ok(fn) \
-                and fx.kernel_template(fn) is not None:
-            fold_and_score = _make_fold_and_score(
-                V, pair, policy, backend, rbf_gamma, block_m, fn=fn,
-                row_aux=row_aux)
-
-            def fold_score_val(cache, w_prev, cand_t):
-                vec, aux = cache
-                row, idx = w_prev
-                gains, vec2 = fold_and_score(
-                    vec, row, (idx >= 0).astype(jnp.float32), V[cand_t])
-                cache2 = (vec2, aux)  # fused-eligible functions carry no aux
-                return gains, cache2, value_of(cache2)
-        else:
-
-            def fold_score_val(cache, w_prev, cand_t):
-                cache2 = fold(cache, w_prev)
-                return score_idx(cache2, cand_t), cache2, value_of(cache2)
-
-    w0c = (w0.astype(V.dtype), jnp.asarray(-1, jnp.int32))
-    sel, traj, n_scored, cache_f = drive_selection_scan(
-        kind=kind, k=k, top_b=top_b, n_global=n, pool=V,
-        cand_rounds=cand_rounds, cache0=(seedf, jnp.float32(0.0)), w0=w0c,
-        fold=fold, score_idx_val=score_idx_val,
-        fold_score_val=fold_score_val, value_of=value_of,
-        with_final_cache=True)
-    return sel, traj, n_scored, cache_f[0]
-
-
-@contract(
-    "engine.select_scan_batched",
-    donate=("seed",),
-    memory=True,
-    claim="all k rounds of B independent requests in ONE dispatch; "
-          "collective-free; the stacked (B, n) seed is donated; per-request "
-          "temp bytes stay at blocked-tile scale")
-@partial(jax.jit, static_argnames=("fn", "kind", "k", "top_b", "distance",
-                                   "policy_name", "block_m", "backend",
-                                   "rbf_gamma", "counter_key"),
-         donate_argnums=(1,))
-def _select_scan_batched(V, seed, row_aux, cand_rounds, w0, k_eff, *, fn,
-                         kind, k, top_b, distance, policy_name, block_m,
-                         backend, rbf_gamma, counter_key):
-    """All k rounds of B independent requests in ONE dispatch.
-
-    The batched mirror of :func:`_select_scan`: ``V (B, n, d)``, ``seed /
-    row_aux (B, n)``, ``cand_rounds (B, k, m)``, ``w0 (B, d)``, ``k_eff
-    (B,)``. The cache-protocol helpers broadcast over the leading axis
-    unchanged; the two index-addressed helpers (graph cut's
-    ``gains_index_extra`` / ``fold_aux`` gathers) vmap per request. Scoring
-    routes through the grid-over-B kernels (:mod:`repro.kernels.ops`
-    batched dispatch) on Pallas backends, a vmapped :func:`_score_blocked`
-    otherwise. ``seed`` is donated exactly like the unbatched dispatch
-    (the final (B, n) cache output aliases it) — callers pass freshly
-    stacked buffers.
     """
     DEVICE_TRACE_COUNTS[counter_key] += 1
     policy = resolve_policy(policy_name)
@@ -937,7 +568,9 @@ def _select_scan_batched(V, seed, row_aux, cand_rounds, w0, k_eff, *, fn,
 
     def score_idx(cache, idx):
         vec, _aux = cache
-        C = jnp.take_along_axis(V, idx[..., None], axis=1)
+        # "clip" indexes like V[idx]: the default "fill" would add a select
+        # over the whole gathered block
+        C = jnp.take_along_axis(V, idx[..., None], axis=1, mode="clip")
         gains = score(fx.score_cache_rows(fn, vec, row_aux), C)
         if fx.gains_index_extra(fn, vec[0], idx[0], 0, n, n) is None:
             return gains
@@ -945,15 +578,18 @@ def _select_scan_batched(V, seed, row_aux, cand_rounds, w0, k_eff, *, fn,
             lambda v, ix: fx.gains_index_extra(fn, v, ix, 0, n, n))(vec, idx)
         return gains + extra
 
+    def score_idx_val(cache, idx):
+        return score_idx(cache, idx), value_of(cache)
+
     fold_score_val = None
     if kind != "lazy":
         if backend != "jnp" and fx.kernel_fused_ok(fn) and tmpl is not None:
-            from repro.kernels import ops as kops
 
             def fold_score_val(cache, w_prev, cand_t):
                 vec, aux = cache
                 row, idx = w_prev
-                C = jnp.take_along_axis(V, cand_t[..., None], axis=1)
+                C = jnp.take_along_axis(V, cand_t[..., None], axis=1,
+                                        mode="clip")
                 gains, vec2 = kops.fused_gain_update(
                     V, C, vec, row, policy=policy, rbf_gamma=rbf_gamma,
                     fold=tmpl[0], score_affine=tmpl[1],
@@ -970,17 +606,23 @@ def _select_scan_batched(V, seed, row_aux, cand_rounds, w0, k_eff, *, fn,
     B = V.shape[0]
     w0c = (w0.astype(V.dtype), jnp.full((B,), -1, jnp.int32))
     cache0 = (seedf, jnp.zeros((B,), jnp.float32))
-    sel, traj, n_scored, cache_f = drive_selection_scan_batched(
+    sel, traj, n_scored, cache_f = drive_selection_scan(
         kind=kind, k=k, top_b=top_b, n_global=n, pool=V, k_eff=k_eff,
         cand_rounds=cand_rounds, cache0=cache0, w0=w0c, fold=fold,
-        score_idx=score_idx, fold_score_val=fold_score_val,
+        score_idx_val=score_idx_val, fold_score_val=fold_score_val,
         value_of=value_of)
     return sel, traj, n_scored, cache_f[0]
-
 
 # ---------------------------------------------------------------------------
 # Engine entry point
 # ---------------------------------------------------------------------------
+
+
+@jax.jit
+def _one_request(*operands):
+    """One request's operands with the scan's leading request axis (B = 1),
+    in one dispatch."""
+    return tuple(x[None] for x in operands)
 
 
 @partial(jax.profiler.annotate_function, name=tracing.RUN_SELECTION)
@@ -1064,14 +706,18 @@ def run_selection(
         if plan == "device":
             bm = block_m if block_m is not None \
                 else _device_block_m(f.n, m_widest)
-            # _select_scan donates the seed: copy it (f.cache_seed may alias
-            # the function's resident d_e0, which must survive this call)
-            seed = jnp.array(f.cache_seed)
-            cand = jnp.asarray(cand_rounds, jnp.int32)
+            # one request is the B = 1 case of the scan, its operands lifted
+            # on the device; the lifted seed is a fresh buffer, so the
+            # donation never consumes f.cache_seed (which may alias the
+            # function's resident d_e0)
+            V1, seed, aux1, w01 = _one_request(f.V, f.cache_seed, f.row_aux,
+                                               w0)
+            cand = jnp.asarray(cand_rounds[None], jnp.int32)
+            k_eff = jnp.asarray([k], jnp.int32)
 
     if plan == "device":
         sel, traj, n_scored, _ = _select_scan(
-            f.V, seed, f.row_aux, cand, w0,
+            V1, seed, aux1, cand, w01, k_eff,
             fn=fn, kind=kind, k=k, top_b=top_b, distance=f.cfg.distance,
             policy_name=policy.name, block_m=bm, backend=backend,
             rbf_gamma=rbf_gamma, counter_key=counter_key)
@@ -1105,15 +751,17 @@ def run_selection(
         raise ValueError(f"unknown execution plan {plan!r}")
 
     with jax.profiler.TraceAnnotation(tracing.RUN_SELECTION_FETCH):
-        sel = [int(x) for x in np.asarray(sel)]
+        # the device plan's one request comes back as (k, 1) and (1,)
+        sel = [int(x) for x in np.ravel(sel)]
         if any(s < 0 for s in sel):
             bad = sel.index(-1)
             raise ValueError(
                 f"round {bad} had no untaken candidate (its sample row is "
                 f"exhausted by earlier selections) — the argmax would "
                 f"silently re-select a taken index")
-        traj = [float(x) for x in np.asarray(traj)]
-        return OptResult(sel, traj[-1] if traj else 0.0, traj, int(n_scored))
+        traj = [float(x) for x in np.ravel(traj)]
+        return OptResult(sel, traj[-1] if traj else 0.0, traj,
+                         int(np.ravel(n_scored)[0]))
 
 
 def _stack_batch_payload(fs: Sequence[SubmodularFunction]) -> dict:
@@ -1204,7 +852,7 @@ def run_selection_batch(
     dense/stochastic strategies; dense may pass None for the
     full-ground-set default. Per-request selections, trajectories, and
     evaluation counts are identical to B :func:`run_selection` calls —
-    only the dispatch is amortized.
+    each of which is this scan at B = 1 — only the dispatch is amortized.
 
     ``plan`` composes the batch axis with the execution plans:
     ``"device"`` (single-device, state (B, n) resident), or
@@ -1212,7 +860,7 @@ def run_selection_batch(
     (B, n/p) per device on ``mesh`` — B per-tenant min-caches row-shard
     with V, each round issues ONE psum of O(B·m) bytes with every
     request's partials stacked into the same collective, and per-request
-    results stay bit-identical to each request's unbatched sharded run).
+    results stay bit-identical to each request's own sharded run).
     ``staged`` optionally passes a payload pre-transferred by
     :func:`stage_selection_batch` (same fs, same plan).
     """
@@ -1301,7 +949,7 @@ def run_selection_batch(
         bm = block_m if block_m is not None \
             else _device_block_m(n, m_widest, n_batch=B)
         payload = staged if staged is not None else _stack_batch_payload(fs)
-        sel, traj, n_scored, _ = _select_scan_batched(
+        sel, traj, n_scored, _ = _select_scan(
             payload["V"], payload["seed"], payload["aux"],
             jnp.asarray(cand_rounds, jnp.int32), payload["w0"],
             jnp.asarray(ks, jnp.int32), fn=fn, kind=kind, k=k, top_b=top_b,

@@ -7,8 +7,8 @@ Two async front ends live here:
 * :class:`SelectionService` — many concurrent *selection* requests (each its
   own (V, k) problem), bucketed by jit signature and solved B-at-a-time
   through :func:`repro.core.engine.run_selection_batch` — ONE batched scan
-  dispatch per bucket, per-request demux, results identical to the
-  unbatched engine.
+  dispatch per bucket, per-request demux, results identical to one
+  request a dispatch.
 
 Streaming ingestion service: async queue in, exemplars out.
 
@@ -771,7 +771,7 @@ class SelectionService:
         runtime_only=True,
         claim="every signature bucket rides ONE run_selection_batch "
               "dispatch (pow2-padded with inert k_eff=0 slots); the traced "
-              "artifact is engine.select_scan_batched's, audited there — "
+              "artifact is engine.select_scan's, audited there — "
               "this contract's own check is the runtime service round trip "
               "(N concurrent tenants, 1 trace, bucket-count dispatches)")
     def _run_bucket(self, reqs: list["_SelectionRequest"],

@@ -495,8 +495,8 @@ def make_sharded_offer_scan(mesh, data_axes, *, spec: SieveSpec,
 # every partition's arithmetic is bit-identical to its own unbatched engine).
 # Kernel backends score all P tables in ONE grid-over-P fused kernel launch
 # (vmap cannot batch a pallas_call), injected through the step's table_gains
-# hook — the gains math is the same kernel body, batched like
-# gain_eval_batched.
+# hook — the gains math is the same kernel body, on a leading grid axis
+# like the selection engine's gain kernels.
 # ---------------------------------------------------------------------------
 
 
@@ -540,7 +540,7 @@ def _offer_block_scan_batched(states, seed, row_aux, idxb, dmatb, validb, *,
             tables = jnp.concatenate(
                 [jnp.broadcast_to(seedf, (idx.shape[0], 1, seedf.shape[0])),
                  sts.caches], axis=1)
-            g_all = kops.sieve_gains_batched(
+            g_all = kops.sieve_gains(
                 tables, dmat, fold=tmpl[0], score_affine=tmpl[1],
                 interpret=(spec.backend != "pallas"))
 
@@ -861,7 +861,7 @@ class BatchedSieveEngine:
     wise), so every partition's members, values, and evaluation counts are
     bit-identical to a standalone :class:`DeviceSieveEngine` fed the same
     sub-stream. Kernel backends score all P tables in one grid-over-P fused
-    launch (:func:`repro.kernels.ops.sieve_gains_batched`).
+    launch (:func:`repro.kernels.ops.sieve_gains` on (P, r, n) tables).
 
     Shares the overlapped-offer pipeline semantics of
     :class:`_SieveEngineBase`: staged payloads, donated state carry, deferred

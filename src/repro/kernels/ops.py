@@ -193,11 +193,12 @@ def gain_working_set(block_n: int, block_m: int, d_pad: int,
     cache in and out, twice each, and the kernel's own columns: norms,
     the winner's distances, the folded cache) and three of Bm (the gains
     twice, and their row sum). The (Bn, Bm) distance tile takes no copy.
-    Both match what a v5e compile of the batched fused kernel asks for at
-    fp32, d_pad=128, to 0.125 MiB: 8.4 MiB at 1024×1024, 14.5 MiB at
-    2048×1024 and 17.0 MiB at 2048×2048, of which the distance tile alone
-    would be 16 MiB; the unbatched kernel asks for less (5.4 and 11.0 MiB
-    at the squares).
+    Both match what a v5e compile of the fused kernel asks for at four
+    requests, fp32, d_pad=128, to 0.125 MiB: 8.4 MiB at 1024×1024,
+    14.5 MiB at 2048×1024 and 17.0 MiB at 2048×2048, of which the distance
+    tile alone would be 16 MiB. At (1024, 2048) the count says 11.5 MiB
+    and four requests ask for 9.96; one request asks for 6.93, so for
+    B = 1 the count is a conservative cap.
     """
     return (2 * (block_n + block_m) * d_pad * policy.itemsize
             + (10 * block_n + 3 * block_m) * LANE * 4)
@@ -216,9 +217,9 @@ def gain_tile(n: int, m: int, d_pad: int,
     ``VMEM_S_BUDGET``, 12 MiB; a v5e kernel may take 16). Larger tiles
     take fewer steps and re-read less, until the working set leaves
     VMEM. Ties go to the fewest steps. Where no tile fits (d in the
-    thousands) the smallest is returned. Every gain kernel, unbatched,
-    batched and on a mesh shard, tiles by this one plan, so equal shapes
-    score in one accumulation order.
+    thousands) the smallest is returned. Every gain kernel, for one
+    request, for B and on a mesh shard, tiles by this one plan, so equal
+    shapes score in one accumulation order.
     """
     bn_opts = sorted({min(b, _round_up(n, SUBLANE)) for b in GAIN_TILES})
     bm_opts = sorted({min(b, _round_up(m, SUBLANE)) for b in GAIN_TILES})
@@ -353,7 +354,9 @@ def exemplar_eval(
 
 
 def _pad_gain_operands(V, C, cache, block_n, block_m, cache_pad: float = 0.0):
-    """Pad V/C/cache to lane- and block-aligned shapes for the gain kernels.
+    """Pad (B, …) V/C/cache to lane- and block-aligned shapes for the gain
+    kernels. The batch axis is never padded here (bucket padding is the
+    serving layer's job).
 
     ``cache_pad`` is the dead-row sentinel for the padded cache entries: 0
     under the min template (relu(0 − d) = 0 for d ≥ 0) and +inf under the
@@ -361,21 +364,6 @@ def _pad_gain_operands(V, C, cache, block_n, block_m, cache_pad: float = 0.0):
     similarity to candidates, so only an infinite cache entry keeps pad rows
     inert).
     """
-    d_pad = _round_up(V.shape[1], LANE)
-    n_pad = _round_up(V.shape[0], block_n)
-    m_pad = _round_up(C.shape[0], block_m)
-    Vp = _pad_axis(_pad_axis(V, n_pad, 0), d_pad, 1)
-    Cp = _pad_axis(_pad_axis(C, m_pad, 0), d_pad, 1)
-    cache_p = _pad_axis(cache.astype(jnp.float32), n_pad, 0,
-                        value=cache_pad)[:, None]
-    return Vp, Cp, cache_p, d_pad
-
-
-def _pad_gain_operands_batched(V, C, cache, block_n, block_m,
-                               cache_pad: float = 0.0):
-    """Batched (B-leading) analogue of :func:`_pad_gain_operands` — pads the
-    row/candidate/feature axes; the batch axis is never padded here (bucket
-    padding is the serving layer's job)."""
     d_pad = _round_up(V.shape[2], LANE)
     n_pad = _round_up(V.shape[1], block_n)
     m_pad = _round_up(C.shape[1], block_m)
@@ -393,7 +381,7 @@ def _pad_gain_operands_batched(V, C, cache, block_n, block_m,
 )
 def _marginal_gain_padded(V, C, cache, *, policy, interpret, rbf_gamma,
                           n_total, block_n, block_m, fold, score_affine):
-    m = C.shape[0]
+    m = C.shape[1]
     Vp, Cp, cache_p, _ = _pad_gain_operands(
         V, C, cache, block_n, block_m,
         cache_pad=float("inf") if fold == "max" else 0.0)
@@ -401,7 +389,7 @@ def _marginal_gain_padded(V, C, cache, *, policy, interpret, rbf_gamma,
         Vp, Cp, cache_p, n_total=n_total, policy=policy,
         block_n=block_n, block_m=block_m, rbf_gamma=rbf_gamma,
         fold=fold, affine=score_affine, interpret=interpret)
-    return out[:m, 0]
+    return out[:, :m, 0]
 
 
 def marginal_gain(
@@ -418,41 +406,38 @@ def marginal_gain(
     fold: str = "min",
     score_affine: Optional[tuple] = None,
 ) -> jax.Array:
-    """Δ(c_j | S) for all candidates — (m,) float32.
+    """Δ(c_j | S) for all candidates — (m,) float32, or (B, m) for B
+    requests.
+
+    ``V (n, d)``, ``C (m, d)`` and ``mincache (n,)`` score one request;
+    ``V (B, n, d)``, ``C (B, m, d)`` and ``mincache (B, n)`` score B
+    independent requests in one launch. One request is the B = 1 case of
+    the same kernel (:mod:`repro.kernels.marginal_gain`).
 
     ``n_total`` overrides the |V| normalizer: pass the *global* ground-set
     size when V is one row-shard of a mesh-sharded ground set, so per-shard
-    partial gains ``psum`` to the exact global gains. The two axes compose:
-    a 3-D ``V`` of shape (B, n_loc, d) inside shard_map is B tenants' row
-    shards scored by ONE grid-over-(B, m_tiles, local-n_tiles) launch whose
-    (B, m) output is that shard's exact partial of the round's single
-    O(B·m) psum (the batched-sharded plans in core/distributed.py).
+    partial gains ``psum`` to the exact global gains. Inside shard_map a
+    (B, n_loc, d) V is B tenants' row shards, and the (B, m) output is that
+    shard's exact partial of the round's single O(B·m) psum (the sharded
+    plans in core/distributed.py).
 
     ``fold``/``score_affine`` select the kernel template (see
     :mod:`repro.kernels.marginal_gain`): the default ``"min"`` scores the
     exemplar min-distance cache; ``("max", (α, β))`` scores
     relu((α + β·d) − cache) against a max-similarity cache.
 
-    Batched dispatch: pass ``V (B, n, d)``, ``C (B, m, d)``, and
-    ``mincache (B, n)`` and the call routes to the grid-over-B kernel —
-    one launch scores all B requests with per-request block shapes identical
-    to the unbatched path (bit-compatible per-request gains).
-
     The tile is :func:`gain_tile`'s for (n, m); ``block_n``/``block_m``
     override it.
     """
     _check_compiled_policy(policy, interpret)
-    if V.ndim == 3:
-        n = V.shape[1]
-        bn, bm = _gain_blocks(n, C.shape[1], V.shape[2], policy, block_n,
-                              block_m)
-        return _marginal_gain_padded_batched(
-            V, C, mincache, policy=policy, interpret=interpret,
-            rbf_gamma=rbf_gamma, n_total=n_total if n_total is not None else n,
-            block_n=bn, block_m=bm, fold=fold,
-            score_affine=None if score_affine is None else tuple(score_affine))
-    n = V.shape[0]
-    bn, bm = _gain_blocks(n, C.shape[0], V.shape[1], policy, block_n, block_m)
+    if V.ndim == 2:
+        return marginal_gain(
+            V[None], C[None], mincache[None], policy=policy,
+            interpret=interpret, rbf_gamma=rbf_gamma, block_n=block_n,
+            block_m=block_m, n_total=n_total, fold=fold,
+            score_affine=score_affine)[0]
+    n = V.shape[1]
+    bn, bm = _gain_blocks(n, C.shape[1], V.shape[2], policy, block_n, block_m)
     return _marginal_gain_padded(
         V, C, mincache, policy=policy, interpret=interpret,
         rbf_gamma=rbf_gamma, n_total=n_total if n_total is not None else n,
@@ -465,39 +450,20 @@ def marginal_gain(
     static_argnames=("policy", "interpret", "rbf_gamma", "n_total",
                      "block_n", "block_m", "fold", "score_affine"),
 )
-def _marginal_gain_padded_batched(V, C, cache, *, policy, interpret,
-                                  rbf_gamma, n_total, block_n, block_m,
-                                  fold, score_affine):
-    m = C.shape[1]
-    Vp, Cp, cache_p, _ = _pad_gain_operands_batched(
-        V, C, cache, block_n, block_m,
-        cache_pad=float("inf") if fold == "max" else 0.0)
-    out = _mg.gain_eval_batched(
-        Vp, Cp, cache_p, n_total=n_total, policy=policy,
-        block_n=block_n, block_m=block_m, rbf_gamma=rbf_gamma,
-        fold=fold, affine=score_affine, interpret=interpret)
-    return out[:, :m, 0]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("policy", "interpret", "rbf_gamma", "n_total",
-                     "block_n", "block_m", "fold", "score_affine"),
-)
 def _fused_gain_update_padded(V, C, cache, winner, w_valid, *, policy,
                               interpret, rbf_gamma, n_total, block_n,
                               block_m, fold, score_affine):
-    n, m = V.shape[0], C.shape[0]
+    n, m = V.shape[1], C.shape[1]
     Vp, Cp, cache_p, d_pad = _pad_gain_operands(
         V, C, cache, block_n, block_m,
         cache_pad=float("inf") if fold == "max" else 0.0)
-    w_p = _pad_axis(winner[None, :], d_pad, 1)
-    wv = jnp.reshape(w_valid.astype(jnp.float32), (1, 1))
+    w_p = _pad_axis(winner[:, None, :], d_pad, 2)
+    wv = jnp.reshape(w_valid.astype(jnp.float32), (-1, 1, 1))
     gains, new_cache = _mg.gain_update_eval(
         Vp, Cp, cache_p, w_p, wv, n_total=n_total, policy=policy,
         block_n=block_n, block_m=block_m, rbf_gamma=rbf_gamma,
         fold=fold, affine=score_affine, interpret=interpret)
-    return gains[:m, 0], new_cache[:n, 0]
+    return gains[:, :m, 0], new_cache[:, :n, 0]
 
 
 def fused_gain_update(
@@ -524,63 +490,37 @@ def fused_gain_update(
     with V a row-shard, gains come back divided by the *global* n and the
     updated cache shard stays local — exactly the engine's psum contract.
 
-    ``w_valid`` (traced scalar, default 1) gates the fold: pass 0 on the
-    round-0 step where no previous winner exists. The min fold is idempotent
+    ``w_valid`` (traced, default 1) gates the fold: pass 0 on the round-0
+    step where no previous winner exists. The min fold is idempotent
     against its own seed so exemplar callers may omit it, but the max fold
     is not — generic callers must gate.
 
-    Batched dispatch: pass ``V (B, n, d)``, ``C (B, m, d)``,
-    ``mincache (B, n)``, ``winner (B, d)``, and ``w_valid (B,)`` — one
-    launch folds+scores all B requests; the per-request ``w_valid`` lane
-    doubles as the ragged-k gate (a request past its effective k passes 0
-    and its cache stays frozen in-kernel).
+    B requests: pass ``V (B, n, d)``, ``C (B, m, d)``, ``mincache (B, n)``,
+    ``winner (B, d)`` and ``w_valid (B,)`` — one launch folds and scores
+    all B; the per-request ``w_valid`` lane doubles as the ragged-k gate (a
+    request past its effective k passes 0 and its cache stays frozen
+    in-kernel). One request, 2-D, is the B = 1 case.
 
     The tile is :func:`gain_tile`'s for (n, m); ``block_n``/``block_m``
     override it.
     """
     _check_compiled_policy(policy, interpret)
-    if V.ndim == 3:
-        n = V.shape[1]
-        bn, bm = _gain_blocks(n, C.shape[1], V.shape[2], policy, block_n,
-                              block_m)
-        if w_valid is None:
-            w_valid = jnp.ones((V.shape[0],), jnp.float32)
-        return _fused_gain_update_padded_batched(
-            V, C, mincache, winner, w_valid, policy=policy,
-            interpret=interpret, rbf_gamma=rbf_gamma,
-            n_total=n_total if n_total is not None else n,
-            block_n=bn, block_m=bm, fold=fold,
-            score_affine=None if score_affine is None else tuple(score_affine))
-    n = V.shape[0]
-    bn, bm = _gain_blocks(n, C.shape[0], V.shape[1], policy, block_n, block_m)
+    if V.ndim == 2:
+        gains, new_cache = fused_gain_update(
+            V[None], C[None], mincache[None], winner[None], policy=policy,
+            interpret=interpret, rbf_gamma=rbf_gamma, block_n=block_n,
+            block_m=block_m, n_total=n_total, fold=fold,
+            score_affine=score_affine, w_valid=w_valid)
+        return gains[0], new_cache[0]
+    n = V.shape[1]
+    bn, bm = _gain_blocks(n, C.shape[1], V.shape[2], policy, block_n, block_m)
     if w_valid is None:
-        w_valid = jnp.float32(1.0)
+        w_valid = jnp.ones((V.shape[0],), jnp.float32)
     return _fused_gain_update_padded(
         V, C, mincache, winner, w_valid, policy=policy, interpret=interpret,
         rbf_gamma=rbf_gamma, n_total=n_total if n_total is not None else n,
         block_n=bn, block_m=bm, fold=fold,
         score_affine=None if score_affine is None else tuple(score_affine))
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("policy", "interpret", "rbf_gamma", "n_total",
-                     "block_n", "block_m", "fold", "score_affine"),
-)
-def _fused_gain_update_padded_batched(V, C, cache, winner, w_valid, *,
-                                      policy, interpret, rbf_gamma, n_total,
-                                      block_n, block_m, fold, score_affine):
-    n, m = V.shape[1], C.shape[1]
-    Vp, Cp, cache_p, d_pad = _pad_gain_operands_batched(
-        V, C, cache, block_n, block_m,
-        cache_pad=float("inf") if fold == "max" else 0.0)
-    w_p = _pad_axis(winner[:, None, :], d_pad, 2)
-    wv = jnp.reshape(w_valid.astype(jnp.float32), (-1, 1, 1))
-    gains, new_cache = _mg.gain_update_eval_batched(
-        Vp, Cp, cache_p, w_p, wv, n_total=n_total, policy=policy,
-        block_n=block_n, block_m=block_m, rbf_gamma=rbf_gamma,
-        fold=fold, affine=score_affine, interpret=interpret)
-    return gains[:, :m, 0], new_cache[:, :n, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -599,7 +539,9 @@ def sieve_gains(
     fold: str = "min",
     score_affine: Optional[tuple] = None,
 ) -> jax.Array:
-    """Per-row gains of a cache table vs one stream element — (r,).
+    """Per-row gains of a cache table vs one stream element — (r,), or
+    (P, r) for P partition tables ``(P, r, n)`` scored against P elements
+    ``(P, n)`` in one launch (one table is the P = 1 case).
 
     min template (default): row r gets
     ``n_total⁻¹ Σ_i relu(table[r, i] − dvec[i])``; max template
@@ -616,55 +558,21 @@ def sieve_gains(
     relu(α − t) > 0 against finite rows, while a +inf column drives the
     affine to −inf before the relu.
     """
-    r, n = table.shape
+    if table.ndim == 2:
+        return sieve_gains(
+            table[None], dvec[None], n_total=n_total, interpret=interpret,
+            block_s=block_s, block_n=block_n, fold=fold,
+            score_affine=score_affine)[0]
+    _, r, n = table.shape
     bs = min(block_s, _round_up(r, SUBLANE))
     bn = min(block_n, _round_up(n, LANE))
     pad = float("inf") if fold == "max" else 0.0
     Tp = _pad_axis(
-        _pad_axis(table.astype(jnp.float32), _round_up(r, bs), 0, value=pad),
-        _round_up(n, bn), 1, value=pad)
-    dp = _pad_axis(dvec.astype(jnp.float32), _round_up(n, bn), 0,
-                   value=pad)[None, :]
-    out = _mg.sieve_gain_eval(
-        Tp, dp, n_total=n_total if n_total is not None else n,
-        block_s=bs, block_n=bn, fold=fold,
-        affine=None if score_affine is None else tuple(score_affine),
-        interpret=interpret)
-    return out[:r, 0]
-
-
-def sieve_gains_batched(
-    tables: jax.Array,     # (P, r, n) float32 per-partition cache rows
-    dvecs: jax.Array,      # (P, n) float32 per-partition element distances
-    *,
-    n_total: Optional[int] = None,
-    interpret: bool = False,
-    block_s: int = 64,
-    block_n: int = 512,
-    fold: str = "min",
-    score_affine: Optional[tuple] = None,
-) -> jax.Array:
-    """Batched :func:`sieve_gains` — P partition tables scored against P
-    stream elements in ONE grid-over-P kernel launch; returns (P, r).
-
-    Tile sizes, padding sentinels, and per-partition accumulation order
-    match the unbatched wrapper exactly, so each partition's gains are
-    bit-identical to its own :func:`sieve_gains` call — the invariant the
-    batched multi-stream sieve engine's parity rests on. Like the unbatched
-    wrapper it is NOT jit-wrapped (it traces inside the batched per-block
-    scan).
-    """
-    P, r, n = tables.shape
-    bs = min(block_s, _round_up(r, SUBLANE))
-    bn = min(block_n, _round_up(n, LANE))
-    pad = float("inf") if fold == "max" else 0.0
-    Tp = _pad_axis(
-        _pad_axis(tables.astype(jnp.float32), _round_up(r, bs), 1,
-                  value=pad),
+        _pad_axis(table.astype(jnp.float32), _round_up(r, bs), 1, value=pad),
         _round_up(n, bn), 2, value=pad)
-    dp = _pad_axis(dvecs.astype(jnp.float32), _round_up(n, bn), 1,
+    dp = _pad_axis(dvec.astype(jnp.float32), _round_up(n, bn), 1,
                    value=pad)[:, None, :]
-    out = _mg.sieve_gain_eval_batched(
+    out = _mg.sieve_gain_eval(
         Tp, dp, n_total=n_total if n_total is not None else n,
         block_s=bs, block_n=bn, fold=fold,
         affine=None if score_affine is None else tuple(score_affine),

@@ -224,7 +224,7 @@ def test_batched_dispatch_consumes_its_seed():
     w0_b = jnp.zeros((2, D), V_b.dtype)
     cand = jnp.broadcast_to(jnp.arange(N, dtype=jnp.int32)[None, None, :],
                             (2, 1, N))
-    eng._select_scan_batched(
+    eng._select_scan(
         V_b, seed_b, aux_b, cand, w0_b, jnp.asarray([K, K], jnp.int32),
         fn=fs[0].spec, kind="dense", k=K, top_b=0,
         distance=fs[0].cfg.distance, policy_name="fp32", block_m=N,

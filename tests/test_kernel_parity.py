@@ -268,47 +268,57 @@ def test_planned_gain_tile_matches_fixed_tile_and_oracle(fold):
 
 @pytest.mark.parametrize("fold", ["min", "max"])
 def test_batched_b1_equals_unbatched_at_planned_tile(fold):
-    """The batched kernels at B = 1 take the unbatched plan's tile and
-    return its gains and cache bit for bit."""
+    """One request through the 2-D entry points (the kernels' B = 1 case)
+    scores bit for bit what its row of a B = 3 call scores, at the planned
+    tile, whatever the other rows hold: requests never mix on the grid,
+    and a request's gains and cache do not depend on B."""
     from repro.kernels import ops
 
     V, C, cache, w, kw, _ = _gain_plan_problem(fold)
-    g, nc = ops.fused_gain_update(V, C, cache, w, **kw)
-    gb, ncb = ops.fused_gain_update(V[None], C[None], cache[None], w[None],
-                                    w_valid=jnp.ones((1,), jnp.float32), **kw)
-    np.testing.assert_array_equal(np.asarray(gb[0]), np.asarray(g))
-    np.testing.assert_array_equal(np.asarray(ncb[0]), np.asarray(nc))
-    s = ops.marginal_gain(V, C, cache, **kw)
-    sb = ops.marginal_gain(V[None], C[None], cache[None], **kw)
-    np.testing.assert_array_equal(np.asarray(sb[0]), np.asarray(s))
+    # three distinct requests of one shape; the middle one folds no winner
+    Vb = jnp.stack([V, V[::-1] * 0.5, V + 0.25])
+    Cb = jnp.stack([C, C[::-1], C * 0.5])
+    cb = jnp.stack([cache, cache[::-1], cache * 0.5])
+    wb = jnp.stack([w, w[::-1], w * 0.5])
+    wv = jnp.asarray([1.0, 0.0, 1.0], jnp.float32)
+    gb, ncb = ops.fused_gain_update(Vb, Cb, cb, wb, w_valid=wv, **kw)
+    sb = ops.marginal_gain(Vb, Cb, cb, **kw)
+    for b in range(3):
+        g, nc = ops.fused_gain_update(Vb[b], Cb[b], cb[b], wb[b],
+                                      w_valid=wv[b], **kw)
+        np.testing.assert_array_equal(np.asarray(gb[b]), np.asarray(g))
+        np.testing.assert_array_equal(np.asarray(ncb[b]), np.asarray(nc))
+        s = ops.marginal_gain(Vb[b], Cb[b], cb[b], **kw)
+        np.testing.assert_array_equal(np.asarray(sb[b]), np.asarray(s))
 
 
 @pytest.mark.parametrize("batched", [False, True])
 def test_gain_entry_points_launch_the_planned_tile(monkeypatch, batched):
-    """marginal_gain and fused_gain_update, unbatched and batched, launch
-    their kernels at ``gain_tile``'s (Bn, Bm) for the call's (n, m)."""
+    """marginal_gain and fused_gain_update, called with one 2-D request
+    and with a 3-D batch, launch the one kernel of each at
+    ``gain_tile``'s (Bn, Bm) for the call's (n, m)."""
     from repro.core.precision import FP32
     from repro.kernels import marginal_gain as mg
     from repro.kernels import ops
 
     launched = {}
-    for name in ("gain_eval", "gain_update_eval", "gain_eval_batched",
-                 "gain_update_eval_batched"):
+    for name in ("gain_eval", "gain_update_eval"):
         def spy(*args, _kernel=getattr(mg, name), _name=name, **kw):
-            launched[_name] = (kw["block_n"], kw["block_m"])
+            launched[_name] = (args[0].shape[0], kw["block_n"],
+                               kw["block_m"])
             return _kernel(*args, **kw)
         monkeypatch.setattr(mg, name, spy)
     V, C, cache, w, kw, _ = _gain_plan_problem("min")
     # shapes no other test traces, so the jitted wrappers trace here
     V, C, cache, w = V[:2590], C[:2290], cache[:2590], w
+    b = 1
     if batched:
-        V, C, cache, w = V[None], C[None], cache[None], w[None]
+        b = 2
+        V, C, cache, w = (jnp.stack([x, x]) for x in (V, C, cache, w))
     ops.marginal_gain(V, C, cache, **kw)
     ops.fused_gain_update(V, C, cache, w, **kw)
-    tile = ops.gain_tile(2590, 2290, ops.LANE, FP32)
-    suffix = "_batched" if batched else ""
-    assert launched == {"gain_eval" + suffix: tile,
-                        "gain_update_eval" + suffix: tile}
+    tile = (b,) + ops.gain_tile(2590, 2290, ops.LANE, FP32)
+    assert launched == {"gain_eval": tile, "gain_update_eval": tile}
 
 
 def test_sieve_gains_max_template_matches_jnp():

@@ -75,36 +75,32 @@ def _has_kernel(text: str, name: str) -> bool:
     return re.search(rf"%{re.escape(name)}(\.\d+)? = ", text) is not None
 
 
-def _gain_operands(sds, policy, batched):
-    lead = (B,) if batched else ()
+def _gain_operands(sds, policy, b):
     dt = policy.compute_dtype
-    return (sds(lead + (N, D), dt), sds(lead + (M, D), dt),
-            sds(lead + (N, 1), jnp.float32))
+    return (sds((b, N, D), dt), sds((b, M, D), dt),
+            sds((b, N, 1), jnp.float32))
 
 
 @pytest.mark.parametrize("fold", ["min", "max"])
 @pytest.mark.parametrize("pname", ["fp32", "bf16"])
-@pytest.mark.parametrize("kernel", ["gain_eval", "gain_update_eval",
-                                    "gain_eval_batched",
-                                    "gain_update_eval_batched"])
-def test_gain_kernels_compile_for_v5e(one_chip, compiled, kernel, pname,
+@pytest.mark.parametrize("b", [1, B])
+@pytest.mark.parametrize("kernel", ["gain_eval", "gain_update_eval"])
+def test_gain_kernels_compile_for_v5e(one_chip, compiled, kernel, b, pname,
                                       fold):
-    assert "tpu_custom_call" in _compile_gain(one_chip, compiled, kernel,
+    assert "tpu_custom_call" in _compile_gain(one_chip, compiled, kernel, b,
                                               pname, fold)
 
 
-def _compile_gain(one_chip, compiled, kernel, pname, fold) -> str:
-    key = ("gain", kernel, pname, fold)
+def _compile_gain(one_chip, compiled, kernel, b, pname, fold) -> str:
+    key = ("gain", kernel, b, pname, fold)
     if key in compiled:
         return compiled[key]
     policy = POLICY[pname]
-    batched = kernel.endswith("_batched")
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    args = _gain_operands(sds, policy, batched)
+    args = _gain_operands(sds, policy, b)
     if "update" in kernel:
-        lead = (B,) if batched else ()
-        args += (sds(lead + (1, D), policy.compute_dtype),
-                 sds(lead + (1, 1), jnp.float32))
+        args += (sds((b, 1, D), policy.compute_dtype),
+                 sds((b, 1, 1), jnp.float32))
     fn = functools.partial(
         getattr(mg, kernel), n_total=N, policy=policy, block_n=BLOCK,
         block_m=BLOCK, fold=fold, affine=AFFINE[fold])
@@ -112,44 +108,39 @@ def _compile_gain(one_chip, compiled, kernel, pname, fold) -> str:
     return compiled[key]
 
 
-@pytest.mark.parametrize("kernel", ["gain_update_eval",
-                                    "gain_update_eval_batched"])
-def test_gain_update_compiles_at_greedy_cell_size(one_chip, kernel):
-    """The fused gain kernels, through their padding wrappers, at the
-    Greedy cell's size (bench/configs/paper_v_a_greedy.json: N = m =
-    50,000, d = 100 in 128 lanes, fp32) with the tile ``ops.gain_tile``
-    picks there, which is where the plan meets the chip's VMEM."""
+@pytest.mark.parametrize("b", [1, B])
+def test_gain_update_compiles_at_greedy_cell_size(one_chip, b):
+    """The fused gain kernel, through its padding wrapper, at the Greedy
+    cell's size (bench/configs/paper_v_a_greedy.json: N = m = 50,000,
+    d = 100 in 128 lanes, fp32) with the tile ``ops.gain_tile`` picks
+    there, which is where the plan meets the chip's VMEM: one request
+    (the cell's B = 1) and a bucket of B."""
     n, d = GREEDY["n"], GREEDY["d"]
     bn, bm = ops.gain_tile(n, n, ops._round_up(d, ops.LANE), FP32)
-    batched = kernel.endswith("_batched")
-    lead = (B,) if batched else ()
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    wrapper = (ops._fused_gain_update_padded_batched if batched
-               else ops._fused_gain_update_padded)
-    text = wrapper.lower(
-        sds(lead + (n, d), jnp.float32), sds(lead + (n, d), jnp.float32),
-        sds(lead + (n,), jnp.float32), sds(lead + (d,), jnp.float32),
-        sds(lead, jnp.float32), policy=FP32, interpret=False,
+    text = ops._fused_gain_update_padded.lower(
+        sds((b, n, d), jnp.float32), sds((b, n, d), jnp.float32),
+        sds((b, n), jnp.float32), sds((b, d), jnp.float32),
+        sds((b,), jnp.float32), policy=FP32, interpret=False,
         rbf_gamma=None, n_total=n, block_n=bn, block_m=bm, fold="min",
         score_affine=None).compile().as_text()
-    assert "tpu_custom_call" in text and _has_kernel(text, kernel)
+    assert "tpu_custom_call" in text and _has_kernel(text, "gain_update_eval")
 
 
-@pytest.mark.parametrize("batched", [False, True])
-def test_sieve_kernels_compile_for_v5e(one_chip, compiled, batched):
-    assert "tpu_custom_call" in _compile_sieve(one_chip, compiled, batched)
+@pytest.mark.parametrize("p", [1, 8])
+def test_sieve_kernels_compile_for_v5e(one_chip, compiled, p):
+    assert "tpu_custom_call" in _compile_sieve(one_chip, compiled, p)
 
 
-def _compile_sieve(one_chip, compiled, batched) -> str:
-    key = ("sieve", batched)
+def _compile_sieve(one_chip, compiled, p) -> str:
+    key = ("sieve", p)
     if key in compiled:
         return compiled[key]
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    lead = (8,) if batched else ()
-    kernel = mg.sieve_gain_eval_batched if batched else mg.sieve_gain_eval
-    fn = functools.partial(kernel, n_total=N, block_s=64, block_n=512)
+    fn = functools.partial(mg.sieve_gain_eval, n_total=N, block_s=64,
+                           block_n=512)
     compiled[key] = _compiled_text(
-        fn, sds(lead + (128, N), jnp.float32), sds(lead + (1, N), jnp.float32))
+        fn, sds((p, 128, N), jnp.float32), sds((p, 1, N), jnp.float32))
     return compiled[key]
 
 
@@ -233,20 +224,20 @@ def test_loop_kernel_chunks_k_at_paper_size(one_chip, compiled, pname, k):
     assert _has_kernel(text, "exemplar_eval_loop")
 
 
-@pytest.mark.parametrize("kernel", ["gain_eval", "gain_update_eval",
-                                    "gain_eval_batched",
-                                    "gain_update_eval_batched"])
-def test_gain_kernel_names(one_chip, compiled, kernel):
+@pytest.mark.parametrize("b", [1, B])
+@pytest.mark.parametrize("kernel", ["gain_eval", "gain_update_eval"])
+def test_gain_kernel_names(one_chip, compiled, kernel, b):
     """Each gain kernel is named after its function in the compiled
-    program (the fp32 min-fold program of the compile test above)."""
-    assert _has_kernel(_compile_gain(one_chip, compiled, kernel, "fp32",
+    program (the fp32 min-fold program of the compile test above), for
+    one request and for B."""
+    assert _has_kernel(_compile_gain(one_chip, compiled, kernel, b, "fp32",
                                      "min"), kernel)
 
 
-@pytest.mark.parametrize("batched", [False, True])
-def test_sieve_kernel_names(one_chip, compiled, batched):
-    name = "sieve_gain_eval_batched" if batched else "sieve_gain_eval"
-    assert _has_kernel(_compile_sieve(one_chip, compiled, batched), name)
+@pytest.mark.parametrize("p", [1, 8])
+def test_sieve_kernel_names(one_chip, compiled, p):
+    assert _has_kernel(_compile_sieve(one_chip, compiled, p),
+                       "sieve_gain_eval")
 
 
 @pytest.mark.parametrize("pname", ["fp16", "fp16_strict"])
