@@ -323,21 +323,40 @@ def _eval_naive(V, packed, d_e0, cfg) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+@partial(jax.jit, static_argnames=("distance", "policy_name"))
+def _e0_column(V, e0, distance, policy_name):
+    if e0 is None:
+        e0 = jnp.zeros((V.shape[-1],), V.dtype)
+    pair = dist_mod.resolve_pairwise(distance)
+    d_e0 = pair(V, e0[None, :], resolve_policy(policy_name))[:, 0]
+    return d_e0, jnp.mean(d_e0.astype(jnp.float32))
+
+
+def e0_column_and_loss(
+    V: jax.Array,
+    e0: Optional[jax.Array],
+    distance: str,
+    policy: "str | PrecisionPolicy" = "fp32",
+) -> tuple[jax.Array, jax.Array]:
+    """d(v_i, e0) for all i and its float32 mean L({e0}), one dispatch.
+
+    e0 defaults to the all-zero auxiliary vector. ``policy`` is the
+    caller's precision policy: half-precision sweeps must compute the e0
+    column with the same policy as the rest of the work matrix. The
+    column is one compiled program per (shape, distance, policy), so a
+    warm call is a single dispatch, not one per primitive.
+    """
+    return _e0_column(V, e0, distance, resolve_policy(policy).name)
+
+
 def e0_distances(
     V: jax.Array,
     e0: Optional[jax.Array],
     distance: str,
     policy: "str | PrecisionPolicy" = "fp32",
 ) -> jax.Array:
-    """d(v_i, e0) for all i. e0 defaults to the all-zero auxiliary vector.
-
-    ``policy`` is the caller's precision policy — half-precision sweeps must
-    compute the e0 column with the same policy as the rest of the work matrix.
-    """
-    if e0 is None:
-        e0 = jnp.zeros((V.shape[-1],), V.dtype)
-    pair = dist_mod.resolve_pairwise(distance)
-    return pair(V, e0[None, :], resolve_policy(policy))[:, 0]
+    """d(v_i, e0) for all i: :func:`e0_column_and_loss` without the mean."""
+    return _e0_column(V, e0, distance, resolve_policy(policy).name)[0]
 
 
 @partial(jax.profiler.annotate_function, name=tracing.EVALUATE_MULTISET)

@@ -71,7 +71,8 @@ import numpy as np
 
 from repro.core import distances as dist_mod
 from repro.core import tracing
-from repro.core.evaluator import EvalConfig, e0_distances, evaluate_multiset
+from repro.core.evaluator import (EvalConfig, e0_column_and_loss,
+                                  evaluate_multiset)
 from repro.core.multiset import PackedMultiset, pack_base_plus_candidates, pack_sets
 from repro.core.precision import resolve as resolve_policy
 
@@ -588,9 +589,11 @@ class ExemplarClustering(SubmodularFunction):
     def __init__(self, V: jax.Array, cfg: EvalConfig = EvalConfig(),
                  e0: Optional[jax.Array] = None):
         super().__init__(V, cfg, e0)
-        # L({e0}) is S-independent; computed "conventionally" once (paper §IV-B-1)
-        self.d_e0 = e0_distances(self.V, e0, cfg.distance, cfg.policy)
-        self.L0 = float(jnp.mean(self.d_e0.astype(jnp.float32)))
+        # L({e0}) is S-independent; computed "conventionally" once (paper
+        # §IV-B-1), in the same dispatch as the column
+        self.d_e0, L0 = e0_column_and_loss(self.V, e0, cfg.distance,
+                                           cfg.policy)
+        self.L0 = float(L0)
 
     @property
     def cache_seed(self) -> jax.Array:

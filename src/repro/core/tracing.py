@@ -9,8 +9,9 @@ host work only, never the body of a jitted function.
 
 - ``evaluate_multiset``: the whole of ``core.evaluator.evaluate_multiset``,
   from entry until it returns the device array.
-- ``evaluate_multiset.e0_distances``: the eager e0 distance column that
-  ``evaluate_multiset`` computes when the caller passes no ``d_e0``.
+- ``evaluate_multiset.e0_distances``: the e0 distance column that
+  ``evaluate_multiset`` computes when the caller passes no ``d_e0``: the
+  dispatch of its one compiled program.
 - ``exemplar_eval``: the whole of ``kernels.ops.exemplar_eval``: the tile
   and chunk plan, the slices of each chunk and the dispatch of its jitted
   kernel.
@@ -24,8 +25,8 @@ host work only, never the body of a jitted function.
   scored count back to the host, which waits for the device to finish
   the selection, and the building of the ``OptResult``.
 - ``function.init``: the whole of ``core.functions.ExemplarClustering``'s
-  constructor: the e0 distance column and the host read of its mean,
-  ``L({e0})``.
+  constructor: one dispatch of the e0 distance column with its mean,
+  ``L({e0})``, and the host read of the mean.
 
 The evaluator's self time is ``evaluate_multiset`` less its two child
 spans: the backend branch and the cast of the output. The engine's

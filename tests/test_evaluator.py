@@ -1,11 +1,14 @@
 """Evaluation-engine equivalence: modes × backends × chunking × precision."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (ChunkingError, EvalConfig, bytes_per_set,
-                        evaluate_multiset, pack_sets, plan_chunks,
-                        work_matrix)
+from repro.core import (ChunkingError, EvalConfig, ExemplarClustering,
+                        bytes_per_set, evaluate_multiset, pack_sets,
+                        plan_chunks, work_matrix)
+from repro.core import distances as dist_mod
+from repro.core.evaluator import e0_distances
 from repro.core.precision import FP16_STRICT, FP32
 
 
@@ -252,6 +255,87 @@ def test_distances_match_naive(problem, distance):
     np.testing.assert_allclose(
         _vals(V, pk, distance=distance),
         _vals(V, pk, distance=distance, backend="naive"), atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# The e0 column: one compiled program, the same math as the direct definition
+# ---------------------------------------------------------------------------
+
+#: (rtol, atol) of the e0 column against ``POINT`` on the float32 inputs:
+#: bf16 rounds V and e0 to 8 mantissa bits before the Gram expansion.
+E0_TOL = {"fp32": (1e-5, 1e-5), "bf16": (2e-2, 2e-2)}
+
+
+def _e0(problem, given):
+    V, _ = problem
+    if not given:
+        return None
+    rng = np.random.default_rng(11)
+    return jnp.asarray(rng.normal(size=(V.shape[1],)).astype(np.float32))
+
+
+@pytest.mark.parametrize("given", [False, True], ids=["e0_none", "e0_given"])
+@pytest.mark.parametrize("policy", sorted(E0_TOL))
+@pytest.mark.parametrize("distance", sorted(dist_mod.PAIRWISE))
+def test_e0_column_matches_point_definition(problem, distance, policy, given):
+    V, _ = problem
+    e0 = _e0(problem, given)
+    got = np.asarray(e0_distances(V, e0, distance, policy))
+    point = jax.vmap(dist_mod.POINT[distance], in_axes=(0, None))
+    ref = np.asarray(point(V, jnp.zeros((V.shape[1],)) if e0 is None else e0))
+    rtol, atol = E0_TOL[policy]
+    assert got.shape == (V.shape[0],)
+    np.testing.assert_allclose(got, ref, rtol=rtol, atol=atol)
+
+
+@pytest.mark.parametrize("given", [False, True], ids=["e0_none", "e0_given"])
+@pytest.mark.parametrize("policy", sorted(E0_TOL))
+@pytest.mark.parametrize("distance", sorted(dist_mod.PAIRWISE))
+def test_exemplar_L0_is_the_mean_of_its_e0_column(problem, distance, policy,
+                                                  given):
+    V, _ = problem
+    e0 = _e0(problem, given)
+    f = ExemplarClustering(V, EvalConfig(distance=distance, policy=policy),
+                           e0=e0)
+    assert isinstance(f.L0, float)
+    column = np.asarray(f.d_e0, np.float64)
+    np.testing.assert_allclose(f.L0, column.mean(), rtol=1e-6)
+    np.testing.assert_array_equal(
+        np.asarray(f.d_e0), np.asarray(e0_distances(V, e0, distance, policy)))
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas_interpret"])
+def test_warm_e0_column_dispatches_no_eager_primitive(problem, monkeypatch,
+                                                      backend):
+    """A warm ``evaluate_multiset`` with no ``d_e0`` runs its e0 column as
+    one compiled program: it sends no primitive through JAX's eager
+    primitive dispatch, ``jax._src.dispatch.apply_primitive``, which looks
+    up each primitive's executable through
+    ``jax._src.dispatch.xla_primitive_callable`` (the hook counted here).
+    So the call dispatches exactly what the same call with ``d_e0`` given
+    does."""
+    from jax._src import dispatch
+
+    V, pk = problem
+    cfg = EvalConfig(backend=backend)
+    d_e0 = e0_distances(V, None, cfg.distance, cfg.policy)
+    for kw in ({}, {"d_e0": d_e0}):  # compile every program once
+        evaluate_multiset(V, pk, cfg, **kw).block_until_ready()
+    seen = []
+    real = dispatch.xla_primitive_callable
+
+    def counting(prim, **params):
+        seen.append(prim.name)
+        return real(prim, **params)
+
+    monkeypatch.setattr(dispatch, "xla_primitive_callable", counting)
+    e0_distances(V, None, cfg.distance, cfg.policy).block_until_ready()
+    assert seen == []
+    evaluate_multiset(V, pk, cfg).block_until_ready()
+    without_d_e0 = list(seen)
+    seen.clear()
+    evaluate_multiset(V, pk, cfg, d_e0=d_e0).block_until_ready()
+    assert without_d_e0 == seen
 
 
 # ---------------------------------------------------------------------------
