@@ -219,6 +219,7 @@ def make_selection_scan(
     stochastic, ONE (B, 1, m) row for dense (closed over by every round),
     (B, 1, 0) for lazy. Returns ``run(V_sh, pool, seed_sh, aux_sh,
     cand_rounds, w0, k_eff) -> (sel (k, B), traj (k, B), n_scored (B,),
+    n_trips (B,) CELF's re-score trips (0 unless lazy),
     cache_vec (B, n_pad))``; one request is B = 1. The builder is cached per
     (mesh, fn, statics) so repeat runs reuse one traced executable; ``fn``
     rides the cache key exactly like a jit static.
@@ -414,13 +415,13 @@ def make_selection_scan(
                 return score_idx_val(cache, jnp.broadcast_to(
                     jnp.arange(n_total, dtype=jnp.int32), (B, n_total)))
 
-            sel, traj, n_scored, cache_f = drive_selection_scan(
+            sel, traj, n_scored, n_trips, cache_f = drive_selection_scan(
                 kind=kind, k=k, top_b=top_b, n_global=n_total, k_eff=k_eff,
                 take=take, n_pool=n_total, seed_val=seed_val,
                 cand_rounds=cand_rounds, cache0=cache0, w0=w0c, fold=fold,
                 score_idx_val=score_idx_val, fold_score_val=fold_score_val,
                 value_of=value_of)
-            return sel, traj, n_scored, cache_f[0]
+            return sel, traj, n_scored, n_trips, cache_f[0]
 
         def score_idx_val(cache, idx):
             vec, _aux = cache
@@ -453,12 +454,12 @@ def make_selection_scan(
                 gains, val = score_idx_val(cache2, cand_t)
                 return gains, cache2, val
 
-        sel, traj, n_scored, cache_f = drive_selection_scan(
+        sel, traj, n_scored, n_trips, cache_f = drive_selection_scan(
             kind=kind, k=k, top_b=top_b, n_global=n_total, k_eff=k_eff,
             pool=pool, cand_rounds=cand_rounds, cache0=cache0, w0=w0c,
             fold=fold, score_idx_val=score_idx_val,
             fold_score_val=fold_score_val, value_of=value_of)
-        return sel, traj, n_scored, cache_f[0]
+        return sel, traj, n_scored, n_trips, cache_f[0]
 
     smapped = shard_map(
         local_scan,
@@ -467,7 +468,7 @@ def make_selection_scan(
                   P(None, axes, None) if sharded_pool else P(None, None, None),
                   P(None, axes), P(None, axes), P(None, None, None),
                   P(None, None), P(None)),
-        out_specs=(P(None), P(None), P(None), P(None, axes)),
+        out_specs=(P(None), P(None), P(None), P(None), P(None, axes)),
         check_vma=False,
     )
 
@@ -574,7 +575,7 @@ def run_sharded_selection(
     p×). Under the sharded pool the take-block width is additionally
     capped at n_loc so the transient gathered block never exceeds the
     resident shard — the O(n/p) peak-memory claim covers transients too.
-    Returns ``(sel (k,), traj (k,), n_scored)`` device arrays.
+    Returns ``(sel (k,), traj (k,), n_scored, n_trips)`` device arrays.
     """
     mesh = _resolve_mesh(mesh, data_axes)
     axes = tuple(data_axes)
@@ -594,10 +595,10 @@ def run_sharded_selection(
         block_m=bm, distance=f.cfg.distance,
         policy_name=f.cfg.resolved_policy().name, counter_key=counter_key,
         backend=backend, rbf_gamma=rbf_gamma, pool_plan=pool_plan)
-    sel, traj, n_scored, _ = scan(
+    sel, traj, n_scored, n_trips, _ = scan(
         V1, pool, entry["seed_sh"][None], entry["aux_sh"][None],
         cand_rounds[None], w0[None], jnp.asarray([k], jnp.int32))
-    return sel[:, 0], traj[:, 0], n_scored[0]
+    return sel[:, 0], traj[:, 0], n_scored[0], n_trips[0]
 
 
 def stage_sharded_batch(
@@ -683,7 +684,7 @@ def run_sharded_selection_batch(
     the resident shard. ``staged`` optionally passes the payload a prior
     :func:`stage_sharded_batch` already transferred (it is re-staged here
     if its mesh/axes/pool_plan disagree). Returns ``(sel (k, B),
-    traj (k, B), n_scored (B,))`` device arrays.
+    traj (k, B), n_scored (B,), n_trips (B,))`` device arrays.
     """
     mesh = _resolve_mesh(mesh, data_axes)
     axes = tuple(data_axes)
@@ -709,10 +710,10 @@ def run_sharded_selection_batch(
         block_m=bm, distance=f0.cfg.distance,
         policy_name=f0.cfg.resolved_policy().name, counter_key=counter_key,
         backend=backend, rbf_gamma=rbf_gamma, pool_plan=pool_plan)
-    sel, traj, n_scored, _ = scan(
+    sel, traj, n_scored, n_trips, _ = scan(
         V_sh, pool, staged["seed_sh"], staged["aux_sh"], cand_rounds,
         staged["w0"], jnp.asarray(np.asarray(ks, np.int32)))
-    return sel, traj, n_scored
+    return sel, traj, n_scored, n_trips
 
 
 # ---------------------------------------------------------------------------
@@ -867,7 +868,7 @@ def make_greedi_scan(
 
         pad_taken = (jnp.arange(n_loc, dtype=jnp.int32) + off) >= n_total
         one = jnp.full((1,), k, jnp.int32)   # each phase is one request
-        sel1, _, nsc1, _ = drive_selection_scan(
+        sel1, _, nsc1, _, _ = drive_selection_scan(
             kind="dense", k=k, top_b=0, n_global=n_total, k_eff=one,
             take=lambda j: (V_loc[j], j), n_pool=n_loc,
             taken0=pad_taken[None],
@@ -965,7 +966,7 @@ def make_greedi_scan(
                 gains, val = psum_gains_val(g, cache2)
                 return gains, cache2, val
 
-        sel2, traj2, nsc2, _ = drive_selection_scan(
+        sel2, traj2, nsc2, _, _ = drive_selection_scan(
             kind="dense", k=k, top_b=0, n_global=n_total, k_eff=one,
             take=lambda j: (merged_vec[j], merged_idx[j]),
             n_pool=p_total * k,

@@ -73,6 +73,9 @@ class OptResult:
     value: float
     trajectory: list[float]
     evaluations: int
+    #: CELF's re-score trips: iterations of the lazy step's while loop,
+    #: summed over the rounds (0 for the dense and stochastic strategies)
+    rescore_trips: int = 0
 
     def exemplars(self, V) -> np.ndarray:
         return np.asarray(V)[self.indices]
@@ -301,6 +304,10 @@ def make_lazy_step(take, fold, score_idx_val, top_b: int, max_iters: int,
     bound state itself stays a replicated (B, n) scalar array, never an
     (n, d) payload).
 
+    Per round the step emits ``(winner, value, scored, trips)``:
+    ``trips`` counts the iterations in which the request re-scored a
+    batch (every iteration while it is active; 0 for a frozen round).
+
     Each request carries its own stale bounds and freshness, and the
     while loop runs while ANY active request fails the fresh-top invariant
     — best re-scored gain ≥ every remaining stale bound — or, on the
@@ -329,12 +336,12 @@ def make_lazy_step(take, fold, score_idx_val, top_b: int, max_iters: int,
             return (fresh_best < stale_max) & act
 
         def invariant_fails(st):
-            ub_c, fresh, _, _, it = st
+            ub_c, fresh, _, _, _, it = st
             return (jnp.any(request_active(ub_c, fresh)) | (it == 0)) \
                 & (it < max_iters)
 
         def rescore_top_b(st):
-            ub_c, fresh, scored, _, it = st
+            ub_c, fresh, scored, trips, _, it = st
             active = request_active(ub_c, fresh)
             stale = jnp.where(fresh | taken, -jnp.inf, ub_c)
             top_ub, top_idx = jax.lax.top_k(stale, top_b)
@@ -347,18 +354,20 @@ def make_lazy_step(take, fold, score_idx_val, top_b: int, max_iters: int,
             fresh = fresh.at[rows, top_idx].set(
                 jnp.take_along_axis(fresh, top_idx, axis=1) | live)
             scored = scored + jnp.sum(live, axis=1).astype(jnp.int32)
-            return ub_c, fresh, scored, val, it + 1
+            trips = trips + active.astype(jnp.int32)
+            return ub_c, fresh, scored, trips, val, it + 1
 
-        ub2, fresh, scored, val, _ = jax.lax.while_loop(
+        zeros = jnp.zeros((B,), jnp.int32)
+        ub2, fresh, scored, trips, val, _ = jax.lax.while_loop(
             invariant_fails, rescore_top_b,
-            (ub, jnp.zeros(taken.shape, bool), jnp.zeros((B,), jnp.int32),
+            (ub, jnp.zeros(taken.shape, bool), zeros, zeros,
              jnp.zeros((B,), jnp.float32), jnp.asarray(0, jnp.int32)))
         j = jnp.argmax(jnp.where(fresh & ~taken, ub2, -jnp.inf), axis=1)
         new_carry = (cache2, taken.at[rows[:, 0], j].set(True), take(j), ub2)
         carry = _freeze_where(act, new_carry, carry)
         # cache includes winners 0..t-1 here → val is trajectory[t-1]
         return carry, (jnp.where(act, j, -1), val,
-                       jnp.where(act, scored, 0))
+                       jnp.where(act, scored, 0), jnp.where(act, trips, 0))
 
     return step
 
@@ -416,9 +425,10 @@ def drive_selection_scan(*, kind, k, top_b, n_global, k_eff, pool=None,
       sharded pool: blocked take-and-score).
     * ``value_of(cache) -> (B,)`` — the global f(S) of each cache.
 
-    Returns ``(sel (k, B), traj (k, B), n_scored (B,), final cache)`` —
-    the final cache rides out so a jitted dispatch can alias its vec onto
-    the donated seed buffer.
+    Returns ``(sel (k, B), traj (k, B), n_scored (B,), n_trips (B,),
+    final cache)`` — ``n_trips`` sums CELF's re-score trips over the rounds
+    (0 for the dense and stochastic strategies); the final cache rides out
+    so a jitted dispatch can alias its vec onto the donated seed buffer.
     """
     B = k_eff.shape[0]
     if take is None:
@@ -440,11 +450,12 @@ def drive_selection_scan(*, kind, k, top_b, n_global, k_eff, pool=None,
             ub0, _ = score_idx_val(cache0, jnp.broadcast_to(
                 jnp.arange(n_pool, dtype=jnp.int32), (B, n_pool)))
         init = (cache0, taken_init, w0, ub0)
-        (cache, _, w_last, _), (sel, vals, scored) = jax.lax.scan(
+        (cache, _, w_last, _), (sel, vals, scored, trips) = jax.lax.scan(
             step, init, ts)
         n_scored = jnp.where(
             k_eff > 0,
             jnp.asarray(n_pool, jnp.int32) + jnp.sum(scored, axis=0), 0)
+        n_trips = jnp.sum(trips, axis=0)
     else:
         step = make_rounds_step(take, fold_score_val, k_eff)
         init = (cache0, taken_init, w0)
@@ -456,13 +467,15 @@ def drive_selection_scan(*, kind, k, top_b, n_global, k_eff, pool=None,
             (cache, _, w_last), (sel, vals, scored) = jax.lax.scan(
                 step, init, (jnp.swapaxes(cand_rounds, 0, 1), ts))
         n_scored = jnp.sum(scored, axis=0)
+        n_trips = jnp.zeros((B,), jnp.int32)
 
     # one final fold for the last trajectory point (frozen requests fold
     # their held winner transiently — still exactly f(S_{k_eff}))
     cache_f = fold(cache, w_last)
     final_val = value_of(cache_f)
     traj = jnp.concatenate([vals[1:], final_val[None, :]], axis=0)
-    return sel.astype(jnp.int32), traj, n_scored.astype(jnp.int32), cache_f
+    return (sel.astype(jnp.int32), traj, n_scored.astype(jnp.int32),
+            n_trips.astype(jnp.int32), cache_f)
 
 
 # ---------------------------------------------------------------------------
@@ -498,7 +511,7 @@ def _select_scan(V, seed, row_aux, cand_rounds, w0, k_eff, *, fn, kind, k,
     which is what makes host and device selections agree.
 
     ``seed`` is DONATED and the final folded (B, n) float32 cache vector
-    rides out as the 4th output, so XLA aliases the carry's final buffer
+    rides out as the last output, so XLA aliases the carry's final buffer
     onto the seed's allocation: repeated same-signature calls (warm-bucket
     serving) reuse the cache buffer instead of allocating a fresh one per
     dispatch. Callers therefore pass a freshly-built seed
@@ -518,7 +531,9 @@ def _select_scan(V, seed, row_aux, cand_rounds, w0, k_eff, *, fn, kind, k,
     scoring is a vmapped :func:`_score_blocked`.
 
     Per-round ys are ``(selected index, trajectory value, #actually-scored
-    candidates)`` — the last is the engine's honest ``evaluations`` unit.
+    candidates)`` — the last is the engine's honest ``evaluations`` unit —
+    and, for lazy, CELF's re-score trips. Returns ``(sel (k, B), traj
+    (k, B), n_scored (B,), n_trips (B,), cache (B, n))``.
     """
     DEVICE_TRACE_COUNTS[counter_key] += 1
     policy = resolve_policy(policy_name)
@@ -606,12 +621,12 @@ def _select_scan(V, seed, row_aux, cand_rounds, w0, k_eff, *, fn, kind, k,
     B = V.shape[0]
     w0c = (w0.astype(V.dtype), jnp.full((B,), -1, jnp.int32))
     cache0 = (seedf, jnp.zeros((B,), jnp.float32))
-    sel, traj, n_scored, cache_f = drive_selection_scan(
+    sel, traj, n_scored, n_trips, cache_f = drive_selection_scan(
         kind=kind, k=k, top_b=top_b, n_global=n, pool=V, k_eff=k_eff,
         cand_rounds=cand_rounds, cache0=cache0, w0=w0c, fold=fold,
         score_idx_val=score_idx_val, fold_score_val=fold_score_val,
         value_of=value_of)
-    return sel, traj, n_scored, cache_f[0]
+    return sel, traj, n_scored, n_trips, cache_f[0]
 
 # ---------------------------------------------------------------------------
 # Engine entry point
@@ -716,7 +731,7 @@ def run_selection(
             k_eff = jnp.asarray([k], jnp.int32)
 
     if plan == "device":
-        sel, traj, n_scored, _ = _select_scan(
+        sel, traj, n_scored, n_trips, _ = _select_scan(
             V1, seed, aux1, cand, w01, k_eff,
             fn=fn, kind=kind, k=k, top_b=top_b, distance=f.cfg.distance,
             policy_name=policy.name, block_m=bm, backend=backend,
@@ -724,7 +739,7 @@ def run_selection(
     elif plan in ("device_sharded", "device_sharded_pool"):
         from repro.core import distributed as dist_engine
 
-        sel, traj, n_scored = dist_engine.run_sharded_selection(
+        sel, traj, n_scored, n_trips = dist_engine.run_sharded_selection(
             f, jnp.asarray(cand_rounds, jnp.int32), w0, kind=kind, k=k,
             top_b=top_b, counter_key=counter_key, m_widest=m_widest,
             block_m=block_m, mesh=mesh, data_axes=data_axes,
@@ -747,6 +762,7 @@ def run_selection(
             f, w0, k=k, counter_key=counter_key, block_m=block_m,
             mesh=mesh, data_axes=data_axes, backend=backend,
             rbf_gamma=rbf_gamma)
+        n_trips = 0
     else:
         raise ValueError(f"unknown execution plan {plan!r}")
 
@@ -761,7 +777,8 @@ def run_selection(
                 f"silently re-select a taken index")
         traj = [float(x) for x in np.ravel(traj)]
         return OptResult(sel, traj[-1] if traj else 0.0, traj,
-                         int(np.ravel(n_scored)[0]))
+                         int(np.ravel(n_scored)[0]),
+                         int(np.ravel(n_trips)[0]))
 
 
 def _stack_batch_payload(fs: Sequence[SubmodularFunction]) -> dict:
@@ -938,18 +955,19 @@ def run_selection_batch(
     if plan in ("device_sharded", "device_sharded_pool"):
         from repro.core import distributed as dist_engine
 
-        sel, traj, n_scored = dist_engine.run_sharded_selection_batch(
-            fs, jnp.asarray(cand_rounds, jnp.int32), ks, kind=kind, k=k,
-            top_b=top_b, counter_key=counter_key, m_widest=m_widest,
-            block_m=block_m, mesh=mesh, data_axes=tuple(data_axes),
-            backend=backend, rbf_gamma=rbf_gamma,
-            pool_plan="sharded" if plan == "device_sharded_pool"
-            else "replicated", staged=staged)
+        sel, traj, n_scored, n_trips = \
+            dist_engine.run_sharded_selection_batch(
+                fs, jnp.asarray(cand_rounds, jnp.int32), ks, kind=kind, k=k,
+                top_b=top_b, counter_key=counter_key, m_widest=m_widest,
+                block_m=block_m, mesh=mesh, data_axes=tuple(data_axes),
+                backend=backend, rbf_gamma=rbf_gamma,
+                pool_plan="sharded" if plan == "device_sharded_pool"
+                else "replicated", staged=staged)
     elif plan == "device":
         bm = block_m if block_m is not None \
             else _device_block_m(n, m_widest, n_batch=B)
         payload = staged if staged is not None else _stack_batch_payload(fs)
-        sel, traj, n_scored, _ = _select_scan(
+        sel, traj, n_scored, n_trips, _ = _select_scan(
             payload["V"], payload["seed"], payload["aux"],
             jnp.asarray(cand_rounds, jnp.int32), payload["w0"],
             jnp.asarray(ks, jnp.int32), fn=fn, kind=kind, k=k, top_b=top_b,
@@ -960,6 +978,7 @@ def run_selection_batch(
     sel = np.asarray(sel)            # (k, B)
     traj = np.asarray(traj)          # (k, B)
     n_scored = np.asarray(n_scored)  # (B,)
+    n_trips = np.asarray(n_trips)    # (B,)
     out = []
     for b, kb in enumerate(ks):
         sb = [int(x) for x in sel[:kb, b]]
@@ -970,5 +989,5 @@ def run_selection_batch(
                 f"sample row is exhausted by earlier selections)")
         tb = [float(x) for x in traj[:kb, b]]
         out.append(OptResult(sb, tb[-1] if tb else 0.0, tb,
-                             int(n_scored[b])))
+                             int(n_scored[b]), int(n_trips[b])))
     return out

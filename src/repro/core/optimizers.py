@@ -201,7 +201,8 @@ def lazy_greedy(
     awareness preserved) until the fresh-top invariant certifies the winner.
     Because host and device run the *same* policy, selections AND
     ``evaluations`` counts agree across modes on the jnp backend (up to
-    exact float ties).
+    exact float ties), and so do ``rescore_trips``: the re-score batches
+    taken, summed over the rounds.
 
     ``mode="device"`` runs CELF entirely on device: the stale bounds ride
     the one-dispatch scan carry, each iteration re-scores the top-``batch``
@@ -234,6 +235,7 @@ def lazy_greedy(
     all_idx = np.arange(n)
     ub = np.asarray(f.gains_from_cache(cache, all_idx), np.float32).copy()
     evals = n
+    trips = 0
     taken = np.zeros(n, bool)
     selected: list[int] = []
     traj: list[float] = []
@@ -249,12 +251,13 @@ def lazy_greedy(
             ub[top_idx] = np.asarray(f.gains_from_cache(cache, top_idx))
             fresh[top_idx] = True
             evals += len(top_idx)
+            trips += 1
         j = int(np.argmax(np.where(fresh & ~taken, ub, -np.inf)))
         selected.append(j)
         taken[j] = True
         cache = f.fold_winner(cache, j)
         traj.append(f.value_from_cache(cache))
-    return OptResult(selected, traj[-1] if traj else 0.0, traj, evals)
+    return OptResult(selected, traj[-1] if traj else 0.0, traj, evals, trips)
 
 
 def stochastic_greedy(

@@ -31,6 +31,16 @@ host work only, never the body of a jitted function.
 The evaluator's self time is ``evaluate_multiset`` less its two child
 spans: the backend branch and the cast of the output. The engine's
 dispatch is ``run_selection`` less its two child spans.
+
+One counter rides the selection's result instead of the trace:
+
+- ``OptResult.rescore_trips``: CELF's re-score trips, the iterations of
+  the lazy step's while loop (``core.engine.make_lazy_step``) summed over
+  the rounds of a selection; each trip scores up to ``top_b`` candidates.
+  The scan emits it per round beside the scored count, and
+  ``run_selection.fetch`` reads it back with the picks, so it costs no
+  host sync of its own. ``lazy_greedy(mode="host")`` counts its batches
+  the same way; the dense and stochastic strategies report 0.
 """
 
 EVALUATE_MULTISET = "evaluate_multiset"

@@ -28,6 +28,7 @@ N, M, D, B = 8192, 512, 128, 4          # serving-size ground set, d in lanes
 BLOCK = 256
 PAPER = dict(n=50_000, l=5_000, k=10, d=100)   # configs/paper.py
 GREEDY = dict(n=50_000, d=100)          # the Greedy cell's ground set
+LAZY = dict(n=50_000, d=100, k=10, top_b=256)   # the CELF cell
 POLICY = {"fp32": FP32, "bf16": BF16}
 AFFINE = {"min": None, "max": (1.0, -0.5)}
 
@@ -125,6 +126,48 @@ def test_gain_update_compiles_at_greedy_cell_size(one_chip, b):
         rbf_gamma=None, n_total=n, block_n=bn, block_m=bm, fold="min",
         score_affine=None).compile().as_text()
     assert "tpu_custom_call" in text and _has_kernel(text, "gain_update_eval")
+
+
+@pytest.mark.parametrize("m", [LAZY["top_b"], LAZY["n"]],
+                         ids=["rescore", "seeding"])
+def test_gain_eval_compiles_at_lazy_cell_size(one_chip, m):
+    """The unfused gain kernel, through its padding wrapper, at the CELF
+    cell's size (bench/configs/paper_v_a_lazy.json: N = 50,000, d = 100
+    in 128 lanes, fp32, one request) and both of its candidate widths:
+    a re-score trip's top_b = 256 and the bound seeding's m = N, each with
+    the tile ``ops.gain_tile`` picks there."""
+    n, d = LAZY["n"], LAZY["d"]
+    bn, bm = ops.gain_tile(n, m, ops._round_up(d, ops.LANE), FP32)
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    text = ops._marginal_gain_padded.lower(
+        sds((1, n, d), jnp.float32), sds((1, m, d), jnp.float32),
+        sds((1, n), jnp.float32), policy=FP32, interpret=False,
+        rbf_gamma=None, n_total=n, block_n=bn, block_m=bm, fold="min",
+        score_affine=None).compile().as_text()
+    assert "tpu_custom_call" in text and _has_kernel(text, "gain_eval")
+
+
+def test_lazy_scan_compiles_at_lazy_cell_size(one_chip):
+    """The whole one-dispatch CELF selection ``run_selection`` launches at
+    the CELF cell's size, on the Pallas backend: its bound seeding and
+    its while loop of top-256 re-score trips both run ``gain_eval``, and
+    no fused kernel (lazy folds each winner once, outside any kernel)."""
+    from repro.core import engine
+    from repro.core.functions import FnSpec
+
+    n, d = LAZY["n"], LAZY["d"]
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    text = engine._select_scan.lower(
+        sds((1, n, d), jnp.float32), sds((1, n), jnp.float32),
+        sds((1, n), jnp.float32), sds((1, 1, 0), jnp.int32),
+        sds((1, d), jnp.float32), sds((1,), jnp.int32), fn=FnSpec(),
+        kind="lazy", k=LAZY["k"], top_b=LAZY["top_b"],
+        distance="sqeuclidean", policy_name="fp32",
+        block_m=engine._device_block_m(n, n), backend="pallas",
+        rbf_gamma=None, counter_key="tpu_compile_lazy",
+    ).compile().as_text()
+    kernels = re.findall(r"%(gain_eval|gain_update_eval)(?:\.\d+)? = ", text)
+    assert sorted(kernels) == ["gain_eval", "gain_eval"]
 
 
 @pytest.mark.parametrize("p", [1, 8])

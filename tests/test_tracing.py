@@ -15,7 +15,9 @@ import numpy as np
 import pytest
 
 from repro.core import (EvalConfig, ExemplarClustering, evaluate_multiset,
-                        greedy, pack_sets, tracing)
+                        greedy, lazy_greedy, pack_sets, stochastic_greedy,
+                        tracing)
+from repro.core.engine import run_selection_batch
 from repro.core.evaluator import e0_distances
 
 CALLS = 2
@@ -86,18 +88,31 @@ def test_no_e0_span_when_the_caller_passes_d_e0(tmp_path, backend):
     assert names.count(tracing.EVALUATE_MULTISET) == CALLS
 
 
-def _traced_selections(tmp_path, V, cfg, k=3):
-    greedy(ExemplarClustering(V, cfg), k, mode="device")  # compile
+def _traced_selections(tmp_path, V, cfg, k=3, select=greedy):
+    select(ExemplarClustering(V, cfg), k, mode="device")  # compile
     with jax.profiler.trace(str(tmp_path)):
         for _ in range(CALLS):
-            greedy(ExemplarClustering(V, cfg), k, mode="device")
+            select(ExemplarClustering(V, cfg), k, mode="device")
     return _spans(tmp_path)
 
 
 @pytest.mark.parametrize("backend", ["jnp", "pallas_interpret"])
 def test_selection_spans_open_once_per_call(tmp_path, backend):
     V, _ = _problem(seed=2)
-    spans = _traced_selections(tmp_path, V, EvalConfig(backend=backend))
+    _check_selection_spans(
+        _traced_selections(tmp_path, V, EvalConfig(backend=backend)))
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas_interpret"])
+def test_lazy_selection_spans_open_once_per_call(tmp_path, backend):
+    """CELF's counter rides the result, so its selection opens the same
+    host spans as dense Greedy's."""
+    V, _ = _problem(seed=2)
+    _check_selection_spans(_traced_selections(
+        tmp_path, V, EvalConfig(backend=backend), select=lazy_greedy))
+
+
+def _check_selection_spans(spans):
     counts = {name: sum(1 for *_, n in spans if n == name)
               for name in tracing.SPANS}
     assert counts == dict.fromkeys(tracing.SPANS, 0) | {
@@ -116,3 +131,55 @@ def test_selection_spans_open_once_per_call(tmp_path, backend):
     prepare = [e for _, e, n in spans if n == tracing.RUN_SELECTION_PREPARE]
     fetch = [s for s, _, n in spans if n == tracing.RUN_SELECTION_FETCH]
     assert all(p <= f for p, f in zip(prepare, fetch))
+
+
+# -- the re-score trip counter (OptResult.rescore_trips) ---------------------
+
+
+@pytest.mark.parametrize("mode", ["host", "device"])
+def test_dense_and_stochastic_selections_report_no_trips(mode):
+    V, _ = _problem(n=128, seed=3)
+    f = ExemplarClustering(V, EvalConfig())
+    assert greedy(f, 4, mode=mode).rescore_trips == 0
+    assert stochastic_greedy(f, 4, mode=mode).rescore_trips == 0
+
+
+@pytest.mark.parametrize("batch", [8, 32, 500])
+@pytest.mark.parametrize("backend", ["jnp", "pallas_interpret"])
+def test_device_and_host_lazy_count_the_same_trips(backend, batch):
+    V, _ = _problem(n=300, d=16, seed=4)
+    f = ExemplarClustering(V, EvalConfig(backend=backend))
+    host = lazy_greedy(f, 6, batch=batch, mode="host")
+    dev = lazy_greedy(f, 6, batch=batch, mode="device")
+    assert dev.indices == host.indices
+    assert dev.rescore_trips == host.rescore_trips
+    assert dev.evaluations == host.evaluations
+    # each round re-scores at least one batch; the bound seeding is no trip
+    assert dev.rescore_trips >= 6
+    assert dev.evaluations <= f.n + dev.rescore_trips * min(batch, f.n)
+
+
+def test_batched_lazy_counts_each_requests_trips_as_alone():
+    """Ragged k in one bucket: each request's trips are those of its own
+    run, and an inert padding slot counts none."""
+    fs = [ExemplarClustering(_problem(n=200, d=8, seed=s)[0], EvalConfig())
+          for s in (5, 6, 7)]
+    ks = [5, 3, 0]
+    out = run_selection_batch(fs, kind="lazy", k=5, ks=ks, top_b=16,
+                              counter_key="test_batched_trips")
+    for f, kb, res in zip(fs, ks, out):
+        alone = lazy_greedy(f, kb, batch=16, mode="device")
+        assert res.rescore_trips == alone.rescore_trips
+        assert res.evaluations == alone.evaluations
+    assert out[2].rescore_trips == 0
+
+
+@pytest.mark.parametrize("mode", ["device_sharded", "device_sharded_pool"])
+def test_sharded_plans_count_the_device_trips(mode):
+    V, _ = _problem(n=300, d=16, seed=8)
+    f = ExemplarClustering(V, EvalConfig())
+    dev = lazy_greedy(f, 6, batch=16, mode="device")
+    sharded = lazy_greedy(f, 6, batch=16, mode=mode)
+    assert sharded.indices == dev.indices
+    assert sharded.rescore_trips == dev.rescore_trips
+    assert sharded.evaluations == dev.evaluations
